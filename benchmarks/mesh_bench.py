@@ -18,6 +18,11 @@ The committed JSONs (results/perf/mesh-bench__k*.json) pin that curve,
 and ``--check`` gates exact parity (sharded == single-device, bit for
 bit) plus >= 1.5x at K=4 vs K=1.
 
+CPU only: the one-process-per-K design needs virtual host devices.  A
+TPU belongs to one process at a time, so children started here could
+not reach the chip; `python chip_smoke.py --chips 4` exercises the
+sharded paths on a real four-chip host, in one process.
+
   PYTHONPATH=src python -m benchmarks.mesh_bench [--quick] [--check]
 """
 from __future__ import annotations
@@ -53,8 +58,9 @@ os.environ["XLA_FLAGS"] = \
 import json
 import time
 import numpy as np
+import jax
 import jax.numpy as jnp
-from repro.compat import make_mesh
+from jax.sharding import AxisType
 from repro.core.predictor import PredictConfig, Predictor
 from repro.core.trees import ObliviousEnsemble
 from repro.kernels import registry
@@ -74,7 +80,7 @@ plan = Predictor.build(ens, PredictConfig(strategy="staged",
                                           backend="ref", layout="soa"))
 pool = plan.quantize(x)                      # once, outside the loop
 ref = np.asarray(plan.raw(pool))             # single-device reference
-mesh = make_mesh(({k},), ("data",))
+mesh = jax.make_mesh(({k},), ("data",), (AxisType.Auto,))
 fn = plan.sharded(mesh)
 
 registry.reset_call_stats()

@@ -41,6 +41,19 @@ def aval_bytes(aval: Any) -> int:
     return int(np.prod(shape, dtype=np.int64)) * np.dtype(dtype).itemsize
 
 
+def vmem_aval_bytes(aval: Any) -> int:
+    """VMEM bytes of one buffer: `aval_bytes` padded to whole
+    (sublane, lane) tiles, the way Mosaic allocates it (see
+    `kernels.tuning.tile_bytes`)."""
+    from repro.kernels import tuning
+    aval = unwrap_aval(aval)
+    shape = getattr(aval, "shape", None)
+    dtype = getattr(aval, "dtype", None)
+    if shape is None or dtype is None:
+        return 0
+    return tuning.tile_bytes(shape, np.dtype(dtype).itemsize)
+
+
 def aval_short(aval: Any) -> str:
     aval = unwrap_aval(aval)
     shape = getattr(aval, "shape", ())
@@ -121,10 +134,13 @@ def consumers_map(
 
 
 # Ops that merely move/reshape data: a value flowing through them keeps
-# its identity for the terminal-consumer walk.
+# its identity for the terminal-consumer walk.  A further dtype
+# conversion keeps it too: a widened panel converted again (uint8 ->
+# int32 -> bfloat16, the route to the MXU where no direct convert
+# exists) is still that panel, and what consumes it decides the verdict.
 LAYOUT_PRESERVING = frozenset({
     "transpose", "reshape", "broadcast_in_dim", "squeeze", "slice",
-    "rev", "copy", "dynamic_slice",
+    "rev", "copy", "dynamic_slice", "convert_element_type",
 })
 
 # Call-like primitives whose body invars map 1:1 onto the eqn invars,
@@ -132,8 +148,11 @@ LAYOUT_PRESERVING = frozenset({
 # gather/dot in a named pjit — a widened panel must be followed inside
 # or the lint would stop at the wrapper).  Loop/branch primitives
 # (scan, while, cond) interleave carries/consts and stay boundaries.
-_CALL_PRIMS = frozenset({"pjit", "closed_call", "core_call",
+_CALL_PRIMS = frozenset({"jit", "pjit", "closed_call", "core_call",
                          "custom_jvp_call", "custom_vjp_call"})
+
+# The jit call primitive: named "jit" since JAX 0.7, "pjit" before.
+JIT_PRIMS = frozenset({"jit", "pjit"})
 
 
 def _call_body(eqn: jax_core.JaxprEqn) -> Optional[jax_core.Jaxpr]:
@@ -211,7 +230,8 @@ _UNCHARGED = frozenset({"get", "iota", "broadcast_in_dim"})
 
 
 def peak_live_bytes(jaxpr: jax_core.Jaxpr,
-                    include_invars: bool = True) -> int:
+                    include_invars: bool = True, *,
+                    size=aval_bytes) -> int:
     """Upper-bound estimate of the scope's peak live buffer bytes.
 
     Walks eqns in order; an eqn's outputs are allocated when it runs,
@@ -225,7 +245,8 @@ def peak_live_bytes(jaxpr: jax_core.Jaxpr,
     could be resident at once" bound, which is what the VMEM audit
     compares against the tuning footprint models.  Ref loads (`get`),
     iota/broadcast values and dead outputs (`swap`'s discarded old
-    value) are not charged."""
+    value) are not charged.  `size` prices one aval (`vmem_aval_bytes`
+    for the tile-padded VMEM view)."""
     last_use: dict[jax_core.Var, int] = {}
     for i, eqn in enumerate(jaxpr.eqns):
         for v in eqn.invars:
@@ -236,10 +257,24 @@ def peak_live_bytes(jaxpr: jax_core.Jaxpr,
         if isinstance(v, jax_core.Var):
             last_use[v] = n_eqns  # escapes: live to the end
 
+    # A value read only as a gather's index operand is an index
+    # encoding (jnp.take_along_axis appends a unit dim that the kernel
+    # lowering shape-casts away), never a resident buffer of its own.
+    index_only: set = set()
+    readers: dict[jax_core.Var, list] = {}
+    for eqn in jaxpr.eqns:
+        for pos, v in enumerate(eqn.invars):
+            if isinstance(v, jax_core.Var):
+                readers.setdefault(v, []).append((eqn.primitive.name, pos))
+    for v, uses in readers.items():
+        if all(name == "gather" and pos > 0 for name, pos in uses):
+            index_only.add(v)
+
     def out_bytes(v, eqn) -> int:
-        if eqn.primitive.name in _UNCHARGED or v not in last_use:
+        if eqn.primitive.name in _UNCHARGED or v not in last_use \
+                or v in index_only:
             return 0
-        return aval_bytes(v.aval)
+        return size(v.aval)
 
     alloc_by: dict[jax_core.Var, jax_core.JaxprEqn] = {}
 
@@ -247,12 +282,12 @@ def peak_live_bytes(jaxpr: jax_core.Jaxpr,
         src = alloc_by.get(v)
         if src is not None:
             return out_bytes(v, src)
-        return aval_bytes(v.aval) if include_invars else 0
+        return size(v.aval) if include_invars else 0
 
     live = 0
     if include_invars:
         roots = list(jaxpr.invars) + list(jaxpr.constvars)
-        live += sum(aval_bytes(v.aval) for v in roots)
+        live += sum(size(v.aval) for v in roots)
     peak = live
     for i, eqn in enumerate(jaxpr.eqns):
         subs = eqn_subjaxprs(eqn)
@@ -269,7 +304,8 @@ def peak_live_bytes(jaxpr: jax_core.Jaxpr,
             # Sub-scope invars alias buffers already counted live here,
             # so only its *interior* growth is a transient.
             transient = max(transient,
-                            peak_live_bytes(sub, include_invars=False))
+                            peak_live_bytes(sub, include_invars=False,
+                                            size=size))
         peak = max(peak, live + transient)
         if subs:
             live -= sum(release(v) for v in dying)

@@ -162,55 +162,56 @@ def _model_bytes(cell: Cell, refs: list[Any]) -> Optional[int]:
         return tuning.binarize_footprint(bn, bf, borders.shape[0],
                                          bins_bytes=ib(out))
     if cell.op == "leaf_index":
+        bins, model, out = refs[0], refs[1], refs[-1]
+        bt, bn = out.shape                 # tree-major index block
         if cell.layout == "depth_major":
-            bins, onehot, _sb, _pow2, out = refs
-            bt, d, f = onehot.shape
-            return tuning.leaf_index_footprint(bins.shape[0], bt, f, d,
+            _, d, f = model.shape
+            return tuning.leaf_index_footprint(bn, bt, f, d,
                                                bins_bytes=ib(bins))
-        bins, sf, _sb, out = refs
         if cell.layout == "bitpacked":
-            d, bt = sf.shape
             return tuning.leaf_index_footprint(
-                bins.shape[0], bt, bins.shape[1], d,
+                bn, bt, bins.shape[1], model.shape[0],
                 bins_bytes=ib(bins), gather="bitplane")
-        bt, d = sf.shape
-        return tuning.leaf_index_footprint(bins.shape[0], bt,
-                                           bins.shape[1], d,
+        return tuning.leaf_index_footprint(bn, bt, bins.shape[1],
+                                           model.shape[1],
                                            bins_bytes=ib(bins))
     if cell.op == "leaf_gather":
         idx, lv, _out = refs
-        bt, l, c = lv.shape
-        return tuning.leaf_gather_footprint(idx.shape[0], bt, l, c)
+        bt, bn = idx.shape
+        _, l, c = lv.shape
+        return tuning.leaf_gather_footprint(bn, bt, l, c)
     if cell.op == "fused_predict":
+        # x, borders, <layout's model arrays>, lv, out, idx, bins scratch
+        x, borders, model = refs[0], refs[1], refs[2]
+        lv, idx, scratch = refs[-4], refs[-2], refs[-1]
+        bt = idx.shape[0]
         if cell.layout == "depth_major":
-            x, borders, onehot, _sb, _pow2, lv, _out, scratch = refs
-            bt, d, f = onehot.shape
+            d = model.shape[1]
+        elif cell.layout == "bitpacked":
+            d = model.shape[0]
         else:
-            x, borders, sf, _sb, lv, _out, scratch = refs
-            if cell.layout == "bitpacked":
-                d, bt = sf.shape
-            else:
-                bt, d = sf.shape
-            f = x.shape[1]
+            d = model.shape[1]
         gather = "bitplane" if cell.layout == "bitpacked" else "mxu"
         _, l, c = lv.shape
-        return tuning.fused_footprint(x.shape[0], bt, f, d, l, c,
+        return tuning.fused_footprint(x.shape[0], bt, x.shape[1], d, l, c,
                                       borders.shape[0],
                                       bins_bytes=ib(scratch),
                                       gather=gather)
     if cell.op == "histogram":
-        bins, _leaf, g, out = refs
-        bf, bn = bins.shape
-        s = out.shape[1]                   # n_leaves * n_bins, fused dim
-        return tuning.hist_footprint(bf, bn, 1, s, g.shape[1],
+        bins, _leaf, g, e_leaf, _e_stat, out = refs
+        bf, _, bn = bins.shape
+        return tuning.hist_footprint(bf, bn, e_leaf.shape[0],
+                                     out.shape[1], g.shape[1],
                                      bins_bytes=ib(bins))
     return None  # l2sq: no footprint model — budget check only
 
 
 def vmem_audit(cell: Cell, closed: Any) -> tuple[list[Finding], int]:
     """Per-pallas-kernel working-set estimate (resident ref blocks +
-    peak live interior values) vs the VMEM budget and the op's tuning
-    footprint model.  Returns (findings, kernels_audited)."""
+    peak live interior values, each priced tile-padded as Mosaic
+    allocates it) vs the VMEM budget and the op's tuning footprint
+    model, which counts the same padding.  Returns (findings,
+    kernels_audited)."""
     from repro.kernels import tuning
 
     out: list[Finding] = []
@@ -218,8 +219,9 @@ def vmem_audit(cell: Cell, closed: Any) -> tuple[list[Finding], int]:
     for eqn in calls:
         refs = jt.pallas_ref_avals(eqn)
         body = jt.pallas_kernel_jaxpr(eqn)
-        est = sum(jt.aval_bytes(a) for a in refs) \
-            + jt.peak_live_bytes(body, include_invars=False)
+        est = sum(jt.vmem_aval_bytes(a) for a in refs) \
+            + jt.peak_live_bytes(body, include_invars=False,
+                                 size=jt.vmem_aval_bytes)
         if est > tuning.VMEM_BUDGET:
             out.append(_finding(
                 cell, "vmem-budget",
@@ -276,7 +278,7 @@ def entry_findings(name: str, closed: Any) -> list[Finding]:
                     cell, "transfer",
                     "device_put staged inside the traced entry — "
                     "host->device transfer on every call"))
-            elif eqn.primitive.name == "pjit":
+            elif eqn.primitive.name in jt.JIT_PRIMS:
                 donated = eqn.params.get("donated_invars", ())
                 for v, don in zip(eqn.invars, donated):
                     nbytes = jt.aval_bytes(getattr(v, "aval", None))
@@ -326,11 +328,11 @@ def shard_parity_findings(batch_sizes: Any = (8,)) -> list[Finding]:
     must not tick the plans' trace counters (an AbstractMesh cannot be
     compiled against, so a tick means a sharded entry escaped the
     abstract path)."""
-    from repro.compat import abstract_mesh
+    import jax
     from repro.core.predictor import Predictor
     from repro.analysis.matrix import canonical_ensemble
 
-    mesh = abstract_mesh((4,), ("data",))
+    mesh = jax.sharding.AbstractMesh((4,), ("data",))
     sizes = [n for n in batch_sizes if n % 4 == 0] or [8]
     ens, _ = canonical_ensemble()
     out: list[Finding] = []

@@ -48,6 +48,7 @@ identical leaf indices, identical per-tree leaf values.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Optional
 
 import jax
@@ -55,6 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops
+from repro.kernels.leaf_index import BP_TREE_BLOCK
 from repro.kernels.ops import FEATURE_ALIGN, PAD_SPLIT_BIN
 
 # T-axis alignment of the prepadded staged path (the leaf_index /
@@ -424,6 +426,10 @@ def shard_trees(lowered: LoweredEnsemble, n_shards: int, *,
                                    n_model_pads=lowered.n_model_pads)
                 for gs in shard_groups]
     if isinstance(lowered, BitpackedLayout):
+        if all(g.n_trees % BP_TREE_BLOCK == 0 for g in lowered.groups):
+            # lowered for the pallas bitplane kernels: every shard keeps
+            # whole 128-tree lanes
+            t_align = math.lcm(max(t_align, 1), BP_TREE_BLOCK)
         shard_groups = [[] for _ in range(n_shards)]
         for g in lowered.groups:
             total, per = _shard_bounds(g.n_trees, n_shards, t_align)
@@ -594,12 +600,16 @@ def lower(ensemble, layout: str = "soa", *, backend: str = "ref",
     multiples; anything else keeps exact shapes — the jnp reference
     kernels accept any shape, so padding would only add wasted math).
     `t_align` is the tree-axis block (the fused plan's block_t, or
-    `STAGED_TREE_ALIGN`); `tree_block` enables the staged soa
-    tree-blocked loop (soa layout only).
+    `STAGED_TREE_ALIGN`); the pallas bitplane kernels put trees on the
+    lane axis, so bitpacked groups pad to whole 128-tree lanes on top.
+    `tree_block` enables the staged soa tree-blocked loop (soa layout
+    only).
     """
     if layout not in LAYOUTS:
         raise ValueError(f"unknown layout {layout!r}; known: "
                          f"{LAYOUT_NAMES}")
+    if layout == "bitpacked" and backend == "pallas":
+        t_align = math.lcm(max(int(t_align), 1), BP_TREE_BLOCK)
     ctx = _LowerCtx(pallas=backend == "pallas", t_align=t_align)
     if layout == "soa":
         return _lower_soa(ensemble, ctx, tree_block)

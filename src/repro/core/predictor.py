@@ -45,6 +45,7 @@ paper benchmarks.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import pathlib
 import threading
@@ -53,7 +54,7 @@ from typing import Any, Callable, Literal, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import layout as layout_mod
 from repro.core.layout import LoweredEnsemble, STAGED_TREE_ALIGN
@@ -336,7 +337,7 @@ class Predictor:
             self._on_trace()
 
     def _make_entry(self, name: str, impl: Callable) -> Callable:
-        def traced(x):
+        def traced(p, x):
             # Body runs only when jax traces a new shape; counting here
             # counts exactly the XLA compiles for this entry point (and
             # keeps shape bookkeeping off the cached-dispatch hot path).
@@ -349,7 +350,11 @@ class Predictor:
                 _TRACER.instant(f"compile/{name}", "compile",
                                 entry=name, layout=self.config.layout,
                                 batch=int(x.shape[0]))
-            return impl(x)
+            return impl(p, x)
+        # The lowered model is an argument, not a closure: a constant
+        # would be baked into every executable (one device copy of the
+        # leaf table per entry and batch bucket, and executables too
+        # large for the persistent compile cache).
         return jax.jit(traced)
 
     def _ensure_prepared(self) -> LoweredEnsemble:
@@ -366,7 +371,8 @@ class Predictor:
                     self._lower_time_s = secs
         return p
 
-    def _accumulate_trees(self, bins: jax.Array) -> jax.Array:
+    def _accumulate_trees(self, p: LoweredEnsemble,
+                          bins: jax.Array) -> jax.Array:
         """Staged index+gather over the lowered model, from bins.
 
         Shared by the float path (after its binarize stage) and the
@@ -383,48 +389,50 @@ class Predictor:
         cfg = self.config
         block_t = (cfg.block_t if cfg.strategy == "fused"
                    else STAGED_TREE_ALIGN)
-        return self._lowered.leaf_sum(bins, backend=cfg.backend,
-                                      block_t=block_t)
+        return p.leaf_sum(bins, backend=cfg.backend, block_t=block_t)
 
-    def _raw_impl(self, x: jax.Array) -> jax.Array:
-        cfg, p = self.config, self._lowered
+    def _raw_impl(self, p: LoweredEnsemble, x: jax.Array) -> jax.Array:
+        cfg = self.config
         base = self.ensemble.base_score[None, :]
         if cfg.strategy == "fused":
             return base + p.fused_raw(x, backend=cfg.backend,
                                       block_n=cfg.block_n,
                                       block_t=cfg.block_t)
         bins = ops.binarize_prepadded(x, p.borders, backend=cfg.backend)
-        return base + self._accumulate_trees(bins)
+        return base + self._accumulate_trees(p, bins)
 
-    def _proba_impl(self, x: jax.Array) -> jax.Array:
-        return proba_from_raw(self._raw_impl(x), self.ensemble.n_outputs)
+    def _proba_impl(self, p: LoweredEnsemble, x: jax.Array) -> jax.Array:
+        return proba_from_raw(self._raw_impl(p, x), self.ensemble.n_outputs)
 
-    def _classify_impl(self, x: jax.Array) -> jax.Array:
-        return classify_from_raw(self._raw_impl(x),
+    def _classify_impl(self, p: LoweredEnsemble,
+                       x: jax.Array) -> jax.Array:
+        return classify_from_raw(self._raw_impl(p, x),
                                  self.ensemble.n_outputs)
 
     # -- quantized-pool path (binarize skipped entirely) -------------------
-    def _pool_raw_impl(self, bins: jax.Array) -> jax.Array:
+    def _pool_raw_impl(self, p: LoweredEnsemble,
+                       bins: jax.Array) -> jax.Array:
         # Pool bins carry the unpadded feature axis (shareable across
         # plans); pad data-side up to the lowered borders' aligned F.
-        p = self._lowered
         bins = ops.pad_features(bins, p.borders.shape[1])
         base = self.ensemble.base_score[None, :]
-        return base + self._accumulate_trees(bins)
+        return base + self._accumulate_trees(p, bins)
 
-    def _pool_proba_impl(self, bins: jax.Array) -> jax.Array:
-        return proba_from_raw(self._pool_raw_impl(bins),
+    def _pool_proba_impl(self, p: LoweredEnsemble,
+                         bins: jax.Array) -> jax.Array:
+        return proba_from_raw(self._pool_raw_impl(p, bins),
                               self.ensemble.n_outputs)
 
-    def _pool_classify_impl(self, bins: jax.Array) -> jax.Array:
-        return classify_from_raw(self._pool_raw_impl(bins),
+    def _pool_classify_impl(self, p: LoweredEnsemble,
+                            bins: jax.Array) -> jax.Array:
+        return classify_from_raw(self._pool_raw_impl(p, bins),
                                  self.ensemble.n_outputs)
 
-    def _quantize_impl(self, x: jax.Array) -> jax.Array:
+    def _quantize_impl(self, p: LoweredEnsemble,
+                       x: jax.Array) -> jax.Array:
         # Binarize against the *lowered* borders (zero model-side pads
         # at trace time), then drop the alignment columns so the pool is
         # schema-wide shareable, not plan-layout specific.
-        p = self._lowered
         bins = ops.binarize_u8_prepadded(x, p.borders,
                                          backend=self.config.backend)
         return bins[:, :self.ensemble.n_features]
@@ -448,10 +456,10 @@ class Predictor:
             if not (isinstance(bins, jax.Array)
                     and bins.dtype == jnp.uint8):
                 bins = jnp.asarray(bins, jnp.uint8)
-            return self._entries[name + "_pool"](bins)
+            return self._entries[name + "_pool"](self._lowered, bins)
         if not (isinstance(x, jax.Array) and x.dtype == jnp.float32):
             x = jnp.asarray(x, jnp.float32)   # skip no-op asarray dispatch
-        return self._entries[name](x)
+        return self._entries[name](self._lowered, x)
 
     # -- public entry points -----------------------------------------------
     def quantize(self, x) -> QuantizedPool:
@@ -466,9 +474,9 @@ class Predictor:
                 f"cannot quantize to uint8 bins: ensemble has "
                 f"{self.ensemble.borders.shape[0]} borders "
                 f"(> {MAX_BINS - 1})")
-        self._ensure_prepared()
+        p = self._ensure_prepared()
         x = jnp.asarray(x, jnp.float32)
-        return QuantizedPool(self._entries["quantize"](x),
+        return QuantizedPool(self._entries["quantize"](p, x),
                              self.schema_fingerprint)
 
     def raw(self, x) -> jax.Array:
@@ -489,11 +497,11 @@ class Predictor:
         """Un-jitted raw scores — for callers that bring their own jit
         (the `core.predict` shim, shard_map bodies).  Accepts floats or
         a `QuantizedPool` like `raw`."""
-        self._ensure_prepared()
+        p = self._ensure_prepared()
         if isinstance(x, QuantizedPool):
             self._check_pool(x)
-            return self._pool_raw_impl(jnp.asarray(x.bins, jnp.uint8))
-        return self._raw_impl(jnp.asarray(x, jnp.float32))
+            return self._pool_raw_impl(p, jnp.asarray(x.bins, jnp.uint8))
+        return self._raw_impl(p, jnp.asarray(x, jnp.float32))
 
     def _shard_raw(self, lw: LoweredEnsemble, data: jax.Array,
                    kind: str, cfg: PredictConfig) -> jax.Array:
@@ -551,8 +559,6 @@ class Predictor:
         shard_map closures are built once per (mesh, axes, strategy,
         shard_axis) and cached on the plan; jit handles per-shape
         caching under that."""
-        from repro.compat import shard_map
-
         key = (id(mesh), tuple(data_axes), model_axis, strategy,
                shard_axis)
         fn = self._sharded_cache.get(key)
@@ -625,21 +631,23 @@ class Predictor:
                     return jax.lax.psum(
                         self._shard_raw(lw, data, kind, cfg), t_axes)
 
-                smapped = shard_map(_local, mesh=mesh,
-                                    in_specs=(P(t_axes), dp),
-                                    out_specs=dp, check_rep=False)
+                model_spec = P(t_axes)
+                smapped = jax.shard_map(_local, mesh=mesh,
+                                        in_specs=(model_spec, dp),
+                                        out_specs=dp, check_vma=False)
                 model_arg = stacked
             else:
                 def _local(lw, data):
                     return self._shard_raw(lw, data, kind, cfg)
 
-                smapped = shard_map(_local, mesh=mesh,
-                                    in_specs=(P(), dp),
-                                    out_specs=dp, check_rep=False)
+                model_spec = P()
+                smapped = jax.shard_map(_local, mesh=mesh,
+                                        in_specs=(model_spec, dp),
+                                        out_specs=dp, check_vma=False)
                 model_arg = lowered
             name = f"sharded_{kind}"
 
-            def _impl(data):
+            def _impl(model, data):
                 self._note_trace(name)
                 with self._lock:
                     self._entry_shapes.add((name,) + tuple(data.shape))
@@ -654,12 +662,16 @@ class Predictor:
                 n_pad = -(-n // n_row) * n_row
                 if n_pad != n:
                     data = ops._pad_dim(data, 0, n_pad, kind="data")
-                out = ens.base_score[None, :] + smapped(model_arg, data)
+                out = ens.base_score[None, :] + smapped(model, data)
                 return out[:n] if n_pad != n else out
 
-            jitted = jax.jit(_impl)
-            entries[(mode, kind)] = jitted
-            return jitted
+            # the model is placed on the mesh once and passed as an
+            # argument (see _make_entry: never a baked-in constant)
+            placed = jax.device_put(model_arg,
+                                    NamedSharding(mesh, model_spec))
+            entry = functools.partial(jax.jit(_impl), placed)
+            entries[(mode, kind)] = entry
+            return entry
 
         n_devices = int(np.prod([int(s) for s in axis_sizes.values()])) \
             if axis_sizes else 1
@@ -697,8 +709,6 @@ class Predictor:
         shard-parity pass abstract-traces.  Rows shard over every mesh
         axis, the lowered model replicates: the jaxpr must not
         all-gather the bins panel back onto one shard."""
-        from repro.compat import shard_map
-
         lowered = self._ensure_prepared()
         cfg = self.config
         dp = P(tuple(mesh.axis_names))
@@ -706,8 +716,8 @@ class Predictor:
         def _local(lw, data):
             return self._shard_raw(lw, data, kind, cfg)
 
-        smapped = shard_map(_local, mesh=mesh, in_specs=(P(), dp),
-                            out_specs=dp, check_rep=False)
+        smapped = jax.shard_map(_local, mesh=mesh, in_specs=(P(), dp),
+                                out_specs=dp, check_vma=False)
         base = self.ensemble.base_score[None, :]
         return lambda data: base + smapped(lowered, data)
 
@@ -734,16 +744,17 @@ class Predictor:
         `sharded_raw` / `sharded_raw_pool`, row-sharded over every
         mesh axis — the contract checker's shard-parity pass reads
         these; batch sizes must divide the mesh."""
-        self._ensure_prepared()
+        p = self._ensure_prepared()
         impls: dict[str, tuple[Callable, Any]] = {
-            "raw": (self._raw_impl, jnp.float32),
-            "proba": (self._proba_impl, jnp.float32),
-            "classify": (self._classify_impl, jnp.float32),
-            "raw_pool": (self._pool_raw_impl, jnp.uint8),
-            "proba_pool": (self._pool_proba_impl, jnp.uint8),
-            "classify_pool": (self._pool_classify_impl, jnp.uint8),
-            "quantize": (self._quantize_impl, jnp.float32),
-        }
+            name: (functools.partial(impl, p), dtype)
+            for name, impl, dtype in (
+                ("raw", self._raw_impl, jnp.float32),
+                ("proba", self._proba_impl, jnp.float32),
+                ("classify", self._classify_impl, jnp.float32),
+                ("raw_pool", self._pool_raw_impl, jnp.uint8),
+                ("proba_pool", self._pool_proba_impl, jnp.uint8),
+                ("classify_pool", self._pool_classify_impl, jnp.uint8),
+                ("quantize", self._quantize_impl, jnp.float32))}
         mesh_key = None
         if mesh is not None:
             mesh_key = tuple(sorted(dict(mesh.shape).items()))

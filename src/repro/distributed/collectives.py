@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import pvary, shard_map
+from jax import shard_map
 
 
 # --------------------------------------------------------------------------
@@ -93,7 +93,7 @@ def ring_allgather_matmul(mesh: Mesh, axis: str = "model") -> Callable:
         acc0 = jnp.zeros((x.shape[0], w_shard.shape[1]), x.dtype)
         # mark the accumulator as device-varying over the ring axis so the
         # loop carry types line up with the permuted weight shard
-        acc0 = pvary(acc0, (axis,))
+        acc0 = jax.lax.pcast(acc0, (axis,), to="varying")
         acc, _, _ = jax.lax.fori_loop(0, n_shards, body,
                                       (acc0, w_shard, idx))
         return acc
@@ -132,12 +132,12 @@ def ring_attention(mesh: Mesh, *, axis: str = "model",
         scale = Dh ** -0.5
         qpos = q_off + jnp.arange(S_loc)
 
-        o0 = pvary(jnp.zeros((B, KVH, G, S_loc, Dh), jnp.float32),
-                   (axis,))
-        m0 = pvary(jnp.full((B, KVH, G, S_loc), -1e30, jnp.float32),
-                   (axis,))
-        l0 = pvary(jnp.zeros((B, KVH, G, S_loc), jnp.float32),
-                   (axis,))
+        o0 = jax.lax.pcast(jnp.zeros((B, KVH, G, S_loc, Dh), jnp.float32),
+                           (axis,), to="varying")
+        m0 = jax.lax.pcast(jnp.full((B, KVH, G, S_loc), -1e30, jnp.float32),
+                           (axis,), to="varying")
+        l0 = jax.lax.pcast(jnp.zeros((B, KVH, G, S_loc), jnp.float32),
+                           (axis,), to="varying")
 
         def step(j, carry):
             o, m, l, kc, vc = carry

@@ -15,9 +15,9 @@ metrics with `ServerMetrics.merge`.
 """
 from __future__ import annotations
 
+import jax
 import numpy as np
-
-from repro.compat import make_mesh
+from jax.sharding import AxisType
 
 
 def replica_submeshes(mesh, n_replicas: int, *, axis_name: str = None):
@@ -45,6 +45,6 @@ def replica_submeshes(mesh, n_replicas: int, *, axis_name: str = None):
             "equal replica groups")
     per = len(devices) // n_replicas
     axis = axis_name if axis_name is not None else mesh.axis_names[0]
-    return [make_mesh((per,), (axis,),
-                      devices=devices[i * per:(i + 1) * per])
+    return [jax.make_mesh((per,), (axis,), (AxisType.Auto,),
+                          devices=devices[i * per:(i + 1) * per])
             for i in range(n_replicas)]

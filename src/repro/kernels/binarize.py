@@ -18,22 +18,28 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import tuning
+
+
+def count_borders(x: jax.Array, borders_ref, n_borders: int) -> jax.Array:
+    """#{b : x > borders[b]} per element -> int32, one border row per
+    loop step.  The row is read through the ref (`pl.ds`), so the
+    compiler emits a dynamic sublane load rather than slicing a value.
+    Shared by the standalone binarize kernel and the fused kernels'
+    stage 1."""
+    def body(b, acc):
+        return acc + (x > borders_ref[pl.ds(b, 1), :]).astype(jnp.int32)
+
+    return jax.lax.fori_loop(0, n_borders, body,
+                             jnp.zeros(x.shape, jnp.int32))
+
 
 def _binarize_kernel(x_ref, borders_ref, out_ref, *, n_borders: int):
-    x = x_ref[...]                       # (bn, bf) f32
-    borders = borders_ref[...]           # (B, bf)  f32
-
-    def body(b, acc):
-        border_row = jax.lax.dynamic_index_in_dim(borders, b, axis=0,
-                                                  keepdims=True)  # (1, bf)
-        return acc + (x > border_row).astype(jnp.int32)
-
-    acc0 = jnp.zeros(x.shape, dtype=jnp.int32)
     # Accumulate in int32 (the compare-add loop), store in the output
     # dtype: uint8 for the quantized-pool path (the paper's one-byte bin
     # stream — vadd_vv_u8m1_m accumulates in u8 directly), int32 legacy.
-    out_ref[...] = jax.lax.fori_loop(0, n_borders, body, acc0).astype(
-        out_ref.dtype)
+    out_ref[...] = count_borders(x_ref[...], borders_ref,
+                                 n_borders).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -46,8 +52,8 @@ def binarize(x: jax.Array, borders: jax.Array, *, block_n: int = 256,
 
     Inputs must be pre-padded: N % block_n == 0, F % block_f == 0 (ops.py
     handles padding).  Padded border rows must be +inf.  `out_dtype`
-    uint8 requires B <= 255 (validated in ops.py; 8-bit stores use the
-    (32, 128) tile on real TPUs — interpret mode has no such constraint).
+    uint8 requires B <= 255 (validated in ops.py) and a block_n that is
+    a multiple of 32 (uint8 stores tile (32, 128)).
     """
     N, F = x.shape
     B = borders.shape[0]
@@ -61,5 +67,6 @@ def binarize(x: jax.Array, borders: jax.Array, *, block_n: int = 256,
         ],
         out_specs=pl.BlockSpec((block_n, block_f), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((N, F), out_dtype),
+        compiler_params=tuning.compiler_params("parallel", "parallel"),
         interpret=interpret,
     )(x, borders)

@@ -7,7 +7,10 @@ memory-bound (sub-1 FLOP/byte on the scalar path), fusing removes the
 intermediate `bins` (N x F int32) and `idx` (N x T int32) HBM traffic
 entirely.  Binarized features are computed once per sample block at
 t-block 0 into VMEM scratch and reused for every tree block (the grid's
-T axis is serial on TPU).
+T axis is serial on TPU).  The three stages are the standalone kernels'
+own bodies (`binarize.count_borders`, `leaf_index.*_index`,
+`leaf_gather.accumulate_leaves`), so fused and staged plans run the same
+arithmetic.
 
 Grid: (N / block_n, T / block_t).
 """
@@ -20,64 +23,59 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.leaf_index import _bp_compare_planes
+from repro.kernels import tuning
+from repro.kernels.binarize import count_borders
+from repro.kernels.leaf_gather import accumulate_into, accumulate_leaves
+from repro.kernels.leaf_index import (BP_TREE_BLOCK, bitplane_index,
+                                      depth_major_index, soa_index)
+
+
+def _binarize_once(x_ref, borders_ref, bins_scratch, n_borders: int):
+    """Stage 1, once per sample block: bins into the VMEM scratch
+    (uint8 when the ensemble fits 255 borders: 4x less VMEM held
+    across every tree block — the quantized-pool representation,
+    in-kernel)."""
+    @pl.when(pl.program_id(1) == 0)
+    def _binarize():
+        bins_scratch[...] = count_borders(
+            x_ref[...], borders_ref, n_borders).astype(bins_scratch.dtype)
 
 
 def _fused_kernel(x_ref, borders_ref, sf_ref, sb_ref, lv_ref, out_ref,
-                  bins_scratch, *, n_borders: int):
-    t_blk = pl.program_id(1)
+                  idx_scratch, bins_scratch, *, n_borders: int):
+    _binarize_once(x_ref, borders_ref, bins_scratch, n_borders)
+    idx_scratch[...] = soa_index(bins_scratch[...], sf_ref[...],
+                                 sb_ref[...])
+    accumulate_into(out_ref, accumulate_leaves(idx_scratch, lv_ref))
 
-    # ---- Stage 1: binarize (once per sample block, persisted in VMEM) ----
-    @pl.when(t_blk == 0)
-    def _binarize():
-        x = x_ref[...]                               # (bn, F)
-        borders = borders_ref[...]                   # (B, F)
 
-        def body(b, acc):
-            row = jax.lax.dynamic_index_in_dim(borders, b, axis=0,
-                                               keepdims=True)
-            return acc + (x > row).astype(jnp.int32)
-
-        # accumulate in int32, store in the scratch dtype (uint8 when
-        # the ensemble fits 255 borders: 4x less VMEM held across every
-        # tree block — the quantized-pool representation, in-kernel)
-        bins_scratch[...] = jax.lax.fori_loop(
-            0, n_borders, body,
-            jnp.zeros(x.shape, jnp.int32)).astype(bins_scratch.dtype)
-
-    bins = bins_scratch[...].astype(jnp.float32)     # (bn, F)
-    sf = sf_ref[...]                                 # (bt, D)
-    sb = sb_ref[...]                                 # (bt, D)
-    lv = lv_ref[...]                                 # (bt, L, C)
-    bt, D = sf.shape
-    bn, F = bins.shape
-    _, L, C = lv.shape
-
-    # ---- Stage 2: leaf index (one-hot feature gather on the MXU) ----
-    f_iota = jax.lax.broadcasted_iota(jnp.int32, (bt * D, F), 1)
-    onehot_f = (f_iota == sf.reshape(bt * D, 1)).astype(jnp.float32)
-    gathered = jax.lax.dot(onehot_f, bins.T,
-                           preferred_element_type=jnp.float32)
-    gathered = gathered.reshape(bt, D, bn)
-    go_right = gathered >= sb[:, :, None].astype(jnp.float32)
-    pow2 = (1 << jax.lax.broadcasted_iota(jnp.int32, (1, D, 1), 1)).astype(
-        jnp.float32)
-    idx = jnp.sum(go_right.astype(jnp.float32) * pow2, axis=1)   # (bt, bn)
-    idx = idx.T.astype(jnp.int32)                                # (bn, bt)
-
-    # ---- Stage 3: leaf accumulate (one-hot matmul on the MXU) ----
-    leaf_iota = jax.lax.broadcasted_iota(jnp.int32, (bn, bt, L), 2)
-    onehot_l = (leaf_iota == idx[:, :, None]).astype(jnp.float32)
-    acc = jax.lax.dot(onehot_l.reshape(bn, bt * L), lv.reshape(bt * L, C),
-                      preferred_element_type=jnp.float32)        # (bn, C)
-
-    @pl.when(t_blk == 0)
-    def _init():
-        out_ref[...] = acc
-
-    @pl.when(t_blk != 0)
-    def _accum():
-        out_ref[...] += acc
+def _fused_call(kernel, x, borders, model_args, model_specs, leaf_values,
+                *, T, block_n, block_t, interpret, bins_scratch_dtype,
+                name):
+    N, F = x.shape
+    B = borders.shape[0]
+    _, L, C = leaf_values.shape
+    if N % block_n or T % block_t:
+        raise ValueError(
+            f"{name} requires padded inputs: N={N} % block_n="
+            f"{block_n} and T={T} % block_t={block_t} must be 0 "
+            "(use kernels.ops.fused_predict for automatic padding)")
+    return pl.pallas_call(
+        functools.partial(kernel, n_borders=B),
+        grid=(N // block_n, T // block_t),
+        in_specs=[pl.BlockSpec((block_n, F), lambda i, j: (i, 0)),
+                  pl.BlockSpec((B, F), lambda i, j: (0, 0))]
+        + model_specs
+        + [pl.BlockSpec((block_t, L, C), lambda i, j: (j, 0, 0))],
+        out_specs=pl.BlockSpec((C, block_n), lambda i, j: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((C, N), jnp.float32),
+        # the tree-major index block stage 3 walks row by row, then the
+        # binarized block (kept last: it is the fused kernel's own state)
+        scratch_shapes=[pltpu.VMEM((block_t, block_n), jnp.int32),
+                        pltpu.VMEM((block_n, F), bins_scratch_dtype)],
+        compiler_params=tuning.compiler_params("parallel", "arbitrary"),
+        interpret=interpret,
+    )(x, borders, *model_args, leaf_values)
 
 
 @functools.partial(jax.jit,
@@ -88,97 +86,38 @@ def fused_predict(x: jax.Array, borders: jax.Array, split_features: jax.Array,
                   block_n: int = 128, block_t: int = 16,
                   interpret: bool = False,
                   bins_scratch_dtype=jnp.int32) -> jax.Array:
-    """Fused GBDT predict -> (N, C) float32.
+    """Fused GBDT predict -> (C, N) float32 (class-major, samples on
+    lanes; `kernels.ops` transposes).
 
     Raw kernel entry: N and T must already be multiples of the block
-    shapes and padded trees must carry zero leaf_values and
-    split_bins > #bins (padded samples/features are harmless zeros).
+    shapes (block_n a multiple of 128, block_t of 8), F a multiple of
+    128, and padded trees must carry zero leaf_values and split_bins >
+    #bins (padded samples/features are harmless zeros).
     `kernels.ops.fused_predict` is the public wrapper that performs that
     padding and picks the block shapes from the tuner — call it, not
     this, unless you have pre-padded tensors.  `bins_scratch_dtype`
     uint8 (valid when B <= 255) quarters the VMEM the binarized block
     holds across tree blocks; values are exact either way.
     """
-    N, F = x.shape
-    B = borders.shape[0]
     T, D = split_features.shape
-    _, L, C = leaf_values.shape
-    if N % block_n or T % block_t:
-        raise ValueError(
-            f"fused_predict requires padded inputs: N={N} % block_n="
-            f"{block_n} and T={T} % block_t={block_t} must be 0 "
-            "(use kernels.ops.fused_predict for automatic padding)")
-    grid = (N // block_n, T // block_t)
-    return pl.pallas_call(
-        functools.partial(_fused_kernel, n_borders=B),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n, F), lambda i, j: (i, 0)),
-            pl.BlockSpec((B, F), lambda i, j: (0, 0)),
-            pl.BlockSpec((block_t, D), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_t, D), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_t, L, C), lambda i, j: (j, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_n, C), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((N, C), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_n, F), bins_scratch_dtype)],
-        interpret=interpret,
-    )(x, borders, split_features, split_bins, leaf_values)
+    spec = pl.BlockSpec((block_t, D), lambda i, j: (j, 0))
+    return _fused_call(_fused_kernel, x, borders,
+                       (split_features, split_bins), [spec, spec],
+                       leaf_values, T=T, block_n=block_n, block_t=block_t,
+                       interpret=interpret,
+                       bins_scratch_dtype=bins_scratch_dtype,
+                       name="fused_predict")
 
 
 def _fused_dm_kernel(x_ref, borders_ref, onehot_ref, sb_ref, pow2_ref,
-                     lv_ref, out_ref, bins_scratch, *, n_borders: int):
-    t_blk = pl.program_id(1)
-
-    # ---- Stage 1: binarize (identical to the soa kernel) ----
-    @pl.when(t_blk == 0)
-    def _binarize():
-        x = x_ref[...]                               # (bn, F)
-        borders = borders_ref[...]                   # (B, F)
-
-        def body(b, acc):
-            row = jax.lax.dynamic_index_in_dim(borders, b, axis=0,
-                                               keepdims=True)
-            return acc + (x > row).astype(jnp.int32)
-
-        bins_scratch[...] = jax.lax.fori_loop(
-            0, n_borders, body,
-            jnp.zeros(x.shape, jnp.int32)).astype(bins_scratch.dtype)
-
-    bins = bins_scratch[...].astype(jnp.float32)     # (bn, F)
-    onehot = onehot_ref[...]                         # (bt, D, F) f32
-    sb = sb_ref[...]                                 # (D, bt) int32
-    pow2 = pow2_ref[...]                             # (D, 1) f32
-    lv = lv_ref[...]                                 # (bt, L, C)
-    bt, D, F = onehot.shape
-    bn = bins.shape[0]
-    _, L, C = lv.shape
-
-    # ---- Stage 2: leaf index via the PRECOMPUTED one-hot ----
-    # The soa kernel rebuilds iota + one-hot from split_features every
-    # call; the depth-major layout hoists that to lower time, so stage 2
-    # is a single MXU matmul against the lowered gather matrix.
-    gathered = jax.lax.dot(onehot.reshape(bt * D, F), bins.T,
-                           preferred_element_type=jnp.float32)
-    gathered = gathered.reshape(bt, D, bn)
-    go_right = gathered >= sb.T[:, :, None].astype(jnp.float32)
-    idx = jnp.sum(go_right.astype(jnp.float32)
-                  * pow2.reshape(1, D, 1), axis=1)               # (bt, bn)
-    idx = idx.T.astype(jnp.int32)                                # (bn, bt)
-
-    # ---- Stage 3: leaf accumulate (identical to the soa kernel) ----
-    leaf_iota = jax.lax.broadcasted_iota(jnp.int32, (bn, bt, L), 2)
-    onehot_l = (leaf_iota == idx[:, :, None]).astype(jnp.float32)
-    acc = jax.lax.dot(onehot_l.reshape(bn, bt * L), lv.reshape(bt * L, C),
-                      preferred_element_type=jnp.float32)        # (bn, C)
-
-    @pl.when(t_blk == 0)
-    def _init():
-        out_ref[...] = acc
-
-    @pl.when(t_blk != 0)
-    def _accum():
-        out_ref[...] += acc
+                     lv_ref, out_ref, idx_scratch, bins_scratch, *,
+                     n_borders: int):
+    _binarize_once(x_ref, borders_ref, bins_scratch, n_borders)
+    # Stage 2 via the PRECOMPUTED one-hot: the depth-major layout hoists
+    # the iota / one-hot build to lower time.
+    idx_scratch[...] = depth_major_index(bins_scratch[...], onehot_ref,
+                                         sb_ref[...], pow2_ref)
+    accumulate_into(out_ref, accumulate_leaves(idx_scratch, lv_ref))
 
 
 @functools.partial(jax.jit,
@@ -190,155 +129,57 @@ def fused_predict_dm(x: jax.Array, borders: jax.Array, onehot: jax.Array,
                      block_n: int = 128, block_t: int = 16,
                      interpret: bool = False,
                      bins_scratch_dtype=jnp.int32) -> jax.Array:
-    """Fused GBDT predict over the depth-major lowered layout -> (N, C).
+    """Fused GBDT predict over the depth-major lowered layout -> (C, N).
 
     Same contract as `fused_predict` with the model side replaced by
     the `DepthMajorLayout` arrays: `onehot` (T, D, F) f32 precomputed
-    one-hot(sf), `split_bins_dm` (D, T) int32 bit planes, `pow2`
-    (D, 1) f32.  N and T must be pre-padded to the block multiples.
+    one-hot(sf), `split_bins_dm` (D, T) int32 bit planes (read
+    tree-major: transposed here, a (T, D) int32 array), `pow2` (D, 1)
+    f32.  N and T must be pre-padded to the block multiples.
     """
-    N, F = x.shape
-    B = borders.shape[0]
-    T, D, _ = onehot.shape
-    _, L, C = leaf_values.shape
-    if N % block_n or T % block_t:
-        raise ValueError(
-            f"fused_predict_dm requires padded inputs: N={N} % block_n="
-            f"{block_n} and T={T} % block_t={block_t} must be 0 "
-            "(lowering pads the model; use the plan API)")
-    grid = (N // block_n, T // block_t)
-    return pl.pallas_call(
-        functools.partial(_fused_dm_kernel, n_borders=B),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n, F), lambda i, j: (i, 0)),
-            pl.BlockSpec((B, F), lambda i, j: (0, 0)),
-            pl.BlockSpec((block_t, D, F), lambda i, j: (j, 0, 0)),
-            pl.BlockSpec((D, block_t), lambda i, j: (0, j)),
-            pl.BlockSpec((D, 1), lambda i, j: (0, 0)),
-            pl.BlockSpec((block_t, L, C), lambda i, j: (j, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_n, C), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((N, C), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_n, F), bins_scratch_dtype)],
-        interpret=interpret,
-    )(x, borders, onehot, split_bins_dm, pow2, leaf_values)
+    T, D, F = onehot.shape
+    return _fused_call(
+        _fused_dm_kernel, x, borders, (onehot, split_bins_dm.T, pow2),
+        [pl.BlockSpec((block_t, D, F), lambda i, j: (j, 0, 0)),
+         pl.BlockSpec((block_t, D), lambda i, j: (j, 0)),
+         pl.BlockSpec(memory_space=pltpu.SMEM)],
+        leaf_values, T=T, block_n=block_n, block_t=block_t,
+        interpret=interpret, bins_scratch_dtype=bins_scratch_dtype,
+        name="fused_predict_dm")
 
 
 def _fused_bp_kernel(x_ref, borders_ref, sf_ref, sb_ref, lv_ref, out_ref,
-                     bins_scratch, *, n_borders: int):
-    t_blk = pl.program_id(1)
-
-    # ---- Stage 1: binarize (identical to the soa kernel) ----
-    @pl.when(t_blk == 0)
-    def _binarize():
-        x = x_ref[...]                               # (bn, F)
-        borders = borders_ref[...]                   # (B, F)
-
-        def body(b, acc):
-            row = jax.lax.dynamic_index_in_dim(borders, b, axis=0,
-                                               keepdims=True)
-            return acc + (x > row).astype(jnp.int32)
-
-        bins_scratch[...] = jax.lax.fori_loop(
-            0, n_borders, body,
-            jnp.zeros(x.shape, jnp.int32)).astype(bins_scratch.dtype)
-
-    bins = bins_scratch[...]                         # (bn, F) — stays integer
-    sf = sf_ref[...]                                 # (D, bt) int32
-    sb = sb_ref[...]                                 # (D, bt) int32
-    lv = lv_ref[...]                                 # (bt, L, C)
-    D, bt = sf.shape
-    bn = bins.shape[0]
-    _, L, C = lv.shape
-
-    # ---- Stage 2: leaf index via bitpacked shift/or (no MXU) ----
-    # Per depth the comparison is one bit per doc; 32-doc columns pack
-    # into uint32 lane words and the index register accumulates bit d
-    # with shift/or — integers end to end, no one-hot materialization.
-    # A uint8 bins scratch (<= 255 borders) also compares unwidened:
-    # thresholds narrow to uint8 with the PAD_SPLIT_BIN sentinel kept
-    # as a mask (see leaf_index._bp_compare_planes), so the panel is
-    # never upcast to int32.
-    narrow = bins.dtype == jnp.uint8
-    if narrow:
-        sb_u8, live = _bp_compare_planes(sb)
-    w = bn // 32
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, 32, bt), 1)
-    idx = jnp.zeros((bn, bt), jnp.int32)
-    for d in range(D):                               # static unroll over depth
-        cols = jnp.take(bins, sf[d], axis=1)         # (bn, bt) integer gather
-        if narrow:
-            go = (cols >= sb_u8[d][None, :]) & live[d][None, :]
-        else:
-            go = cols >= sb[d][None, :]
-        bit = go.astype(jnp.uint32)
-        words = jnp.sum(bit.reshape(w, 32, bt) << shifts, axis=1,
-                        dtype=jnp.uint32)            # (w, bt) lane words
-        plane = ((words[:, None, :] >> shifts) & jnp.uint32(1)
-                 ).reshape(bn, bt).astype(jnp.int32)
-        idx = idx | (plane << d)
-
-    # ---- Stage 3: leaf accumulate (one-hot matmul, as in soa) ----
-    # Gathering leaf values is the one stage where the MXU one-hot
-    # earns its keep; the bitpacked win is confined to index assembly.
-    leaf_iota = jax.lax.broadcasted_iota(jnp.int32, (bn, bt, L), 2)
-    onehot_l = (leaf_iota == idx[:, :, None]).astype(jnp.float32)
-    acc = jax.lax.dot(onehot_l.reshape(bn, bt * L), lv.reshape(bt * L, C),
-                      preferred_element_type=jnp.float32)        # (bn, C)
-
-    @pl.when(t_blk == 0)
-    def _init():
-        out_ref[...] = acc
-
-    @pl.when(t_blk != 0)
-    def _accum():
-        out_ref[...] += acc
+                     idx_scratch, bins_scratch, *, n_borders: int):
+    _binarize_once(x_ref, borders_ref, bins_scratch, n_borders)
+    # Stage 2 via the integer bit-plane pipeline (no MXU, no one-hot);
+    # the leaf gather of stage 3 is where the MXU one-hot earns its keep.
+    idx_scratch[...] = bitplane_index(bins_scratch[...], sf_ref[...],
+                                      sb_ref[...]).T
+    accumulate_into(out_ref, accumulate_leaves(idx_scratch, lv_ref))
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("block_n", "block_t", "interpret",
+                   static_argnames=("block_n", "interpret",
                                     "bins_scratch_dtype"))
 def fused_predict_bp(x: jax.Array, borders: jax.Array,
                      split_features_bp: jax.Array, split_bins_bp: jax.Array,
                      leaf_values: jax.Array, *,
-                     block_n: int = 128, block_t: int = 16,
+                     block_n: int = 128,
                      interpret: bool = False,
                      bins_scratch_dtype=jnp.int32) -> jax.Array:
-    """Fused GBDT predict over the bitpacked lowered layout -> (N, C).
+    """Fused GBDT predict over the bitpacked lowered layout -> (C, N).
 
     Same contract as `fused_predict` with the model side replaced by
     the `BitpackedLayout` bit-plane arrays: `split_features_bp` /
-    `split_bins_bp`, both (D, T).  N and T must be pre-padded to the
-    block multiples and block_n must be a multiple of 32 (whole uint32
-    doc lanes).
+    `split_bins_bp`, both (D, T).  Trees ride the lane axis, one
+    128-tree lane per block: T and F must be multiples of 128.
     """
-    N, F = x.shape
-    B = borders.shape[0]
     D, T = split_features_bp.shape
-    _, L, C = leaf_values.shape
-    if N % block_n or T % block_t:
-        raise ValueError(
-            f"fused_predict_bp requires padded inputs: N={N} % block_n="
-            f"{block_n} and T={T} % block_t={block_t} must be 0 "
-            "(lowering pads the model; use the plan API)")
-    if block_n % 32:
-        raise ValueError(f"fused_predict_bp packs 32-doc uint32 lanes: "
-                         f"block_n={block_n} must be a multiple of 32")
-    grid = (N // block_n, T // block_t)
-    return pl.pallas_call(
-        functools.partial(_fused_bp_kernel, n_borders=B),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n, F), lambda i, j: (i, 0)),
-            pl.BlockSpec((B, F), lambda i, j: (0, 0)),
-            pl.BlockSpec((D, block_t), lambda i, j: (0, j)),
-            pl.BlockSpec((D, block_t), lambda i, j: (0, j)),
-            pl.BlockSpec((block_t, L, C), lambda i, j: (j, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_n, C), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((N, C), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_n, F), bins_scratch_dtype)],
-        interpret=interpret,
-    )(x, borders, split_features_bp.astype(jnp.int32),
-      split_bins_bp.astype(jnp.int32), leaf_values)
+    spec = pl.BlockSpec((D, BP_TREE_BLOCK), lambda i, j: (0, j))
+    return _fused_call(
+        _fused_bp_kernel, x, borders,
+        (split_features_bp.astype(jnp.int32),
+         split_bins_bp.astype(jnp.int32)), [spec, spec],
+        leaf_values, T=T, block_n=block_n, block_t=BP_TREE_BLOCK,
+        interpret=interpret, bins_scratch_dtype=bins_scratch_dtype,
+        name="fused_predict_bp")

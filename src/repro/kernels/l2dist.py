@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import tuning
+
 
 # --------------------------------------------------------------------------
 # Row-wise (paper-faithful) form
@@ -56,6 +58,7 @@ def l2sq_rowwise(q: jax.Array, refs: jax.Array, *, block_n: int = 256,
         ],
         out_specs=pl.BlockSpec((block_n, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N, 1), jnp.float32),
+        compiler_params=tuning.compiler_params("parallel", "arbitrary"),
         interpret=interpret,
     )(q.reshape(1, K), refs)
     return out[:, 0]
@@ -108,5 +111,7 @@ def l2sq_matrix(a: jax.Array, b: jax.Array, *, block_m: int = 128,
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
+        compiler_params=tuning.compiler_params("parallel", "parallel",
+                                               "arbitrary"),
         interpret=interpret,
     )(a, b, a_sq, b_sq)

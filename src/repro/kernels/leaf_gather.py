@@ -5,9 +5,12 @@ This is the hotspot the paper explicitly could NOT vectorize: RVV 0.7.1
 gather/scatter is too slow to pay for the few arithmetic ops per element
 (their Tables 2-3 show speedup 0.98-1.03x).  The TPU answer is to avoid
 the gather unit entirely: `sum_t leaf_values[t, idx[n, t], :]` becomes a
-one-hot matmul `onehot(idx) @ leaf_values` on the 128x128 MXU.  The
-indirect addressing turns into dense systolic compute — the beyond-paper
-optimization recorded in EXPERIMENTS.md SSPerf.
+one-hot matmul `leaf_values[t]^T @ onehot(idx[:, t])` on the 128x128
+MXU, one tree at a time.  The indirect addressing turns into dense systolic
+compute.
+
+The index arrives tree-major, (T, N) — the layout `leaf_index` writes —
+so its blocks are lane-dense for any tree block that is a multiple of 8.
 
 Grid: (N / block_n, T / block_t) with the T axis as a serial reduction;
 the output tile is initialized at t-block 0 and accumulated in place.
@@ -20,20 +23,42 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import tuning
 
-def _leaf_gather_kernel(idx_ref, lv_ref, out_ref):
+# Leaf values are arbitrary float32: the one-hot contraction must not
+# round them to bfloat16 on the MXU.
+LEAF_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def accumulate_leaves(idx_t_ref, lv_ref) -> jax.Array:
+    """sum_t lv[t, idx_t[t, :], :] over one tree block -> (C, bn) f32.
+
+    `idx_t_ref` is the tree-major (bt, bn) index block, `lv_ref` the
+    (bt, L, C) leaf-value block.  One tree per loop step: its index row
+    against a sublane iota is the (L, bn) one-hot, contracted with that
+    tree's (L, C) table on the MXU.  The one-hot is exact, and the
+    contraction runs at HIGHEST precision so the sum carries full
+    float32 leaf values.  Samples stay on lanes throughout, so the sum
+    comes out (C, bn); `kernels.ops` transposes the (C, N) result.
+    Shared by `leaf_gather` and the fused kernels' stage 3."""
+    bt, bn = idx_t_ref.shape
+    _, L, C = lv_ref.shape
+    leaf_iota = jax.lax.broadcasted_iota(jnp.int32, (L, bn), 0)
+
+    def body(t, acc):
+        onehot = (leaf_iota == idx_t_ref[pl.ds(t, 1), :]).astype(
+            jnp.float32)                                     # (L, bn)
+        return acc + jax.lax.dot_general(
+            lv_ref[t], onehot, (((0,), (0,)), ((), ())),
+            precision=LEAF_PRECISION, preferred_element_type=jnp.float32)
+
+    return jax.lax.fori_loop(0, bt, body, jnp.zeros((C, bn), jnp.float32))
+
+
+def accumulate_into(out_ref, acc: jax.Array) -> None:
+    """out = acc at the first tree block, out += acc after (the grid's
+    tree axis is the serial reduction)."""
     t_blk = pl.program_id(1)
-    idx = idx_ref[...]                                 # (bn, bt) int32
-    lv = lv_ref[...]                                   # (bt, L, C) f32
-    bn, bt = idx.shape
-    _, L, C = lv.shape
-
-    # onehot over the flattened (tree, leaf) axis -> one MXU matmul.
-    leaf_iota = jax.lax.broadcasted_iota(jnp.int32, (bn, bt, L), 2)
-    onehot = (leaf_iota == idx[:, :, None]).astype(jnp.float32)
-    onehot = onehot.reshape(bn, bt * L)
-    acc = jax.lax.dot(onehot, lv.reshape(bt * L, C),
-                      preferred_element_type=jnp.float32)   # (bn, C)
 
     @pl.when(t_blk == 0)
     def _init():
@@ -44,25 +69,37 @@ def _leaf_gather_kernel(idx_ref, lv_ref, out_ref):
         out_ref[...] += acc
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "block_t", "interpret"))
-def leaf_gather(idx: jax.Array, leaf_values: jax.Array, *, block_n: int = 128,
-                block_t: int = 16, interpret: bool = False) -> jax.Array:
-    """pred[n, c] = sum_t leaf_values[t, idx[n, t], c]  -> (N, C) float32.
+def _leaf_gather_kernel(idx_ref, lv_ref, out_ref):
+    accumulate_into(out_ref, accumulate_leaves(idx_ref, lv_ref))
 
-    Pre-padded: N % block_n == 0, T % block_t == 0.  Padded trees must have
-    all-zero leaf_values.
+
+@functools.partial(jax.jit, static_argnames=("block_n", "block_t", "interpret"))
+def leaf_gather(idx_t: jax.Array, leaf_values: jax.Array, *,
+                block_n: int = 128, block_t: int = 16,
+                interpret: bool = False) -> jax.Array:
+    """pred^T[c, n] = sum_t leaf_values[t, idx_t[t, n], c] -> (C, N) f32.
+
+    `idx_t` is the tree-major (T, N) index `leaf_index` writes; the sum
+    comes out class-major too (samples on lanes).
+    Pre-padded: N % block_n == 0 (block_n a multiple of 128), T %
+    block_t == 0.  Padded trees must have all-zero leaf_values.
     """
-    N, T = idx.shape
+    T, N = idx_t.shape
     _, L, C = leaf_values.shape
-    grid = (N // block_n, T // block_t)
+    if N % block_n or T % block_t:
+        raise ValueError(
+            f"leaf_gather requires padded inputs: N={N} % block_n="
+            f"{block_n} and T={T} % block_t={block_t} must be 0 "
+            "(use kernels.ops for automatic padding)")
     return pl.pallas_call(
         _leaf_gather_kernel,
-        grid=grid,
+        grid=(N // block_n, T // block_t),
         in_specs=[
-            pl.BlockSpec((block_n, block_t), lambda i, j: (i, j)),
+            pl.BlockSpec((block_t, block_n), lambda i, j: (j, i)),
             pl.BlockSpec((block_t, L, C), lambda i, j: (j, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((block_n, C), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((N, C), jnp.float32),
+        out_specs=pl.BlockSpec((C, block_n), lambda i, j: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((C, N), jnp.float32),
+        compiler_params=tuning.compiler_params("parallel", "arbitrary"),
         interpret=interpret,
-    )(idx, leaf_values)
+    )(idx_t, leaf_values)

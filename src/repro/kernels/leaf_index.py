@@ -7,10 +7,16 @@ border (vmsgeu) and mask-ORs the shifted bit into the running index.
 
 TPU adaptation: the per-(tree, depth) feature *gather* bins[n, sf[t, d]] —
 the strided-access pattern RVV also struggles with — is reformulated as a
-one-hot matmul on the MXU: onehot(sf) @ bins^T gathers D x block_t feature
-columns for the whole sample block in one systolic pass.  The bit-OR
-accumulation becomes a mask-weighted sum with the power-of-two vector
-precomputed outside the loop (the paper's hoisting trick, verbatim).
+one-hot matmul on the MXU: per depth d, onehot(sf[:, d]) (bt, F) against
+the bins panel (bn, F) gathers the block's feature column for every tree
+and sample in one systolic pass.  The bit-OR accumulation is the paper's
+shifted-bit OR, verbatim.
+
+Layout: the index comes out tree-major, (T, N) — trees on sublanes,
+samples on lanes — so every block is lane-dense for any tree block that
+is a multiple of 8 and the kernel never reshapes between the two axes.
+`kernels.ops` transposes to the public (N, T) contract (within one jit,
+XLA cancels that transpose against `leaf_gather`'s).
 
 Grid: (N / block_n, T / block_t); the bins panel (block_n, F) is VMEM-
 resident for all trees of the block row.
@@ -22,78 +28,151 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import tuning
+
+# Bitpacked kernels put trees on the lane axis: one full lane per block.
+BP_TREE_BLOCK = tuning.BITPLANE_TREE_BLOCK
+
+
+def gather_operand(bins: jax.Array):
+    """The bins panel as an MXU operand -> (operand, dot precision).
+
+    uint8 bin ids (<= 255) are exact in bfloat16, so the one-hot gather
+    runs as one bf16 pass.  The v5e has no uint8 -> float convert, so
+    the panel widens through int32 first — the widening the contract
+    checker sanctions, since its only sink is the matmul.  int32 bins
+    (> 255 borders) may exceed bf16's exact integers and contract in
+    float32 at HIGHEST precision instead."""
+    if bins.dtype == jnp.uint8:
+        return bins.astype(jnp.int32).astype(jnp.bfloat16), None
+    return bins.astype(jnp.float32), jax.lax.Precision.HIGHEST
+
+
+def index_planes(bins_op, precision, onehots, thresholds, weights):
+    """idx^T (bt, bn) = sum_d weights[d] * [onehots[d] . bins >= thr[d]].
+
+    `onehots[d]` is the (bt, F) feature selector of depth d,
+    `thresholds[d]` the (bt, 1) split bins, `weights[d]` the depth's
+    bit value (1 << d, or the depth-major layout's hoisted pow2).  The
+    contraction is over the feature (lane) axis of both operands, so
+    the (bt, bn) result needs no transpose."""
+    bn = bins_op.shape[0]
+    bt = onehots[0].shape[0]
+    idx = jnp.zeros((bt, bn), jnp.float32)
+    for onehot, thr, w in zip(onehots, thresholds, weights):
+        gathered = jax.lax.dot_general(
+            onehot.astype(bins_op.dtype), bins_op,
+            (((1,), (1,)), ((), ())), precision=precision,
+            preferred_element_type=jnp.float32)              # (bt, bn)
+        idx = idx + jnp.where(gathered >= thr, w, 0.0)
+    return idx.astype(jnp.int32)
+
+
+def soa_index(bins, sf, sb):
+    """`index_planes` over (bt, D) split arrays: the one-hot is built
+    per depth from an iota compare against the split feature column."""
+    bins_op, precision = gather_operand(bins)
+    bt, D = sf.shape
+    f_iota = jax.lax.broadcasted_iota(jnp.int32, (bt, bins.shape[1]), 1)
+    return index_planes(
+        bins_op, precision,
+        [f_iota == sf[:, d:d + 1] for d in range(D)],
+        [sb[:, d:d + 1].astype(jnp.float32) for d in range(D)],
+        [float(1 << d) for d in range(D)])
+
+
+def depth_major_index(bins, onehot_ref, sb, pow2_ref):
+    """`index_planes` over the depth-major lowered arrays: the one-hot
+    rows arrive precomputed (the (bt, D, F) block's depth-d plane) and
+    the bit values are the hoisted pow2 vector — no iota, no one-hot
+    construction in the kernel."""
+    bins_op, precision = gather_operand(bins)
+    D = sb.shape[1]
+    # pow2 lives in SMEM: each depth's bit value is a scalar read
+    return index_planes(
+        bins_op, precision,
+        [onehot_ref[:, d, :] for d in range(D)],
+        [sb[:, d:d + 1].astype(jnp.float32) for d in range(D)],
+        [pow2_ref[d, 0] for d in range(D)])
+
+
+def bitplane_index(bins, sf, sb):
+    """Integer-only index assembly over (D, 128) bit planes -> (bn, 128).
+
+    Per depth, each tree's split feature is gathered from the sample
+    panel with a lane gather (trees and features share the lane axis,
+    one 128-feature chunk at a time), compared against the threshold
+    plane, and its bit OR-ed into the index register — no one-hot, no
+    float, no MXU.  The v5e VPU has neither 8-bit compares nor 8-bit
+    lane gathers, so a uint8 panel widens to int32 in registers first."""
+    panel = bins.astype(jnp.int32)                   # (bn, F)
+    bn, F = panel.shape
+    D, bt = sf.shape
+    idx = jnp.zeros((bn, bt), jnp.int32)
+    for d in range(D):
+        cols = jnp.zeros((bn, bt), jnp.int32)
+        for k in range(0, F, bt):
+            local = sf[d:d + 1, :] - k               # (1, bt)
+            hit = (local >= 0) & (local < bt)
+            lanes = jnp.broadcast_to(jnp.clip(local, 0, bt - 1), (bn, bt))
+            got = jnp.take_along_axis(panel[:, k:k + bt], lanes, axis=1,
+                                      mode="promise_in_bounds")
+            cols = jnp.where(hit, got, cols)
+        go = cols >= sb[d:d + 1, :]
+        idx = idx | (go.astype(jnp.int32) << d)
+    return idx
 
 
 def _leaf_index_kernel(bins_ref, sf_ref, sb_ref, out_ref):
-    # bins may arrive int32 (legacy) or uint8 (quantized pool); both
-    # upcast exactly to float32 for the MXU gather (bin ids <= 255 and
-    # split ids < 2^30 are exact in f32).
-    bins = bins_ref[...].astype(jnp.float32)          # (bn, F)
-    sf = sf_ref[...]                                  # (bt, D) int32
-    sb = sb_ref[...]                                  # (bt, D) int32
-    bt, D = sf.shape
-    bn, F = bins.shape
+    out_ref[...] = soa_index(bins_ref[...], sf_ref[...], sb_ref[...])
 
-    # One-hot gather on the MXU: (bt*D, F) @ (F, bn) -> (bt*D, bn)
-    sf_flat = sf.reshape(bt * D, 1)
-    f_iota = jax.lax.broadcasted_iota(jnp.int32, (bt * D, F), 1)
-    onehot = (f_iota == sf_flat).astype(jnp.float32)
-    gathered = jax.lax.dot(onehot, bins.T,
-                           preferred_element_type=jnp.float32)  # (bt*D, bn)
-    gathered = gathered.reshape(bt, D, bn)
 
-    go_right = gathered >= sb[:, :, None].astype(jnp.float32)   # (bt, D, bn)
-    pow2 = (1 << jax.lax.broadcasted_iota(jnp.int32, (1, D, 1), 1)).astype(
-        jnp.float32)
-    idx = jnp.sum(go_right.astype(jnp.float32) * pow2, axis=1)  # (bt, bn)
-    out_ref[...] = idx.T.astype(jnp.int32)                      # (bn, bt)
+def _tree_major_call(kernel, bins, model_args, model_specs, T, *,
+                     block_n, block_t, interpret):
+    """pallas_call shared by the leaf-index variants: grid over (row
+    blocks, tree blocks), bins panel per row block, the tree-major
+    (T, N) index out."""
+    N, F = bins.shape
+    if N % block_n or T % block_t:
+        raise ValueError(
+            f"leaf index kernels require padded inputs: N={N} % block_n="
+            f"{block_n} and T={T} % block_t={block_t} must be 0 "
+            "(use kernels.ops for automatic padding)")
+    return pl.pallas_call(
+        kernel,
+        grid=(N // block_n, T // block_t),
+        in_specs=[pl.BlockSpec((block_n, F), lambda i, j: (i, 0))]
+        + model_specs,
+        out_specs=pl.BlockSpec((block_t, block_n), lambda i, j: (j, i)),
+        out_shape=jax.ShapeDtypeStruct((T, N), jnp.int32),
+        compiler_params=tuning.compiler_params("parallel", "parallel"),
+        interpret=interpret,
+    )(bins, *model_args)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_t", "interpret"))
 def leaf_index(bins: jax.Array, split_features: jax.Array,
                split_bins: jax.Array, *, block_n: int = 256,
                block_t: int = 16, interpret: bool = False) -> jax.Array:
-    """idx[n, t] = sum_d 2^d [bins[n, sf[t,d]] >= sb[t,d]]  -> (N, T) int32.
+    """idx^T[t, n] = sum_d 2^d [bins[n, sf[t,d]] >= sb[t,d]] -> (T, N) int32.
 
-    Pre-padded: N % block_n == 0, T % block_t == 0.  Padded trees must use
+    Pre-padded: N % block_n == 0 (block_n a multiple of 128), T %
+    block_t == 0 (block_t a multiple of 8).  Padded trees must use
     split_bins > max bin (e.g. 2^30) so they contribute leaf 0.
     """
-    N, F = bins.shape
     T, D = split_features.shape
-    grid = (N // block_n, T // block_t)
-    return pl.pallas_call(
-        _leaf_index_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n, F), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_t, D), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_t, D), lambda i, j: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_n, block_t), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((N, T), jnp.int32),
-        interpret=interpret,
-    )(bins, split_features, split_bins)
+    spec = pl.BlockSpec((block_t, D), lambda i, j: (j, 0))
+    return _tree_major_call(_leaf_index_kernel, bins,
+                            (split_features, split_bins), [spec, spec], T,
+                            block_n=block_n, block_t=block_t,
+                            interpret=interpret)
 
 
 def _leaf_index_dm_kernel(bins_ref, onehot_ref, sb_ref, pow2_ref, out_ref):
-    # Depth-major lowered layout: the one-hot feature-gather matrix and
-    # the pow2 vector arrive precomputed (hoisted to lower time), so the
-    # kernel body is the two MXU/VPU passes and nothing else — no iota,
-    # no one-hot construction, no per-call shift building.
-    bins = bins_ref[...].astype(jnp.float32)          # (bn, F)
-    onehot = onehot_ref[...]                          # (bt, D, F) f32
-    sb = sb_ref[...]                                  # (D, bt) int32
-    pow2 = pow2_ref[...]                              # (D, 1) f32
-    bt, D, F = onehot.shape
-    bn = bins.shape[0]
-
-    gathered = jax.lax.dot(onehot.reshape(bt * D, F), bins.T,
-                           preferred_element_type=jnp.float32)  # (bt*D, bn)
-    gathered = gathered.reshape(bt, D, bn)
-    go_right = gathered >= sb.T[:, :, None].astype(jnp.float32)  # (bt, D, bn)
-    idx = jnp.sum(go_right.astype(jnp.float32)
-                  * pow2.reshape(1, D, 1), axis=1)               # (bt, bn)
-    out_ref[...] = idx.T.astype(jnp.int32)                       # (bn, bt)
+    out_ref[...] = depth_major_index(bins_ref[...], onehot_ref,
+                                     sb_ref[...], pow2_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_t",
@@ -102,130 +181,58 @@ def leaf_index_dm(bins: jax.Array, onehot: jax.Array, split_bins_dm: jax.Array,
                   pow2: jax.Array, *, block_n: int = 256, block_t: int = 16,
                   interpret: bool = False) -> jax.Array:
     """Depth-major `leaf_index`: gather via the precomputed one-hot
-    matrix -> (N, T) int32.
+    matrix -> (T, N) int32.
 
     Inputs are the depth-major lowered model arrays (see
     `repro.core.layout.DepthMajorLayout`): `onehot` (T, D, F) f32,
     `split_bins_dm` (D, T) int32 bit-plane order, `pow2` (D, 1) f32.
-    Pre-padded: N % block_n == 0, T % block_t == 0, padded trees carry
-    split_bins > max bin.  `bins` may be int32 or uint8 (the
-    quantized-pool stream) — both upcast exactly to f32.
+    The kernel reads the thresholds tree-major, so the (D, T) planes are
+    transposed once here (a (T, D) int32 array; the one-hot, which
+    dominates, is read in place).  Pre-padded: N % block_n == 0, T %
+    block_t == 0, padded trees carry split_bins > max bin.  `bins` may
+    be int32 or uint8 (the quantized-pool stream).
     """
-    N, F = bins.shape
-    T, D, _ = onehot.shape
-    if N % block_n or T % block_t:
-        raise ValueError(
-            f"leaf_index_dm requires padded inputs: N={N} % block_n="
-            f"{block_n} and T={T} % block_t={block_t} must be 0 "
-            "(lowering pads the model; use the plan API)")
-    grid = (N // block_n, T // block_t)
-    return pl.pallas_call(
-        _leaf_index_dm_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n, F), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_t, D, F), lambda i, j: (j, 0, 0)),
-            pl.BlockSpec((D, block_t), lambda i, j: (0, j)),
-            pl.BlockSpec((D, 1), lambda i, j: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_n, block_t), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((N, T), jnp.int32),
-        interpret=interpret,
-    )(bins, onehot, split_bins_dm, pow2)
-
-
-def _bp_compare_planes(sb):
-    """Narrow the (D, bt) int32 threshold planes for a uint8 compare.
-
-    Real split thresholds on a uint8 pool are <= 255 (bin ids fit one
-    byte), so the compare can run unwidened in uint8 — the paper's
-    vmsgeu on the byte stream.  The PAD_SPLIT_BIN sentinel (2^30, used
-    for padded trees and truncated depths) means "never go right"; it
-    survives the narrowing as an explicit liveness mask, NOT by
-    widening the bins panel to int32 (which would 4x the VMEM the
-    panel holds — the contract checker's working-set audit pins this).
-    """
-    live = sb <= 255                       # (D, bt) bool: real splits
-    return sb.astype(jnp.uint8), live
+    T, D, F = onehot.shape
+    return _tree_major_call(
+        _leaf_index_dm_kernel, bins,
+        (onehot, split_bins_dm.T, pow2),
+        [pl.BlockSpec((block_t, D, F), lambda i, j: (j, 0, 0)),
+         pl.BlockSpec((block_t, D), lambda i, j: (j, 0)),
+         pl.BlockSpec(memory_space=pltpu.SMEM)], T,
+        block_n=block_n, block_t=block_t, interpret=interpret)
 
 
 def _leaf_index_bp_kernel(bins_ref, sf_ref, sb_ref, out_ref):
     # Bitpacked lowered layout: integer-only pipeline, the closest TPU
-    # analog of the paper's RVV loop.  Per depth d the comparison
-    # bins[n, sf[d, t]] >= sb[d, t] is ONE bit per doc; a 32-doc column
-    # packs into a uint32 lane word (the vmsgeu mask register) and the
-    # leaf-index register accumulates bit d via shift/or.  No MXU, no
-    # one-hot materialization, no float arithmetic anywhere — and for
-    # uint8 pool bins the panel is never widened either: the compare
-    # runs in uint8 against the narrowed threshold planes.
-    bins = bins_ref[...]                              # (bn, F) i32 | u8
-    sf = sf_ref[...]                                  # (D, bt) int32
-    sb = sb_ref[...]                                  # (D, bt) int32
-    D, bt = sf.shape
-    bn = bins.shape[0]
-    w = bn // 32
-    narrow = bins.dtype == jnp.uint8
-    if narrow:
-        sb_u8, live = _bp_compare_planes(sb)
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, 32, bt), 1)
-    idx = jnp.zeros((bn, bt), jnp.int32)
-    for d in range(D):                                # static unroll over depth
-        cols = jnp.take(bins, sf[d], axis=1)          # (bn, bt) integer gather
-        if narrow:
-            go = (cols >= sb_u8[d][None, :]) & live[d][None, :]
-        else:
-            go = cols >= sb[d][None, :]
-        bit = go.astype(jnp.uint32)
-        # pack 32-doc lanes into uint32 words: bits are disjoint per
-        # lane position, so the shifted sum IS the bitwise OR
-        words = jnp.sum(bit.reshape(w, 32, bt) << shifts, axis=1,
-                        dtype=jnp.uint32)             # (w, bt) lane words
-        plane = ((words[:, None, :] >> shifts) & jnp.uint32(1)
-                 ).reshape(bn, bt).astype(jnp.int32)
-        idx = idx | (plane << d)
-    out_ref[...] = idx
+    # analog of the paper's RVV loop (see `bitplane_index`).
+    out_ref[...] = bitplane_index(bins_ref[...], sf_ref[...],
+                                  sb_ref[...]).T
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "block_t",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def leaf_index_bp(bins: jax.Array, split_features_bp: jax.Array,
                   split_bins_bp: jax.Array, *, block_n: int = 256,
-                  block_t: int = 16, interpret: bool = False) -> jax.Array:
-    """Bitpacked `leaf_index`: integer shift/or index assembly -> (N, T) int32.
+                  interpret: bool = False) -> jax.Array:
+    """Bitpacked `leaf_index`: integer lane-gather + shift/or index
+    assembly -> (T, N) int32.
 
     Inputs are the bitpacked lowered model arrays (see
     `repro.core.layout.BitpackedLayout`): bit-plane transposed
-    `split_features_bp` / `split_bins_bp`, both (D, T).  Pre-padded:
-    N % block_n == 0 (block_n a multiple of 32 so doc lanes fill whole
-    uint32 words), T % block_t == 0, padded trees carry split_bins >
-    max bin (they pack bit 0 at every depth -> leaf 0).  `bins` may be
-    int32 or uint8 — uint8 compares unwidened against the narrowed
-    threshold planes (see `_bp_compare_planes`), int32 directly.
+    `split_features_bp` / `split_bins_bp`, both (D, T).  Trees ride the
+    lane axis, one 128-tree lane per block.  Pre-padded: N % block_n ==
+    0, T and F multiples of 128, padded trees carry split_bins > max
+    bin (they take bit 0 at every depth -> leaf 0).
     """
-    N, F = bins.shape
     D, T = split_features_bp.shape
-    if N % block_n or T % block_t:
-        raise ValueError(
-            f"leaf_index_bp requires padded inputs: N={N} % block_n="
-            f"{block_n} and T={T} % block_t={block_t} must be 0 "
-            "(lowering pads the model; use the plan API)")
-    if block_n % 32:
-        raise ValueError(f"leaf_index_bp packs 32-doc uint32 lanes: "
-                         f"block_n={block_n} must be a multiple of 32")
-    grid = (N // block_n, T // block_t)
-    return pl.pallas_call(
-        _leaf_index_bp_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n, F), lambda i, j: (i, 0)),
-            pl.BlockSpec((D, block_t), lambda i, j: (0, j)),
-            pl.BlockSpec((D, block_t), lambda i, j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((block_n, block_t), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((N, T), jnp.int32),
-        interpret=interpret,
-    )(bins, split_features_bp.astype(jnp.int32),
-      split_bins_bp.astype(jnp.int32))
+    if bins.shape[1] % BP_TREE_BLOCK:
+        raise ValueError(f"leaf_index_bp gathers 128-feature lanes: "
+                         f"F={bins.shape[1]} must be a multiple of 128")
+    spec = pl.BlockSpec((D, BP_TREE_BLOCK), lambda i, j: (0, j))
+    return _tree_major_call(
+        _leaf_index_bp_kernel, bins,
+        (split_features_bp.astype(jnp.int32),
+         split_bins_bp.astype(jnp.int32)), [spec, spec], T,
+        block_n=block_n, block_t=BP_TREE_BLOCK, interpret=interpret)
 
 
 def leaf_index_u8(bins: jax.Array, split_features: jax.Array,
@@ -234,14 +241,11 @@ def leaf_index_u8(bins: jax.Array, split_features: jax.Array,
     """`leaf_index` over the quantized-pool bin stream: uint8 bins.
 
     Mirrors the paper's CalcIndexesBasic loop, which runs entirely on
-    the *quantized* uint8 representation (vmsgeu compares u8 bins
-    against the u8 split border) — binarization never reruns per tree.
-    The kernel body is shared with the int32 variant (bins upcast to
-    f32 for the one-hot MXU gather either way); this entry pins the
-    dtype contract and keeps the 4x-narrower bins panel (block_n x F
-    bytes instead of words) VMEM-resident per sample block.  8-bit
-    loads use the (32, 128) tile on real TPUs; interpret mode has no
-    such constraint.
+    the *quantized* uint8 representation — binarization never reruns per
+    tree.  The kernel body is shared with the int32 variant; this entry
+    pins the dtype contract and keeps the 4x-narrower bins panel
+    (block_n x F bytes instead of words) VMEM-resident per sample block.
+    uint8 blocks tile (32, 128), which every lane-multiple block_n fits.
     """
     if bins.dtype != jnp.uint8:
         raise TypeError(f"leaf_index_u8 takes uint8 bins, got {bins.dtype} "

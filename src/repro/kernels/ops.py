@@ -70,6 +70,13 @@ def _round_up(n: int, m: int) -> int:
     return ((n + m - 1) // m) * m
 
 
+def _lanes(block: int) -> int:
+    """A sample-axis block rounded up to whole 128-row lanes: the
+    kernels keep samples on the lane axis of the tree-major index, and
+    uint8 blocks tile (32, 128), so every row block is a lane multiple."""
+    return _round_up(max(int(block), 1), FEATURE_ALIGN)
+
+
 # Pad-op accounting, split by which side of the problem was padded:
 #   model — ensemble arrays (borders / splits / leaf values); a prepared
 #           plan must incur these exactly once, at build time
@@ -96,6 +103,9 @@ def _pad_dim(a: jax.Array, axis: int, target: int, value=0,
     _PAD_STATS[kind] += 1
     widths = [(0, 0)] * a.ndim
     widths[axis] = (0, pad)
+    if jnp.issubdtype(a.dtype, jnp.floating):
+        # a typed fill keeps integer constants out of float traces
+        value = np.asarray(value, a.dtype)
     return jnp.pad(a, widths, constant_values=value)
 
 
@@ -146,6 +156,7 @@ def _binarize_ref_u8(x, borders, *, prepadded=False, **_blocks):
 
 def _binarize_pallas_impl(x, borders, *, block_n, block_f, prepadded,
                           out_dtype):
+    block_n, block_f = _lanes(block_n), _lanes(block_f)
     if prepadded:
         # Borders already F-aligned (+inf pad columns); only the data
         # side is padded per call.  Padded feature columns stay in the
@@ -216,28 +227,30 @@ def _leaf_index_ref(bins, sf, sb, *, prepadded=False, **_blocks):
 
 def _leaf_index_pallas_impl(kernel, bins, sf, sb, *, block_n, block_t,
                             prepadded):
+    # The kernels write the index tree-major, (T, N); the registered
+    # contract is (N, T).  Inside one jit the transpose cancels against
+    # leaf_gather's, so the staged pipeline never materializes it.
+    block_n = _lanes(block_n)
+    N, F = bins.shape
+    T = sf.shape[0]
+    binsp = _pad_dim(bins, 0, _round_up(max(N, 1), block_n))
     if prepadded:
-        N = bins.shape[0]
-        Np = _round_up(max(N, 1), block_n)
-        binsp = _pad_dim(bins, 0, Np)
         out = kernel(binsp, sf, sb, block_n=block_n, block_t=block_t,
                      interpret=_interpret())
-        return out[:N]
-    N, F = bins.shape
-    T, D = sf.shape
-    Np, Tp = _round_up(N, block_n), _round_up(T, block_t)
-    Fp = _round_up(F, FEATURE_ALIGN)
-    binsp = _pad_dim(_pad_dim(bins, 0, Np), 1, Fp)
+        return out.T[:N]
+    Tp = _round_up(T, block_t)
+    binsp = _pad_dim(binsp, 1, _round_up(F, FEATURE_ALIGN))
     sfp = _pad_dim(sf, 0, Tp, kind="model")
     sbp = _pad_dim(sb, 0, Tp, value=PAD_SPLIT_BIN, kind="model")
     out = kernel(binsp, sfp, sbp, block_n=block_n, block_t=block_t,
                  interpret=_interpret())
-    return out[:N, :T]
+    return out.T[:N, :T]
 
 
 @registry.register("leaf_index", "pallas", dtypes=("int32",),
                    layouts=SOA_LAYOUTS,
-                   constraints="pads N/T to block multiples")
+                   constraints="pads N/T to block multiples; block_n "
+                               "rounds up to 128-row lanes")
 def _leaf_index_pallas(bins, sf, sb, *, block_n=256, block_t=16,
                        prepadded=False):
     return _leaf_index_pallas_impl(_index_k.leaf_index, bins, sf, sb,
@@ -247,8 +260,8 @@ def _leaf_index_pallas(bins, sf, sb, *, block_n=256, block_t=16,
 
 @registry.register("leaf_index", "pallas_u8", dtypes=("uint8",),
                    layouts=SOA_LAYOUTS,
-                   constraints="uint8 bins (quantized pool); u8 loads tile "
-                               "(32, 128) on real TPUs")
+                   constraints="uint8 bins (quantized pool); bf16 "
+                               "one-hot gather (exact for ids <= 255)")
 def _leaf_index_pallas_u8(bins, sf, sb, *, block_n=256, block_t=16,
                           prepadded=False):
     return _leaf_index_pallas_impl(_index_k.leaf_index_u8, bins, sf, sb,
@@ -274,13 +287,14 @@ def _leaf_index_ref_dm(bins, onehot, sb_dm, pow2, *, prepadded=False,
                                "at lower time); pads N per call")
 def _leaf_index_pallas_dm(bins, onehot, sb_dm, pow2, *, block_n=256,
                           block_t=16, prepadded=False):
+    block_n = _lanes(block_n)
     N = bins.shape[0]
     Np = _round_up(max(N, 1), block_n)
     binsp = _pad_dim(bins, 0, Np)
     out = _index_k.leaf_index_dm(binsp, onehot, sb_dm, pow2,
                                  block_n=block_n, block_t=block_t,
                                  interpret=_interpret())
-    return out[:N]
+    return out.T[:N]
 
 
 # Bitpacked layout variants: consume the bit-plane transposed
@@ -294,24 +308,41 @@ def _leaf_index_ref_bp(bins, sf_bp, sb_bp, *, prepadded=False, **_blocks):
     return _ref.leaf_index_bitpacked(bins, sf_bp, sb_bp)
 
 
+def _bp_pad(bins_or_x, sf_bp, sb_bp, block_n):
+    """Pad a bitpacked call to the kernel's lane contract: rows to the
+    row block, features and trees to whole 128 lanes.  Plans lower the
+    model pre-padded (only data-side pads happen per call); direct
+    registry dispatch may hand unpadded planes."""
+    N, F = bins_or_x.shape
+    T = sf_bp.shape[1]
+    xp = _pad_dim(_pad_dim(bins_or_x, 0, _round_up(max(N, 1), block_n)),
+                  1, _round_up(F, FEATURE_ALIGN))
+    Tp = _round_up(max(T, 1), _index_k.BP_TREE_BLOCK)
+    sfp = _pad_dim(sf_bp.astype(jnp.int32), 1, Tp, kind="model")
+    sbp = _pad_dim(sb_bp.astype(jnp.int32), 1, Tp, value=PAD_SPLIT_BIN,
+                   kind="model")
+    return xp, sfp, sbp
+
+
 @registry.register("leaf_index", "pallas_bp", dtypes=("int32", "uint8"),
                    layouts=("bitpacked",),
-                   constraints="bitpacked lowered model (T pre-padded at "
-                               "lower time); pads N per call; packs 32-doc "
-                               "uint32 lanes, block_n % 32 == 0")
-def _leaf_index_pallas_bp(bins, sf_bp, sb_bp, *, block_n=256, block_t=16,
+                   constraints="bitpacked lowered model (T pre-padded to "
+                               "128-tree lanes at lower time); pads N per "
+                               "call",
+                   suppressions=(
+                       "widening: the v5e VPU has neither 8-bit compares "
+                       "nor 8-bit lane gathers; a uint8 panel widens to "
+                       "int32 in registers before the integer gather",))
+def _leaf_index_pallas_bp(bins, sf_bp, sb_bp, *, block_n=256, block_t=None,
                           prepadded=False):
-    T = sf_bp.shape[1]
-    if T % block_t:
-        # direct registry dispatch may hand an unpadded T; plans always
-        # lower the model pre-padded to the block multiple
-        block_t = next(bt for bt in (64, 32, 16, 8, 4, 2, 1) if T % bt == 0)
-    N = bins.shape[0]
-    Np = _round_up(max(N, 1), block_n)
-    binsp = _pad_dim(bins, 0, Np)
-    out = _index_k.leaf_index_bp(binsp, sf_bp, sb_bp, block_n=block_n,
-                                 block_t=block_t, interpret=_interpret())
-    return out[:N]
+    # block_t is accepted for the shared call convention; the bitplane
+    # kernel's tree block is always one 128-tree lane
+    block_n = _lanes(block_n)
+    N, T = bins.shape[0], sf_bp.shape[1]
+    binsp, sfp, sbp = _bp_pad(bins, sf_bp, sb_bp, block_n)
+    out = _index_k.leaf_index_bp(binsp, sfp, sbp, block_n=block_n,
+                                 interpret=_interpret())
+    return out.T[:N, :T]
 
 
 # --------------------------------------------------------------------------
@@ -329,20 +360,15 @@ def _leaf_gather_ref(idx, leaf_values, *, prepadded=False, **_blocks):
                    constraints="pads N/T to block multiples")
 def _leaf_gather_pallas(idx, leaf_values, *, block_n=128, block_t=16,
                         prepadded=False):
-    if prepadded:
-        N = idx.shape[0]
-        Np = _round_up(max(N, 1), block_n)
-        idxp = _pad_dim(idx, 0, Np)
-        out = _gather_k.leaf_gather(idxp, leaf_values, block_n=block_n,
-                                    block_t=block_t, interpret=_interpret())
-        return out[:N]
+    # the kernel reads the index tree-major (see _leaf_index_pallas_impl)
+    block_n = _lanes(block_n)
     N, T = idx.shape
-    Np, Tp = _round_up(N, block_n), _round_up(T, block_t)
-    idxp = _pad_dim(_pad_dim(idx, 0, Np), 1, Tp)
+    Tp = T if prepadded else _round_up(T, block_t)
+    idxp = _pad_dim(_pad_dim(idx, 0, _round_up(max(N, 1), block_n)), 1, Tp)
     lvp = _pad_dim(leaf_values, 0, Tp, kind="model")  # zero leaves: no-op trees
-    out = _gather_k.leaf_gather(idxp, lvp, block_n=block_n, block_t=block_t,
-                                interpret=_interpret())
-    return out[:N]
+    out = _gather_k.leaf_gather(idxp.T, lvp, block_n=block_n,
+                                block_t=block_t, interpret=_interpret())
+    return out.T[:N]
 
 
 # --------------------------------------------------------------------------
@@ -403,6 +429,7 @@ def _fused_pallas(x, borders, sf, sb, lv, *, block_n=None, block_t=None,
     scratch = (jnp.uint8 if borders.shape[0] <= MAX_U8_BORDERS
                else jnp.int32)
     if prepadded:
+        block_n = _lanes(block_n)
         N = x.shape[0]
         Np = _round_up(max(N, 1), block_n)
         xp = _pad_dim(_pad_dim(x, 0, Np), 1, borders.shape[1])
@@ -410,7 +437,7 @@ def _fused_pallas(x, borders, sf, sb, lv, *, block_n=None, block_t=None,
                                      block_n=block_n, block_t=block_t,
                                      interpret=_interpret(),
                                      bins_scratch_dtype=scratch)
-        return out[:N]
+        return out.T[:N]
     N, F = x.shape
     T, D = sf.shape
     _, L, C = lv.shape
@@ -419,6 +446,7 @@ def _fused_pallas(x, borders, sf, sb, lv, *, block_n=None, block_t=None,
             F, D, L, C, borders.shape[0], n_rows=N, n_trees=T)
         block_n = block_n or tn
         block_t = block_t or tt
+    block_n = _lanes(block_n)
     Np = _round_up(N, block_n)
     Tp = _round_up(T, block_t)
     Fp = _round_up(F, FEATURE_ALIGN)
@@ -430,7 +458,7 @@ def _fused_pallas(x, borders, sf, sb, lv, *, block_n=None, block_t=None,
     out = _fused_k.fused_predict(xp, bp, sfp, sbp, lvp, block_n=block_n,
                                  block_t=block_t, interpret=_interpret(),
                                  bins_scratch_dtype=scratch)
-    return out[:N]
+    return out.T[:N]
 
 
 @registry.register("fused_predict", "ref_dm", dtypes=("int32",),
@@ -466,6 +494,7 @@ def _fused_pallas_dm(x, borders, onehot, sb_dm, pow2, lv, *,
         if block_t is None:
             block_t = next(bt for bt in (tt, 64, 32, 16, 8, 4, 2, 1)
                            if T % bt == 0)
+    block_n = _lanes(block_n)
     N = x.shape[0]
     Np = _round_up(max(N, 1), block_n)
     xp = _pad_dim(_pad_dim(x, 0, Np), 1, borders.shape[1])
@@ -473,7 +502,7 @@ def _fused_pallas_dm(x, borders, onehot, sb_dm, pow2, lv, *,
                                     block_n=block_n, block_t=block_t,
                                     interpret=_interpret(),
                                     bins_scratch_dtype=scratch)
-    return out[:N]
+    return out.T[:N]
 
 
 @registry.register("fused_predict", "ref_bp", dtypes=("int32",),
@@ -488,33 +517,37 @@ def _fused_ref_bp(x, borders, sf_bp, sb_bp, lv, *, prepadded=False,
 
 @registry.register("fused_predict", "pallas_bp", dtypes=("int32", "uint8"),
                    layouts=("bitpacked",),
-                   constraints="bitpacked lowered model (T pre-padded at "
-                               "lower time); pads N per call; u8 bins "
-                               "scratch when <= 255 borders")
+                   constraints="bitpacked lowered model (T pre-padded to "
+                               "128-tree lanes at lower time); pads N per "
+                               "call; u8 bins scratch when <= 255 borders",
+                   suppressions=(
+                       "widening: the v5e VPU has neither 8-bit compares "
+                       "nor 8-bit lane gathers; the uint8 bins scratch "
+                       "widens to int32 in registers before the integer "
+                       "gather",))
 def _fused_pallas_bp(x, borders, sf_bp, sb_bp, lv, *, block_n=None,
                      block_t=None, prepadded=False):
+    # block_t is accepted for the shared call convention; the bitplane
+    # kernel's tree block is always one 128-tree lane
     scratch = (jnp.uint8 if borders.shape[0] <= MAX_U8_BORDERS
                else jnp.int32)
     D, T = sf_bp.shape
-    if block_n is None or block_t is None:
-        # same autotune fallback as the dm impl: the model side is
-        # lowered, so block_t must divide the pre-padded T
+    if block_n is None:
         _, L, C = lv.shape
-        tn, tt = _tuning.best_fused_blocks(
+        block_n, _ = _tuning.best_fused_blocks(
             borders.shape[1], D, L, C, borders.shape[0], n_rows=x.shape[0],
-            n_trees=T)
-        block_n = block_n or tn
-        if block_t is None:
-            block_t = next(bt for bt in (tt, 64, 32, 16, 8, 4, 2, 1)
-                           if T % bt == 0)
+            n_trees=T, gather="bitplane")
+    block_n = _lanes(block_n)
     N = x.shape[0]
-    Np = _round_up(max(N, 1), block_n)
-    xp = _pad_dim(_pad_dim(x, 0, Np), 1, borders.shape[1])
-    out = _fused_k.fused_predict_bp(xp, borders, sf_bp, sb_bp, lv,
-                                    block_n=block_n, block_t=block_t,
+    bp = _pad_dim(borders, 1, _round_up(borders.shape[1], FEATURE_ALIGN),
+                  value=np.float32(np.inf), kind="model")
+    xp, sfp, sbp = _bp_pad(x, sf_bp, sb_bp, block_n)
+    lvp = _pad_dim(lv, 0, sfp.shape[1], kind="model")
+    out = _fused_k.fused_predict_bp(xp, bp, sfp, sbp, lvp,
+                                    block_n=block_n,
                                     interpret=_interpret(),
                                     bins_scratch_dtype=scratch)
-    return out[:N]
+    return out.T[:N]
 
 
 # --------------------------------------------------------------------------
@@ -549,6 +582,7 @@ def _histogram_pallas_impl(bins_t, leaf, g, *, n_bins, n_leaves,
             bins_bytes=1 if bins_t.dtype == jnp.uint8 else 4)
         block_f = block_f or bf
         block_n = block_n or bn
+    block_n = _lanes(block_n)
     Fp = _round_up(max(F, 1), block_f)
     Np = _round_up(max(N, 1), block_n)
     # padded samples carry g == 0 so they accumulate nothing; padded
@@ -575,8 +609,12 @@ def _histogram_pallas(bins_t, leaf, g, *, n_bins, n_leaves, block_f=None,
 
 @registry.register("histogram", "pallas_u8", dtypes=("uint8",),
                    layouts=ALL_LAYOUTS,
-                   constraints="uint8 pool bins compared unwidened "
-                               "against the bin digit; <= 256 bins")
+                   constraints="uint8 pool bins; <= 256 bins; one "
+                               "(1, block_n) row widened per feature",
+                   suppressions=(
+                       "widening: the v5e VPU has no 8-bit compare; each "
+                       "feature's (1, block_n) uint8 row widens to int32 "
+                       "in registers for the bin one-hot, never the panel",))
 def _histogram_pallas_u8(bins_t, leaf, g, *, n_bins, n_leaves,
                          block_f=None, block_n=None):
     return _histogram_pallas_impl(bins_t, leaf, g, n_bins=n_bins,

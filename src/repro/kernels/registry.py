@@ -68,6 +68,7 @@ class KernelImpl:
 
 _REGISTRY: dict[str, dict[str, KernelImpl]] = {}
 _CALL_STATS: dict[str, int] = {}
+_IMPLS_RUN: dict[str, set[str]] = {}
 
 
 @functools.cache
@@ -229,6 +230,7 @@ def dispatch(op: str, backend: str, *args: Any,
     is one attribute load + bool test; no span kwargs are built."""
     impl = get(op, resolve(op, backend, dtype=dtype, layout=layout))
     _CALL_STATS[op] = _CALL_STATS.get(op, 0) + 1
+    _IMPLS_RUN.setdefault(op, set()).add(impl.name)
     if not _TRACER.enabled:
         return impl.fn(*args, **kw)
     attrs: dict[str, Any] = {"op": op, "impl": impl.name,
@@ -271,8 +273,15 @@ def call_stats() -> dict[str, int]:
     return dict(_CALL_STATS)
 
 
+def dispatched_impls() -> dict[str, list[str]]:
+    """Per op, the implementation names dispatch has resolved to since
+    the last reset — which kernel actually ran for each op."""
+    return {op: sorted(names) for op, names in sorted(_IMPLS_RUN.items())}
+
+
 def reset_call_stats() -> None:
     _CALL_STATS.clear()
+    _IMPLS_RUN.clear()
 
 
 def table() -> list[dict[str, str]]:

@@ -3,46 +3,104 @@
 RVV 0.7.1 exposes LMUL (m1/m2/m4/m8) register grouping; the paper notes
 picking the best mode "requires experiments".  The TPU analog is the
 Pallas BlockSpec shape: it sets the VMEM working set and the MXU/VPU
-tile utilization.  This module provides the VMEM footprint model used to
-pre-filter candidate block shapes (anything over the budget would spill)
-and the candidate grids the benchmark sweeps.
+tile utilization.  This module holds the one VMEM limit every kernel
+compiles under, the footprint models that keep the tuner's block
+choices inside it, and the candidate grids the tuner ranks.
 
-On real TPU hardware `sweep()` would time each candidate; on CPU the
-interpret-mode result is correctness-only, so the selector falls back to
-the analytic footprint/alignment score.
+The footprint models count what Mosaic allocates, not the logical
+bytes: every VMEM buffer is padded to whole (sublane, lane) tiles —
+the lane dim to 128, the sublane dim to 8 rows of 32-bit words (16
+for 2-byte, 32 for 1-byte dtypes) — and every pipelined input/output
+block is double-buffered.  A (T, L, C=7) leaf table block therefore
+costs 128/7 of its logical size; a (1, N) uint8 row costs 32 rows.
 """
 from __future__ import annotations
 
 import dataclasses
+import math
 
-VMEM_BUDGET = 96 * 1024 * 1024   # bytes; leave headroom of v5e's 128 MiB
+# The VMEM limit each pallas_call passes to the compiler
+# (`compiler_params`), and the budget the footprint models plan
+# against.  A v5e TensorCore has 128 MiB of VMEM; the compiler's
+# default scoped limit is far lower, so kernels state theirs.  Half the
+# physical size leaves room for Mosaic's own internal scratch.
+VMEM_BUDGET = 64 * 1024 * 1024
 LANE = 128                        # VPU lane width / MXU tile edge
-SUBLANE = 8
+SUBLANE = 8                       # rows of 32-bit words per tile
+# Sample-axis block candidates: whole lanes, since the staged kernels
+# keep samples on the lane axis of the (trees, samples) index block.
+ROW_BLOCKS = (128, 256, 512, 1024)
+# Tree-axis block candidates of the MXU-gather kernels (trees sit on
+# sublanes there, so any multiple of 8 tiles); the bitpacked kernels
+# put trees on lanes and always take one full lane of trees.
+TREE_BLOCKS = (8, 16, 32, 64)
+BITPLANE_TREE_BLOCK = LANE
 
 
-def _align_score(*dims: int) -> float:
-    """Fraction of hardware tile actually used (penalizes ragged tiles)."""
-    score = 1.0
-    for d in dims[:-1]:
-        score *= min(1.0, d / (SUBLANE * ((d + SUBLANE - 1) // SUBLANE)))
-    d = dims[-1]
-    score *= min(1.0, d / (LANE * ((d + LANE - 1) // LANE)))
-    return score
+def compiler_params(*dimension_semantics: str):
+    """`pltpu.CompilerParams` every kernel compiles with: the grid's
+    dimension semantics and the shared `VMEM_BUDGET` limit."""
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics,
+                                vmem_limit_bytes=VMEM_BUDGET)
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def tile_bytes(shape, itemsize: int) -> int:
+    """VMEM bytes of one buffer of `shape`, padded to whole tiles."""
+    dims = (1, 1) + tuple(int(d) for d in shape)
+    *lead, rows, lanes = dims
+    rows_per_tile = SUBLANE * max(1, 4 // itemsize)
+    return (math.prod(lead) * _round_up(rows, rows_per_tile)
+            * _round_up(lanes, LANE) * itemsize)
+
+
+def _block(shape, itemsize: int) -> int:
+    """A pipelined input/output block: two tile-padded buffers."""
+    return 2 * tile_bytes(shape, itemsize)
 
 
 def binarize_footprint(block_n: int, block_f: int, n_borders: int, *,
                        bins_bytes: int = 4) -> int:
     """`bins_bytes=1` models the uint8 bin stream (quantized pool /
-    u8 fused scratch): the output panel shrinks 4x.  The compare-add
-    loop accumulates in int32 regardless of the stored dtype, so the
-    (block_n, block_f) accumulator is always counted at 4 bytes (the
-    static analyzer's live-buffer estimate checks this model against
-    the traced kernel)."""
-    x = block_n * block_f * 4
-    borders = n_borders * block_f * 4
-    acc = block_n * block_f * 4
-    out = block_n * block_f * bins_bytes
-    return x + borders + acc + out
+    u8 fused scratch).  The compare-add loop accumulates in int32
+    regardless of the stored dtype, so the accumulator and the compare
+    mask are counted at 4 bytes."""
+    return (_block((block_n, block_f), 4)                # x
+            + _block((n_borders, block_f), 4)            # borders
+            + _block((block_n, block_f), bins_bytes)     # bins out
+            + 2 * tile_bytes((block_n, block_f), 4))     # acc + mask
+
+
+def _gather_stage_bytes(block_n: int, block_t: int, F: int,
+                        bins_bytes: int) -> int:
+    """Stage-2 temporaries of the MXU gather: the widened bins panel
+    and its matmul operand, one depth's (bt, F) one-hot, the (bt, bn)
+    gathered plane and index accumulator, and the (bn, bt) transpose."""
+    return (2 * tile_bytes((block_n, F), 4)
+            + tile_bytes((block_t, F), 4)
+            + 3 * tile_bytes((block_t, block_n), 4)
+            + tile_bytes((block_n, block_t), 4))
+
+
+def _bitplane_stage_bytes(block_n: int, F: int) -> int:
+    """Stage-2 temporaries of the bitpacked kernel: the int32 panel it
+    gathers from, and per depth the (bn, 128) gathered columns, mask,
+    index register and 32-doc words."""
+    return (tile_bytes((block_n, F), 4)
+            + 4 * tile_bytes((block_n, BITPLANE_TREE_BLOCK), 4))
+
+
+def _accumulate_stage_bytes(block_n: int, block_t: int, L: int,
+                            C: int) -> int:
+    """Stage-3 (leaf accumulate) working set: the (bt, L, C) leaf block
+    (lane-padded C), two live (bn, L) one-hots and the (bn, C) sum."""
+    return (2 * block_t * tile_bytes((L, C), 4)
+            + 2 * tile_bytes((block_n, L), 4)
+            + tile_bytes((block_n, C), 4))
 
 
 def leaf_index_footprint(block_n: int, block_t: int, F: int, D: int, *,
@@ -50,46 +108,47 @@ def leaf_index_footprint(block_n: int, block_t: int, F: int, D: int, *,
                          gather: str = "mxu") -> int:
     """`gather` names the index-assembly pipeline the kernel runs:
 
-      mxu       one-hot matmul gather — the kernel holds an f32 working
-                copy of the bins panel for the systolic pass (exact for
-                bin ids <= 255), plus the one-hot and gathered panels
-      bitplane  integer shift/or assembly (the bitpacked layout): no
-                one-hot, no f32 upcast — the working set past the
-                resident bins panel is the per-depth (block_n, block_t)
-                column/mask/plane panels and the index register
+      mxu       per depth, a (bt, F) one-hot against the bins panel on
+                the MXU; split arrays arrive as (bt, D) blocks
+      bitplane  integer lane gather + shift/or (the bitpacked layout):
+                split planes arrive as (D, 128) blocks
     """
-    bins = block_n * F * bins_bytes
-    out = block_n * block_t * 4
+    bins = _block((block_n, F), bins_bytes)
+    out = _block((block_t, block_n), 4)
     if gather == "bitplane":
-        depth_panels = block_n * block_t * (bins_bytes + 4 + 4)
-        idx = block_n * block_t * 4
-        return bins + depth_panels + idx + out
-    upcast = block_n * F * 4
-    onehot = block_t * D * F * 4
-    gathered = block_t * D * block_n * 4
-    return bins + upcast + onehot + gathered + out
+        planes = 2 * _block((D, block_t), 4)
+        return bins + planes + out + _bitplane_stage_bytes(block_n, F)
+    splits = 2 * _block((block_t, D), 4)
+    return (bins + splits + out
+            + _gather_stage_bytes(block_n, block_t, F, bins_bytes))
 
 
 def leaf_gather_footprint(block_n: int, block_t: int, L: int, C: int) -> int:
-    idx = block_n * block_t * 4
-    lv = block_t * L * C * 4
-    onehot = block_n * block_t * L * 4
-    out = block_n * C * 4
-    return idx + lv + onehot + out
+    return (_block((block_t, block_n), 4)                # idx (trees, rows)
+            + _block((block_n, C), 4)                    # out
+            + tile_bytes((block_n, block_t), 4)          # idx transposed
+            + _accumulate_stage_bytes(block_n, block_t, L, C))
 
 
 def fused_footprint(block_n: int, block_t: int, F: int, D: int, L: int,
                     C: int, n_borders: int, *, bins_bytes: int = 4,
                     gather: str = "mxu") -> int:
-    """`bins_bytes=1` models the u8 bins scratch the fused kernel uses
-    when the ensemble fits 255 borders (ops.py picks it automatically);
-    `gather="bitplane"` models the bitpacked fused kernel's integer
-    stage-2 (see `leaf_index_footprint`)."""
-    return (binarize_footprint(block_n, F, n_borders,
-                               bins_bytes=bins_bytes)
-            + leaf_index_footprint(block_n, block_t, F, D,
-                                   bins_bytes=bins_bytes, gather=gather)
-            + leaf_gather_footprint(block_n, block_t, L, C))
+    """Working set of one fused grid step.  `bins_bytes=1` models the
+    u8 bins scratch the fused kernel uses when the ensemble fits 255
+    borders (ops.py picks it automatically); `gather="bitplane"` models
+    the bitpacked kernel (see `leaf_index_footprint`)."""
+    stage1 = (_block((block_n, F), 4)                    # x
+              + _block((n_borders, F), 4)                # borders
+              + tile_bytes((block_n, F), bins_bytes)     # bins scratch
+              + 2 * tile_bytes((block_n, F), 4))         # acc + mask
+    if gather == "bitplane":
+        stage2 = (2 * _block((D, block_t), 4)
+                  + _bitplane_stage_bytes(block_n, F))
+    else:
+        stage2 = (2 * _block((block_t, D), 4)
+                  + _gather_stage_bytes(block_n, block_t, F, bins_bytes))
+    return (stage1 + stage2 + _block((block_n, C), 4)
+            + _accumulate_stage_bytes(block_n, block_t, L, C))
 
 
 @dataclasses.dataclass
@@ -110,23 +169,29 @@ def _pad_utilization(n: int, block: int) -> float:
 def candidates_fused(F: int, D: int, L: int, C: int, n_borders: int,
                      budget: int = VMEM_BUDGET, *,
                      n_rows: int | None = None,
-                     n_trees: int | None = None) -> list[Candidate]:
+                     n_trees: int | None = None,
+                     gather: str = "mxu") -> list[Candidate]:
     """Candidate (block_n, block_t) grid, best first.
 
     When the workload shape (n_rows, n_trees) is known — the serving path
     always knows it — candidates that force heavy zero-padding are
     penalized by the fraction of padded work that is real, so a 150-row
-    bucket is not handed a 1024-row block.
+    bucket is not handed a 1024-row block.  `F` is the logical feature
+    count; the kernels see it padded to whole lanes.
     """
+    Fp = _round_up(max(F, 1), LANE)
+    bins_bytes = 1 if n_borders <= 255 else 4
+    tree_blocks = ((BITPLANE_TREE_BLOCK,) if gather == "bitplane"
+                   else TREE_BLOCKS)
     out = []
-    for bn in (64, 128, 256, 512, 1024):
-        for bt in (8, 16, 32, 64):
-            fp = fused_footprint(bn, bt, F, D, L, C, n_borders)
+    for bn in ROW_BLOCKS:
+        for bt in tree_blocks:
+            fp = fused_footprint(bn, bt, Fp, D, L, C, n_borders,
+                                 bins_bytes=bins_bytes, gather=gather)
             if fp > budget:
                 continue
-            # prefer larger tiles (fewer grid steps) once aligned
-            score = _align_score(bn, LANE) * min(1.0, fp / budget + 0.2) \
-                * (bn * bt) ** 0.25
+            # prefer larger tiles (fewer grid steps) once they fit
+            score = min(1.0, fp / budget + 0.2) * (bn * bt) ** 0.25
             if n_rows is not None:
                 score *= _pad_utilization(n_rows, bn)
             if n_trees is not None:
@@ -138,11 +203,19 @@ def candidates_fused(F: int, D: int, L: int, C: int, n_borders: int,
 def best_fused_blocks(F: int, D: int, L: int, C: int,
                       n_borders: int, *,
                       n_rows: int | None = None,
-                      n_trees: int | None = None) -> tuple[int, int]:
-    cands = candidates_fused(F, D, L, C, n_borders,
-                             n_rows=n_rows, n_trees=n_trees)
+                      n_trees: int | None = None,
+                      gather: str = "mxu") -> tuple[int, int]:
+    cands = candidates_fused(F, D, L, C, n_borders, n_rows=n_rows,
+                             n_trees=n_trees, gather=gather)
     if not cands:
-        return 128, 16
+        smallest = fused_footprint(ROW_BLOCKS[0], TREE_BLOCKS[0],
+                                   _round_up(max(F, 1), LANE), D, L, C,
+                                   n_borders)
+        raise ValueError(
+            f"no fused block shape fits VMEM_BUDGET={VMEM_BUDGET} B for "
+            f"F={F} D={D} L={L} C={C} borders={n_borders}: the smallest "
+            f"candidate ({ROW_BLOCKS[0]}, {TREE_BLOCKS[0]}) needs "
+            f"{smallest} B")
     return cands[0].block_n, cands[0].block_t
 
 
@@ -154,16 +227,26 @@ def hist_footprint(block_f: int, block_n: int, n_leaves: int,
                    bins_bytes: int = 1) -> int:
     """VMEM working set of one histogram grid step.
 
-    The (block_f, block_n, L*B) one-hot selector panel dominates — the
-    training twin of the (N, L) gather one-hot `best_fused_blocks`
-    budgets — plus the bins tile (`bins_bytes=1` for uint8 pool bins),
-    the (block_n, n_stats) gradient/hessian tile and the
-    (block_f, L*B, n_stats) accumulator."""
-    S = n_leaves * n_bins
-    return (block_f * block_n * S * 4          # one-hot selector (f32)
-            + block_f * block_n * bins_bytes   # bins tile
-            + block_n * n_stats * 4            # g/h stats tile
-            + block_f * S * n_stats * 4)       # accumulator
+    The kernel contracts a per-feature (n_bins, bn) bin one-hot with a
+    (bn, n_leaves * n_stats) leaf-expanded stats panel, so the
+    accumulator block is (block_f, n_bins, n_leaves * n_stats): stats
+    share the lane axis with the leaves instead of owning it.  Counted:
+    the bins rows (one (1, bn) tile each), the leaf column and stats
+    blocks, the two constant expansion matrices, the accumulator, and
+    the temporaries (leaf one-hot, expanded stats and its two factors,
+    bin one-hot, one feature's product)."""
+    bp = _round_up(max(n_bins, 1), SUBLANE)
+    lc = n_leaves * n_stats
+    return (block_f * _block((1, block_n), bins_bytes)
+            + _block((block_n, 1), 4)                    # leaf column
+            + _block((block_n, n_stats), 4)              # g/h stats
+            + _block((n_leaves, lc), 4)                  # leaf expansion
+            + _block((n_stats, lc), 4)                   # stats expansion
+            + block_f * _block((bp, lc), 4)              # accumulator
+            + tile_bytes((block_n, n_leaves), 4)
+            + 3 * tile_bytes((block_n, lc), 4)
+            + tile_bytes((bp, block_n), 4)
+            + tile_bytes((bp, lc), 4))
 
 
 @dataclasses.dataclass
@@ -179,18 +262,17 @@ def candidates_hist(F: int, n_leaves: int, n_bins: int, n_stats: int,
                     n_rows: int | None = None,
                     bins_bytes: int = 1) -> list[HistCandidate]:
     """Candidate (block_f, block_n) grid for the histogram kernel, best
-    first.  Scored like `candidates_fused`: prefer lane-aligned sample
-    blocks and larger tiles once aligned, penalize candidates whose
-    padding (features to block_f, rows to block_n) is mostly zeros."""
+    first.  Scored like `candidates_fused`: prefer larger tiles once
+    they fit, penalize candidates whose padding (features to block_f,
+    rows to block_n) is mostly zeros."""
     out = []
-    for bf in (1, 2, 4, 8, 16, 32):
-        for bn in (128, 256, 512, 1024):
+    for bf in (1, 2, 4, 8):
+        for bn in ROW_BLOCKS:
             fp = hist_footprint(bf, bn, n_leaves, n_bins, n_stats,
                                 bins_bytes=bins_bytes)
             if fp > budget:
                 continue
-            score = _align_score(bn, LANE) * min(1.0, fp / budget + 0.2) \
-                * (bf * bn) ** 0.25
+            score = min(1.0, fp / budget + 0.2) * (bf * bn) ** 0.25
             if n_rows is not None:
                 score *= _pad_utilization(n_rows, bn)
             score *= _pad_utilization(F, bf)
@@ -204,7 +286,9 @@ def best_hist_blocks(F: int, n_leaves: int, n_bins: int, n_stats: int, *,
     cands = candidates_hist(F, n_leaves, n_bins, n_stats,
                             n_rows=n_rows, bins_bytes=bins_bytes)
     if not cands:
-        return 1, 128
+        raise ValueError(
+            f"no histogram block shape fits VMEM_BUDGET={VMEM_BUDGET} B "
+            f"for n_leaves={n_leaves} n_bins={n_bins} n_stats={n_stats}")
     return cands[0].block_f, cands[0].block_n
 
 

@@ -52,18 +52,16 @@ def lower_predict(mesh):
     dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 
     def predict(sf, sb, lv, borders, x):
-        from repro.compat import shard_map
-
         def local(sf, sb, lv, borders, xs):
             bins = ref.binarize(xs, borders)
             idx = ref.leaf_index(bins, sf, sb)
             part = ref.leaf_gather(idx, lv)
             return jax.lax.psum(part, "model")
 
-        fn = shard_map(local, mesh=mesh,
-                       in_specs=(P("model"), P("model"), P("model"), P(),
-                                 P(dp)),
-                       out_specs=P(dp))
+        fn = jax.shard_map(local, mesh=mesh,
+                           in_specs=(P("model"), P("model"), P("model"),
+                                     P(), P(dp)),
+                           out_specs=P(dp))
         return fn(sf, sb, lv, borders, x)
 
     a = _ensemble_abs()

@@ -9,18 +9,18 @@ parallel) traffic plus the gradient all-reduce.
 from __future__ import annotations
 
 import jax
-
-from repro.compat import make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(shape))
 
 
 def make_local_mesh(n_devices: int | None = None, model: int = 1):
     """Small mesh over the actually-available devices (tests, examples)."""
     n = n_devices or len(jax.devices())
     assert n % model == 0
-    return make_mesh((n // model, model), ("data", "model"))
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         (AxisType.Auto,) * 2)

@@ -32,6 +32,11 @@ Scenarios with a committed baseline but no fresh quick run (e.g.
 `mesh-bench__k8` — quick mode only runs K in {1,4}) are reported as
 skipped, not failed.
 
+CPU only.  The gate runs each bench in a child process; on a TPU host a
+parent that has touched JAX holds the chip and the child would fail or
+hang, and the committed baselines are CPU-host numbers anyway.  On the
+chip, run `python chip_smoke.py` (one process) instead.
+
   PYTHONPATH=src python -m repro.launch.perf_gate --quick --check
   # positive control / offline compare: gate pre-existing JSONs
   PYTHONPATH=src python -m repro.launch.perf_gate --check \

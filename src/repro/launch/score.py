@@ -152,6 +152,8 @@ def main() -> int:
     from repro.launch.obs_cli import add_obs_flags
     add_obs_flags(ap)
     args = ap.parse_args()
+    from repro.launch import compile_cache
+    compile_cache.configure()
     if sum([bool(args.out), args.stats_only, bool(args.top_k)]) > 1:
         ap.error("--out, --stats-only and --top-k pick one output mode "
                  "each; pass at most one")

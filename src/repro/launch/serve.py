@@ -132,6 +132,8 @@ def main():
     from repro.launch.obs_cli import add_obs_flags
     add_obs_flags(ap)
     args = ap.parse_args()
+    from repro.launch import compile_cache
+    compile_cache.configure()
     if args.show_kernels:
         from repro.core import layout as layout_mod
         from repro.kernels import registry as kernel_registry

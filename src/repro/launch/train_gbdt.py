@@ -32,6 +32,7 @@ import numpy as np
 
 from repro.core import boosting, predictor, quantize
 from repro.core.losses import make_loss
+from repro.launch import compile_cache
 from repro.scoring import sources as sources_lib
 from repro.training.checkpoint import CheckpointManager
 from repro.training.gbdt import GBDTTrainer
@@ -74,6 +75,7 @@ def main(argv=None) -> int:
     from repro.launch.obs_cli import add_obs_flags
     add_obs_flags(ap)
     args = ap.parse_args(argv)
+    compile_cache.configure()
 
     if args.ckpt_every and not args.ckpt_dir:
         ap.error("--ckpt-every requires --ckpt-dir")

@@ -75,12 +75,16 @@ class ScoreConfig:
                       uint8 cap fall back to the float path per schema
       chunk_budget_bytes   host bytes one in-flight chunk may cost
                       (feeds the auto chunk planner)
+      shard_axis      how a mesh scorer splits the work: rows (exact
+                      parity with one device) | trees | auto
+                      (`tuning.best_shard_axis` per chunk)
     """
     chunk_rows: int = 0
     output: str = "proba"
     prefetch_depth: int = 2
     prequantize: bool = True
     chunk_budget_bytes: int = tuning.CHUNK_BUDGET_BYTES
+    shard_axis: str = "auto"
 
     def __post_init__(self):
         if self.output not in _OUTPUTS:
@@ -95,6 +99,9 @@ class ScoreConfig:
                              f"got {self.prefetch_depth!r}")
         if self.chunk_budget_bytes < 1:
             raise ValueError("chunk_budget_bytes must be positive")
+        if self.shard_axis not in ("auto", "rows", "trees"):
+            raise ValueError(f"shard_axis must be auto|rows|trees, "
+                             f"got {self.shard_axis!r}")
 
 
 class ScoringMetrics:
@@ -447,7 +454,8 @@ class BulkScorer:
     def _score_entry(self, plan: Predictor, x) -> np.ndarray:
         out = self.config.output
         if self.mesh is not None:
-            raw = plan.sharded(self.mesh)(x)
+            raw = plan.sharded(self.mesh,
+                               shard_axis=self.config.shard_axis)(x)
             if out == "raw":
                 return raw
             if out == "proba":

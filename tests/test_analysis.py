@@ -31,17 +31,24 @@ def test_full_matrix_clean(full_report):
 
 
 def test_declared_suppressions_are_exercised(full_report):
-    """The two shipped suppressions (jnp oracle widenings on
-    leaf_index:ref and histogram:ref) must both match real findings —
+    """Every shipped suppression must match real findings: the jnp
+    oracle widenings on leaf_index:ref and histogram:ref, and the
+    widenings the v5e forces on the Pallas kernels that compare or
+    lane-gather uint8 data (no 8-bit compare or gather on its VPU) —
     and the depth_grouped layout must be among leaf_index:ref's
     suppressed cells (the uint8 promotion audit of PR 6's layout)."""
     sup = full_report.suppressed
     assert all(f.rule == "widening" for f in sup)
     keys = {(f.op, f.impl) for f in sup}
-    assert keys == {("leaf_index", "ref"), ("histogram", "ref")}
+    assert keys == {("leaf_index", "ref"), ("histogram", "ref"),
+                    ("histogram", "pallas_u8"), ("leaf_index", "pallas_bp"),
+                    ("fused_predict", "pallas_bp")}
     assert ("depth_grouped" in
             {f.layout for f in sup if f.op == "leaf_index"})
     assert all(f.dtype == "uint8" for f in sup)
+    # the histogram kernel widens one (1, block_n) row, never the panel
+    assert all(f.message.startswith("uint8 uint8[1,")
+               for f in sup if (f.op, f.impl) == ("histogram", "pallas_u8"))
 
 
 def test_verified_map_covers_every_impl(full_report):
@@ -176,6 +183,50 @@ def test_vmem_audit_fires_on_understated_footprint():
         registry.unregister("binarize", "toy_fat")
 
 
+def test_vmem_audit_counts_tile_padding():
+    """A kernel whose extra temporary is tiny in logical bytes but
+    lane-padded on the chip — a (bn, bf, 2) f32 panel, the shape of the
+    old histogram's 2-wide stats lane — must trip the vmem-model audit:
+    the audit prices every buffer tile-padded, as the footprint models
+    and Mosaic do.  Priced at logical bytes it would pass unnoticed."""
+    from jax.experimental import pallas as pl
+    from repro.kernels import tuning
+
+    @registry.register("binarize", "toy_lane2", dtypes=("int32",),
+                       layouts=("soa",))
+    def _toy(x, borders, **_kw):
+        def kernel(x_ref, b_ref, out_ref):
+            xv = x_ref[...]
+            pair = jnp.stack([xv > b_ref[0:1, :], xv > b_ref[1:2, :]],
+                             axis=-1)                       # (bn, bf, 2)
+            out_ref[...] = jnp.sum(pair.astype(jnp.int32), axis=-1)
+        return pl.pallas_call(
+            kernel,
+            out_shape=jax.ShapeDtypeStruct(x.shape, jnp.int32),
+            interpret=True)(x, borders)
+
+    try:
+        r = _narrow_check({"binarize:toy_lane2"})
+        hits = [f for f in r.unsuppressed if f.rule == "vmem-model"]
+        assert hits, r.format(verbose=True)
+        closed = matrix.trace_cell(
+            matrix.Cell("binarize", "toy_lane2", "soa", "int32"))[0]
+        call = jaxpr_tools.find_pallas_calls(closed.jaxpr)[0]
+        refs = jaxpr_tools.pallas_ref_avals(call)
+        logical = sum(jaxpr_tools.aval_bytes(a) for a in refs) \
+            + jaxpr_tools.peak_live_bytes(
+                jaxpr_tools.pallas_kernel_jaxpr(call), include_invars=False)
+        model = tuning.binarize_footprint(*refs[0].shape,
+                                          refs[1].shape[0])
+        assert logical <= passes.VMEM_SLACK * model
+    finally:
+        registry.unregister("binarize", "toy_lane2")
+    # the padding itself: lanes to 128, sublanes to 32-bit-word tiles
+    assert tuning.tile_bytes((5, 2), 4) == 8 * 128 * 4
+    assert tuning.tile_bytes((3, 1, 200), 1) == 3 * 32 * 256
+    assert tuning.tile_bytes((40, 130), 2) == 48 * 256 * 2
+
+
 # --------------------------------------------------------------------------
 # Suppressions: honored, and flagged when stale
 # --------------------------------------------------------------------------
@@ -301,7 +352,7 @@ def test_pallas_refs_carry_block_shapes():
     calls = jaxpr_tools.find_pallas_calls(closed.jaxpr)
     assert len(calls) == 1
     refs = jaxpr_tools.pallas_ref_avals(calls[0])
-    assert len(refs) == 7            # 5 inputs + out + bins scratch
+    assert len(refs) == 8            # 5 inputs + out + index + bins scratch
     assert np.dtype(refs[-1].dtype) == np.uint8   # u8 scratch picked
     assert all(hasattr(a, "shape") for a in refs)
     assert jaxpr_tools.peak_live_bytes(

@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from repro import configs
-from repro.compat import make_mesh
+from jax.sharding import AxisType
 from repro.data.pipeline import TokenSource
 from repro.training.checkpoint import CheckpointManager
 from repro.training.trainer import Trainer, TrainerConfig
@@ -57,7 +57,7 @@ def test_async_save_waits(tmp_path):
 
 def test_trainer_crash_resume_end_to_end(tmp_path):
     cfg = configs.get("internvl2-1b", smoke=True)
-    mesh = make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), (AxisType.Auto,) * 2)
     ts = TokenSource(cfg.vocab_size, 16, 2)
 
     def batches():
@@ -87,7 +87,7 @@ def test_straggler_detection(tmp_path):
     """Artificially slow step is recorded as a straggler."""
     import time
     cfg = configs.get("glm4-9b", smoke=True)
-    mesh = make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), (AxisType.Auto,) * 2)
     ts = TokenSource(cfg.vocab_size, 16, 2)
     tr = Trainer(cfg, mesh, tmp_path,
                  TrainerConfig(total_steps=6, ckpt_every=100,

@@ -37,8 +37,8 @@ def test_flash_decode_matches_reference():
     res = run_sub("""
 from repro.distributed.collectives import flash_decode
 from repro.models.layers import decode_attention
-from repro.compat import make_mesh
-mesh = make_mesh((2, 4), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 4), ("data", "model"), (AxisType.Auto,) * 2)
 rng = np.random.default_rng(0)
 B, S, H, KVH, Dh = 4, 32, 8, 2, 16
 q = rng.normal(size=(B, H, Dh)).astype(np.float32)
@@ -58,11 +58,11 @@ print(json.dumps({"err": err}))
 def test_compressed_allreduce_error_feedback():
     res = run_sub("""
 from functools import partial
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.distributed.collectives import compressed_psum_grads
-from repro.compat import make_mesh
-mesh = make_mesh((8,), ("data",))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((8,), ("data",), (AxisType.Auto,))
 rng = np.random.default_rng(1)
 g = rng.normal(size=(8, 64)).astype(np.float32)
 
@@ -91,8 +91,8 @@ print(json.dumps({"err": err, "scale": scale, "rmax": rmax, "gmax": gmax}))
 def test_ring_allgather_matmul():
     res = run_sub("""
 from repro.distributed.collectives import ring_allgather_matmul
-from repro.compat import make_mesh
-mesh = make_mesh((2, 4), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 4), ("data", "model"), (AxisType.Auto,) * 2)
 rng = np.random.default_rng(2)
 x = rng.normal(size=(16, 32)).astype(np.float32)
 w = rng.normal(size=(32, 8)).astype(np.float32)
@@ -115,8 +115,8 @@ loss = losses.make_loss("logloss")
 ens, _ = boosting.fit(x, y, loss=loss,
                       params=BoostingParams(n_trees=16, depth=3,
                                             learning_rate=0.3))
-from repro.compat import make_mesh
-mesh = make_mesh((2, 4), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 4), ("data", "model"), (AxisType.Auto,) * 2)
 xj = jnp.asarray(x[:64])
 want = np.asarray(predict.raw_predict(ens, xj, strategy="staged",
                                       backend="ref"))
@@ -145,15 +145,15 @@ def batches():
         yield ts.next_batch(s); s += 1
 
 with tempfile.TemporaryDirectory() as d:
-    from repro.compat import make_mesh
-    mesh8 = make_mesh((4, 2), ("data", "model"))
+    from jax.sharding import AxisType
+    mesh8 = jax.make_mesh((4, 2), ("data", "model"), (AxisType.Auto,) * 2)
     tr = Trainer(cfg, mesh8, d, TrainerConfig(total_steps=4, ckpt_every=2))
     tr.init_or_restore()
     tr.train(batches())
     loss8 = None
     # restore onto a DIFFERENT mesh (2x2 over 4 devices)
-    mesh4 = make_mesh((2, 2), ("data", "model"),
-                      devices=jax.devices()[:4])
+    mesh4 = jax.make_mesh((2, 2), ("data", "model"), (AxisType.Auto,) * 2,
+                          devices=jax.devices()[:4])
     tr2 = Trainer(cfg, mesh4, d, TrainerConfig(total_steps=6, ckpt_every=2))
     ok = tr2.restore()
     hist = tr2.train(batches())
@@ -169,8 +169,8 @@ def test_ring_attention_matches_plain():
     res = run_sub("""
 from repro.distributed.collectives import ring_attention
 from repro.models.layers import attention
-from repro.compat import make_mesh
-mesh = make_mesh((2, 4), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 4), ("data", "model"), (AxisType.Auto,) * 2)
 rng = np.random.default_rng(4)
 B, S, H, KVH, Dh = 2, 32, 6, 2, 8      # 6 heads: does NOT divide 4
 q = rng.normal(size=(B, S, H, Dh)).astype(np.float32)

@@ -34,7 +34,7 @@ from repro.core.trees import ObliviousEnsemble
 from repro.core.predictor import Predictor
 from repro.kernels import registry
 from repro.kernels.ops import PAD_SPLIT_BIN
-from repro.compat import make_mesh
+from jax.sharding import AxisType
 
 def make_ens(T, D, F, B, C, seed=0, leaf_scale=1.0):
     rng = np.random.default_rng(seed)
@@ -82,7 +82,7 @@ def test_row_sharded_parity_all_layouts():
     path never dispatches binarize."""
     res = run_sub(ENSEMBLE_SETUP + """
 ens = make_ens(30, 5, 20, 60, 3)
-mesh = make_mesh((4,), ("data",))
+mesh = jax.make_mesh((4,), ("data",), (AxisType.Auto,))
 rng = np.random.default_rng(7)
 x = rng.normal(size=(136, 20)).astype(np.float32)
 out = {}
@@ -121,7 +121,7 @@ def test_tree_sharded_psum_parity():
     a reassociated float sum, so parity is to tolerance, not bits."""
     res = run_sub(ENSEMBLE_SETUP + """
 ens = make_ens(256, 5, 20, 60, 3, seed=3)
-mesh = make_mesh((4,), ("data",))
+mesh = jax.make_mesh((4,), ("data",), (AxisType.Auto,))
 rng = np.random.default_rng(11)
 x = rng.normal(size=(64, 20)).astype(np.float32)
 plan = Predictor.build(ens, strategy="staged", backend="ref")
@@ -131,9 +131,17 @@ fn = plan.sharded(mesh, shard_axis="trees")
 got = np.asarray(fn(pool))
 gotf = np.asarray(fn(x))
 scale = float(np.abs(ref).max())
+# a mesh BulkScorer takes the shard axis from its config
+from repro.scoring.scorer import BulkScorer, ScoreConfig
+from repro.scoring.sources import ArraySource
+bulk = {axis: np.asarray(BulkScorer(plan, ScoreConfig(
+            output="raw", chunk_rows=32, shard_axis=axis), mesh=mesh)
+        .score(ArraySource(x)).output) for axis in ("rows", "trees")}
 print(json.dumps({
     "err_pool": float(np.abs(got - ref).max()),
     "err_float": float(np.abs(gotf - ref).max()),
+    "err_bulk_trees": float(np.abs(bulk["trees"] - ref).max()),
+    "bulk_rows_exact": bool(np.array_equal(bulk["rows"], ref)),
     "scale": scale,
 }))
 """)
@@ -141,6 +149,8 @@ print(json.dumps({
     tol = 1e-6 * max(res["scale"], 1.0) * 4
     assert res["err_pool"] <= tol, res
     assert res["err_float"] <= tol, res
+    assert res["err_bulk_trees"] <= tol, res
+    assert res["bulk_rows_exact"], res
 
 
 def test_registry_replicas_and_predict_multi():
@@ -156,7 +166,7 @@ ens_a = make_ens(12, 4, 10, 30, 3, seed=1)
 ens_b = dataclasses.replace(make_ens(12, 4, 10, 30, 3, seed=2),
                             borders=ens_a.borders,
                             n_borders=ens_a.n_borders)
-mesh = make_mesh((4,), ("data",))
+mesh = jax.make_mesh((4,), ("data",), (AxisType.Auto,))
 rng = np.random.default_rng(5)
 xs = rng.normal(size=(40, 10)).astype(np.float32)
 
@@ -228,10 +238,11 @@ def test_best_shard_axis_cost_model():
 
 
 def test_replica_submeshes_validation():
-    from repro.compat import make_mesh
+    import jax
+    from jax.sharding import AxisType
     from repro.distributed.gbdt import replica_submeshes
 
-    mesh = make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), (AxisType.Auto,))
     subs = replica_submeshes(mesh, 1)
     assert len(subs) == 1 and subs[0].axis_names == ("data",)
     with pytest.raises(ValueError):
@@ -255,16 +266,15 @@ def test_shard_parity_lint_flags_all_gather():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
     from repro.analysis import passes
-    from repro.compat import abstract_mesh, shard_map
 
-    mesh = abstract_mesh((4,), ("data",))
+    mesh = jax.sharding.AbstractMesh((4,), ("data",))
 
     def local(x):
         full = jax.lax.all_gather(x, "data", tiled=True)
         return jnp.sum(full)[None] * jnp.ones_like(x[:, 0])
 
-    fn = shard_map(local, mesh=mesh, in_specs=(P("data"),),
-                   out_specs=P("data"), check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(P("data"),),
+                       out_specs=P("data"), check_vma=False)
     closed = jax.make_jaxpr(fn)(
         jax.ShapeDtypeStruct((8, 4), jnp.float32))
     findings = passes.sharded_entry_findings("ctrl:sharded_raw", closed)

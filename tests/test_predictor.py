@@ -115,6 +115,22 @@ def test_model_padded_once_then_zero_model_pads():
     np.testing.assert_allclose(np.asarray(outs[0]), np.asarray(outs[-1]))
 
 
+@pytest.mark.parametrize("strategy", ["staged", "fused"])
+def test_lowered_model_is_an_argument_not_a_constant(strategy):
+    # The jitted entries take the lowered model as an argument: baked
+    # in as a constant it would be copied into every executable (one
+    # device copy per entry and batch bucket).  The program text must
+    # not grow with the tree count.
+    def program_text(n_trees):
+        ens = _rand_ensemble(n_trees=n_trees)
+        plan = Predictor.build(ens, strategy=strategy, backend="ref")
+        entry = plan._entries["raw"]
+        return entry.lower(plan.lowered, _rand_x(ens, 16)).as_text()
+
+    small, large = program_text(16), program_text(512)
+    assert len(large) < 1.1 * len(small), (len(small), len(large))
+
+
 def test_deferred_prepare_pads_on_first_predict():
     # prepare=False (mesh servers): no model prep at build, one-time
     # prep on first local predict, same results.

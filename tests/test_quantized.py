@@ -240,9 +240,11 @@ def test_zero_binarize_dispatches_when_scoring_pool():
     stats = registry.call_stats()
     assert stats.get("binarize", 0) == 0, stats
     assert stats.get("leaf_index", 0) >= 1       # the pool path did run
+    assert "binarize" not in registry.dispatched_impls()
     # the float path, by contrast, dispatches binarize
     plan.raw(x)
     assert registry.call_stats().get("binarize", 0) >= 1
+    assert registry.dispatched_impls()["binarize"] == ["ref"]
 
 
 # --------------------------------------------------------------------------

@@ -510,6 +510,8 @@ def test_scorer_rejects_bad_config_and_sinks():
     plan = _plan(ens)
     with pytest.raises(ValueError, match="output"):
         ScoreConfig(output="logits")
+    with pytest.raises(ValueError, match="shard_axis"):
+        ScoreConfig(shard_axis="columns")
     with pytest.raises(TypeError, match="not both"):
         BulkScorer(plan, ScoreConfig(), chunk_rows=64)
     with pytest.raises(ValueError, match="at least one"):

@@ -262,3 +262,23 @@ def test_registry_swap_builds_fresh_plan(cov_model):
         assert new.predictor.stats["total_traces"] == 0
     finally:
         reg.close()
+
+
+def test_compile_cache_placement(monkeypatch):
+    """The launch CLIs' compile cache: JAX_COMPILATION_CACHE_DIR wins
+    untouched; unset, a fixed `.jax_cache/` at the checkout root."""
+    import jax
+    from repro.launch import compile_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(compile_cache.ENV_VAR, "/elsewhere/cache")
+        assert compile_cache.configure() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv(compile_cache.ENV_VAR)
+        placed = compile_cache.configure()
+        assert placed == str(compile_cache.CHECKOUT / ".jax_cache")
+        assert (compile_cache.CHECKOUT / "chip_smoke.py").is_file()
+        assert jax.config.jax_compilation_cache_dir == placed
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

@@ -7,14 +7,13 @@ import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import configs
-from repro.compat import abstract_mesh
 from repro.distributed import sharding as shd
 from repro.models import transformer as tf
 
 
 def fake_mesh(shape=(16, 16), axes=("data", "model")):
     # AbstractMesh carries shape info without real devices
-    return abstract_mesh(shape, axes)
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
 
 
 @pytest.mark.parametrize("arch", list(configs.ARCHS))

@@ -1,0 +1,140 @@
+"""The main-path Pallas kernels compile for a TPU v5e, at the covertype
+widths the chip smoke runs (54 features padded to 128, 254 borders,
+depth 8, 7 classes, 10,000 trees), with the block shapes the tuner
+picks for them.
+
+No chip is needed: the TPU compiler is installed and compiles for a
+described, unattached v5e.  Interpret-mode tests check what the kernels
+compute; these check that Mosaic accepts them — tiling, casts, VMEM.
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library, and pytest-xdist imports this file in
+every worker.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import binarize as bk
+from repro.kernels import fused_predict as fk
+from repro.kernels import histogram as hk
+from repro.kernels import leaf_gather as gk
+from repro.kernels import leaf_index as ik
+from repro.kernels import tuning
+
+F, FP, B, D, C, T = 54, 128, 254, 8, 7, 10_000
+L = 1 << D
+TRAIN_ROWS = 464_800
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile here is written to a persistent cache but cannot be
+    # read back without a chip: keep it out of any configured cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *avals):
+    text = jax.jit(fn).lower(*avals).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+def _sds(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=sharding)
+
+
+def _pad(n, m):
+    return -(-n // m) * m
+
+
+@pytest.mark.parametrize("n_rows", [256, 4096])
+def test_fused_predict_u8_10k_trees(one_chip, n_rows):
+    """The serving kernel `auto` resolves to on TPU (soa layout: the
+    10k-tree one-hot is over the depth-major budget), at the blocks the
+    tuner picks for a serving bucket and for a bulk batch."""
+    bn, bt = tuning.best_fused_blocks(F, D, L, C, B, n_rows=n_rows,
+                                      n_trees=T)
+    tp = _pad(T, bt)
+    s = lambda shape, dt: _sds(one_chip, shape, dt)  # noqa: E731
+    _compile(lambda x, b, sf, sb, lv: fk.fused_predict(
+        x, b, sf, sb, lv, block_n=bn, block_t=bt,
+        bins_scratch_dtype=jnp.uint8),
+        s((_pad(n_rows, bn), FP), jnp.float32), s((B, FP), jnp.float32),
+        s((tp, D), jnp.int32), s((tp, D), jnp.int32),
+        s((tp, L, C), jnp.float32))
+
+
+def test_staged_chain_u8(one_chip):
+    """binarize -> leaf_index -> leaf_gather on the uint8 bin stream at
+    the blocks a fused plan's pool path uses (ops defaults, the plan's
+    tree block)."""
+    _, bt = tuning.best_fused_blocks(F, D, L, C, B, n_rows=256, n_trees=T)
+    tp, n = _pad(T, bt), 2048
+    s = lambda shape, dt: _sds(one_chip, shape, dt)  # noqa: E731
+
+    def chain(x, b, sf, sb, lv):
+        bins = bk.binarize(x, b, block_n=256, block_f=128,
+                           out_dtype=jnp.uint8)
+        idx = ik.leaf_index(bins, sf, sb, block_n=256, block_t=bt)
+        return gk.leaf_gather(idx, lv, block_n=128, block_t=bt)
+
+    text = _compile(chain, s((n, FP), jnp.float32), s((B, FP), jnp.float32),
+                    s((tp, D), jnp.int32), s((tp, D), jnp.int32),
+                    s((tp, L, C), jnp.float32))
+    assert text.count("tpu_custom_call") >= 3
+
+
+def test_histogram_u8_deepest_level(one_chip):
+    """The training histogram at the deepest level of a depth-8 tree
+    (128 leaves x 255 bins, gradients and hessians of 7 classes) over
+    the full synthetic covertype pool."""
+    n_leaves, n_bins, n_stats = 1 << (D - 1), B + 1, 2 * C
+    bf, bn = tuning.best_hist_blocks(F, n_leaves, n_bins, n_stats,
+                                     n_rows=TRAIN_ROWS)
+    fp, n = _pad(F, bf), _pad(TRAIN_ROWS, bn)
+    s = lambda shape, dt: _sds(one_chip, shape, dt)  # noqa: E731
+    _compile(lambda b, leaf, g: hk.histogram(
+        b, leaf, g, n_bins=n_bins, n_leaves=n_leaves, block_f=bf,
+        block_n=bn),
+        s((fp, n), jnp.uint8), s((n,), jnp.int32),
+        s((n, n_stats), jnp.float32))
+
+
+@pytest.mark.parametrize("layout", ["depth_major", "bitpacked"])
+def test_fused_layout_variants(one_chip, layout):
+    """The `_dm` / `_bp` fused kernels `tuning.best_layout` can pick."""
+    s = lambda shape, dt: _sds(one_chip, shape, dt)  # noqa: E731
+    n = 1024
+    if layout == "depth_major":
+        bn, bt = tuning.best_fused_blocks(F, D, L, C, B, n_rows=n,
+                                          n_trees=1024)
+        _compile(lambda x, b, oh, sb, p2, lv: fk.fused_predict_dm(
+            x, b, oh, sb, p2, lv, block_n=bn, block_t=bt,
+            bins_scratch_dtype=jnp.uint8),
+            s((n, FP), jnp.float32), s((B, FP), jnp.float32),
+            s((1024, D, FP), jnp.float32), s((D, 1024), jnp.int32),
+            s((D, 1), jnp.float32), s((1024, L, C), jnp.float32))
+        return
+    bn, _ = tuning.best_fused_blocks(F, D, L, C, B, n_rows=n, n_trees=1024,
+                                     gather="bitplane")
+    _compile(lambda x, b, sf, sb, lv: fk.fused_predict_bp(
+        x, b, sf, sb, lv, block_n=bn, bins_scratch_dtype=jnp.uint8),
+        s((n, FP), jnp.float32), s((B, FP), jnp.float32),
+        s((D, 1024), jnp.int32), s((D, 1024), jnp.int32),
+        s((1024, L, C), jnp.float32))

@@ -1,0 +1,6 @@
+"""leaf_gather's share of its roofline in the traced slice, in %: the least
+time (bench/harness/work.py Run.roofline) over its summed device time."""
+
+
+def read(run):
+    return run.roofline("leaf_gather")

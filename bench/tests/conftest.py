@@ -1,0 +1,90 @@
+"""Shared fixtures for the benchmark's own tests: a copy of the benchmark
+with tiny configurations, run on the CPU.  Importing this module touches
+no device."""
+from __future__ import annotations
+
+import json
+import pathlib
+import shutil
+import sys
+
+import pytest
+
+BENCH = pathlib.Path(__file__).resolve().parents[1]
+ROOT = BENCH.parent
+for p in (str(BENCH), str(ROOT / "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
+
+TINY = {"covertype": {"rows": 1500, "trees": 24, "depth": 3},
+        "santander": {"rows": 1200, "trees": 24, "depth": 1}}
+
+# Cells whose files bench/ holds but whose BENCHMARK.json entries wait
+# for their proof on the chip (PERF.md, Open questions); the tests run
+# them from these entries.
+_ONLINE = ["covertype-online"]
+DEFERRED = {
+    "configs": [{"name": "santander", "file": "bench/configs/santander.json",
+                 "source": "test", "reduced": [], "why": "test"}],
+    "workloads": [
+        {"name": "santander-bulk", "config": "santander",
+         "traffic": "bulk_sweep", "chips": 1, "why": "test"},
+        {"name": "covertype-online", "config": "covertype",
+         "traffic": "online_poisson", "chips": 1, "why": "test"}],
+    "end_to_end": [
+        {"name": n, "unit": "ms", "better": "lower", "bound": 0.25,
+         "source": "host_clock", "workloads": _ONLINE}
+        for n in ("p50_ms", "p99_ms")],
+    "per_layer": [
+        {"name": n, "unit": u, "better": b, "source": s, "layer": layer,
+         "moves": "p99_ms", "workloads": _ONLINE}
+        for n, u, b, s, layer in (
+            ("idle_share.online", "%", "lower", "device_trace", "device"),
+            ("mfu.online", "%", "higher", "device_trace", "whole step"),
+            ("fused_predict_roofline", "%", "higher", "device_trace",
+             "kernels"),
+            ("online.batch_rows", "rows", "higher", "program_counter",
+             "serving"))],
+}
+
+
+def with_deferred(spec: dict) -> dict:
+    """BENCHMARK.json's entries plus the deferred cells'; santander-bulk
+    reports what covertype-bulk reports."""
+    for key, entries in DEFERRED.items():
+        have = {e["name"] for e in spec[key]}
+        spec[key] += [dict(e) for e in entries if e["name"] not in have]
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        w = m.get("workloads", [])
+        if "covertype-bulk" in w and "santander-bulk" not in w:
+            w.append("santander-bulk")
+    return spec
+
+
+def make_tiny_root(dst: pathlib.Path) -> pathlib.Path:
+    """BENCHMARK.json (with the deferred cells) and bench/ copied to dst,
+    every configuration cut to a few trees and rows, the online rate cut
+    to what the CPU serves."""
+    shutil.copytree(BENCH, dst / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    (dst / "BENCHMARK.json").write_text(json.dumps(with_deferred(spec)))
+    for name, cut in TINY.items():
+        path = dst / "bench" / "configs" / f"{name}.json"
+        cfg = json.loads(path.read_text())
+        cfg["data"]["rows"] = cut["rows"]
+        cfg["model"].update(trees=cut["trees"], depth=cut["depth"],
+                            border_sample_rows=500)
+        path.write_text(json.dumps(cfg))
+    for path in (dst / "bench" / "traffic").glob("*.json"):
+        tr = json.loads(path.read_text())
+        tr["check_rows"] = 64
+        if tr["client"] == "online":
+            tr.update(rate_per_s=300, warm_seconds=0.2, trace_seconds=0.3)
+        path.write_text(json.dumps(tr))
+    return dst
+
+
+@pytest.fixture
+def tiny_root(tmp_path):
+    return make_tiny_root(tmp_path)

@@ -148,9 +148,8 @@ python -m benchmarks.mesh_bench --quick --check \
 echo "== observability smoke (span tracer + metrics hub end to end) =="
 # a tiny bulk-scoring run with --trace-out/--metrics-out, then assert
 # the Chrome trace parses and contains the span taxonomy CI depends on
-# (dispatch/<op> kernel spans, compile/<entry> instants, the
-# bulk/quantize|score|sink pipeline) and the metrics export carries the
-# scoring snapshot
+# (compile/<entry> instants and the bulk/* stage spans) and the metrics
+# export carries the scoring snapshot
 OBS_TRACE="$PERF_FRESH/obs-trace.json"
 OBS_METRICS="$PERF_FRESH/obs-metrics.json"
 python -m repro.launch.score --dataset covertype --scale 0.002 \
@@ -161,8 +160,8 @@ import json, sys
 
 trace = json.load(open(sys.argv[1]))
 names = [e["name"] for e in trace["traceEvents"]]
-for want in ("dispatch/", "compile/", "bulk/quantize", "bulk/score",
-             "bulk/sink"):
+for want in ("compile/", "bulk/read", "bulk/quantize", "bulk/quantize_wait",
+             "bulk/prefetch_wait", "bulk/score", "bulk/sync", "bulk/sink"):
     assert any(n.startswith(want) for n in names), \
         f"trace missing {want} spans: {sorted(set(names))[:20]}"
 assert all({"ph", "pid", "tid"} <= set(e) for e in

@@ -188,10 +188,11 @@ def proba_from_raw(raw: jax.Array, n_outputs: int) -> jax.Array:
     """Raw scores -> class probabilities: two-column sigmoid for binary
     models, softmax otherwise.  The single definition every predict
     surface (plan entries, kwarg shims, mesh serving) shares."""
-    if n_outputs == 1:
-        p = jax.nn.sigmoid(raw[:, 0])
-        return jnp.stack([1.0 - p, p], axis=1)
-    return jax.nn.softmax(raw, axis=-1)
+    with jax.named_scope("gbdt/softmax"):
+        if n_outputs == 1:
+            p = jax.nn.sigmoid(raw[:, 0])
+            return jnp.stack([1.0 - p, p], axis=1)
+        return jax.nn.softmax(raw, axis=-1)
 
 
 def classify_from_raw(raw: jax.Array, n_outputs: int) -> jax.Array:

@@ -69,4 +69,5 @@ def binarize(x: jax.Array, borders: jax.Array, *, block_n: int = 256,
         out_shape=jax.ShapeDtypeStruct((N, F), out_dtype),
         compiler_params=tuning.compiler_params("parallel", "parallel"),
         interpret=interpret,
+        name="binarize",
     )(x, borders)

@@ -75,6 +75,7 @@ def _fused_call(kernel, x, borders, model_args, model_specs, leaf_values,
                         pltpu.VMEM((block_n, F), bins_scratch_dtype)],
         compiler_params=tuning.compiler_params("parallel", "arbitrary"),
         interpret=interpret,
+        name=name,
     )(x, borders, *model_args, leaf_values)
 
 

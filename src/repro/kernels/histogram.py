@@ -117,6 +117,7 @@ def histogram(bins_t: jax.Array, leaf: jax.Array, g: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((F, bp, lc), jnp.float32),
         compiler_params=tuning.compiler_params("parallel", "arbitrary"),
         interpret=interpret,
+        name="histogram",
     )(bins_t.reshape(F, 1, N), leaf.reshape(N, 1), g, e_leaf, e_stat)
     hist = out[:, :n_bins].reshape(F, n_bins, n_leaves, C)
     return hist.transpose(0, 2, 1, 3).reshape(F, n_leaves * n_bins, C)

@@ -60,6 +60,7 @@ def l2sq_rowwise(q: jax.Array, refs: jax.Array, *, block_n: int = 256,
         out_shape=jax.ShapeDtypeStruct((N, 1), jnp.float32),
         compiler_params=tuning.compiler_params("parallel", "arbitrary"),
         interpret=interpret,
+        name="l2sq_rowwise",
     )(q.reshape(1, K), refs)
     return out[:, 0]
 
@@ -114,4 +115,5 @@ def l2sq_matrix(a: jax.Array, b: jax.Array, *, block_m: int = 128,
         compiler_params=tuning.compiler_params("parallel", "parallel",
                                                "arbitrary"),
         interpret=interpret,
+        name="l2sq_matrix",
     )(a, b, a_sq, b_sq)

@@ -102,4 +102,5 @@ def leaf_gather(idx_t: jax.Array, leaf_values: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((C, N), jnp.float32),
         compiler_params=tuning.compiler_params("parallel", "arbitrary"),
         interpret=interpret,
+        name="leaf_gather",
     )(idx_t, leaf_values)

@@ -130,10 +130,10 @@ def _leaf_index_kernel(bins_ref, sf_ref, sb_ref, out_ref):
 
 
 def _tree_major_call(kernel, bins, model_args, model_specs, T, *,
-                     block_n, block_t, interpret):
+                     block_n, block_t, interpret, name):
     """pallas_call shared by the leaf-index variants: grid over (row
     blocks, tree blocks), bins panel per row block, the tree-major
-    (T, N) index out."""
+    (T, N) index out.  `name` names the kernel's op in a device trace."""
     N, F = bins.shape
     if N % block_n or T % block_t:
         raise ValueError(
@@ -149,7 +149,18 @@ def _tree_major_call(kernel, bins, model_args, model_specs, T, *,
         out_shape=jax.ShapeDtypeStruct((T, N), jnp.int32),
         compiler_params=tuning.compiler_params("parallel", "parallel"),
         interpret=interpret,
+        name=name,
     )(bins, *model_args)
+
+
+def _soa_call(bins, split_features, split_bins, *, block_n, block_t,
+              interpret, name):
+    T, D = split_features.shape
+    spec = pl.BlockSpec((block_t, D), lambda i, j: (j, 0))
+    return _tree_major_call(_leaf_index_kernel, bins,
+                            (split_features, split_bins), [spec, spec], T,
+                            block_n=block_n, block_t=block_t,
+                            interpret=interpret, name=name)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_t", "interpret"))
@@ -162,12 +173,8 @@ def leaf_index(bins: jax.Array, split_features: jax.Array,
     block_t == 0 (block_t a multiple of 8).  Padded trees must use
     split_bins > max bin (e.g. 2^30) so they contribute leaf 0.
     """
-    T, D = split_features.shape
-    spec = pl.BlockSpec((block_t, D), lambda i, j: (j, 0))
-    return _tree_major_call(_leaf_index_kernel, bins,
-                            (split_features, split_bins), [spec, spec], T,
-                            block_n=block_n, block_t=block_t,
-                            interpret=interpret)
+    return _soa_call(bins, split_features, split_bins, block_n=block_n,
+                     block_t=block_t, interpret=interpret, name="leaf_index")
 
 
 def _leaf_index_dm_kernel(bins_ref, onehot_ref, sb_ref, pow2_ref, out_ref):
@@ -199,7 +206,8 @@ def leaf_index_dm(bins: jax.Array, onehot: jax.Array, split_bins_dm: jax.Array,
         [pl.BlockSpec((block_t, D, F), lambda i, j: (j, 0, 0)),
          pl.BlockSpec((block_t, D), lambda i, j: (j, 0)),
          pl.BlockSpec(memory_space=pltpu.SMEM)], T,
-        block_n=block_n, block_t=block_t, interpret=interpret)
+        block_n=block_n, block_t=block_t, interpret=interpret,
+        name="leaf_index_dm")
 
 
 def _leaf_index_bp_kernel(bins_ref, sf_ref, sb_ref, out_ref):
@@ -232,9 +240,11 @@ def leaf_index_bp(bins: jax.Array, split_features_bp: jax.Array,
         _leaf_index_bp_kernel, bins,
         (split_features_bp.astype(jnp.int32),
          split_bins_bp.astype(jnp.int32)), [spec, spec], T,
-        block_n=block_n, block_t=BP_TREE_BLOCK, interpret=interpret)
+        block_n=block_n, block_t=BP_TREE_BLOCK, interpret=interpret,
+        name="leaf_index_bp")
 
 
+@functools.partial(jax.jit, static_argnames=("block_n", "block_t", "interpret"))
 def leaf_index_u8(bins: jax.Array, split_features: jax.Array,
                   split_bins: jax.Array, *, block_n: int = 256,
                   block_t: int = 16, interpret: bool = False) -> jax.Array:
@@ -250,5 +260,6 @@ def leaf_index_u8(bins: jax.Array, split_features: jax.Array,
     if bins.dtype != jnp.uint8:
         raise TypeError(f"leaf_index_u8 takes uint8 bins, got {bins.dtype} "
                         "(use leaf_index for int32)")
-    return leaf_index(bins, split_features, split_bins, block_n=block_n,
-                      block_t=block_t, interpret=interpret)
+    return _soa_call(bins, split_features, split_bins, block_n=block_n,
+                     block_t=block_t, interpret=interpret,
+                     name="leaf_index_u8")

@@ -731,7 +731,11 @@ def fused_predict(x: jax.Array, borders: jax.Array, split_features: jax.Array,
 #   leaves   T padded with zeros (padded trees contribute nothing)
 # On the ref backend the same arrays work unpadded — ref kernels accept
 # any shape — so a ref plan carries the original arrays through.
+# Each entry runs under `jax.named_scope("gbdt/<stage>")`, so every HLO
+# op a plan stage emits (the kernel, its pads, slices and transposes)
+# carries the stage in its op_name metadata.
 
+@jax.named_scope("gbdt/fused_predict")
 def fused_predict_prepadded(x: jax.Array, borders: jax.Array,
                             split_features: jax.Array, split_bins: jax.Array,
                             leaf_values: jax.Array, *,
@@ -745,6 +749,7 @@ def fused_predict_prepadded(x: jax.Array, borders: jax.Array,
                              prepadded=True)
 
 
+@jax.named_scope("gbdt/binarize")
 def binarize_prepadded(x: jax.Array, borders: jax.Array, *,
                        backend: Backend = "auto",
                        block_n: int = 256) -> jax.Array:
@@ -757,6 +762,7 @@ def binarize_prepadded(x: jax.Array, borders: jax.Array, *,
                              block_n=block_n, prepadded=True)
 
 
+@jax.named_scope("gbdt/binarize")
 def binarize_u8_prepadded(x: jax.Array, borders: jax.Array, *,
                           backend: Backend = "auto",
                           block_n: int = 256) -> jax.Array:
@@ -770,6 +776,7 @@ def binarize_u8_prepadded(x: jax.Array, borders: jax.Array, *,
                              prepadded=True)
 
 
+@jax.named_scope("gbdt/leaf_index")
 def leaf_index_prepadded(bins: jax.Array, split_features: jax.Array,
                          split_bins: jax.Array, *,
                          backend: Backend = "auto", block_n: int = 256,
@@ -783,6 +790,7 @@ def leaf_index_prepadded(bins: jax.Array, split_features: jax.Array,
                              prepadded=True)
 
 
+@jax.named_scope("gbdt/leaf_gather")
 def leaf_gather_prepadded(idx: jax.Array, leaf_values: jax.Array, *,
                           backend: Backend = "auto", block_n: int = 128,
                           block_t: int = 16) -> jax.Array:
@@ -800,6 +808,7 @@ def leaf_gather_prepadded(idx: jax.Array, leaf_values: jax.Array, *,
 # vector — so the kernels never rebuild iota/one-hot per call.  The
 # model side is always lowered pre-padded; data is padded per call.
 
+@jax.named_scope("gbdt/leaf_index")
 def leaf_index_dm_prepadded(bins: jax.Array, onehot: jax.Array,
                             split_bins_dm: jax.Array, pow2: jax.Array, *,
                             backend: Backend = "auto", block_n: int = 256,
@@ -814,6 +823,7 @@ def leaf_index_dm_prepadded(bins: jax.Array, onehot: jax.Array,
                              prepadded=True)
 
 
+@jax.named_scope("gbdt/fused_predict")
 def fused_predict_dm_prepadded(x: jax.Array, borders: jax.Array,
                                onehot: jax.Array, split_bins_dm: jax.Array,
                                pow2: jax.Array, leaf_values: jax.Array, *,
@@ -836,6 +846,7 @@ def fused_predict_dm_prepadded(x: jax.Array, borders: jax.Array,
 # assembly runs as integer shift/or with no one-hot anywhere.  The
 # model side is always lowered pre-padded; data is padded per call.
 
+@jax.named_scope("gbdt/leaf_index")
 def leaf_index_bp_prepadded(bins: jax.Array, split_features_bp: jax.Array,
                             split_bins_bp: jax.Array, *,
                             backend: Backend = "auto", block_n: int = 256,
@@ -849,6 +860,7 @@ def leaf_index_bp_prepadded(bins: jax.Array, split_features_bp: jax.Array,
                              prepadded=True)
 
 
+@jax.named_scope("gbdt/fused_predict")
 def fused_predict_bp_prepadded(x: jax.Array, borders: jax.Array,
                                split_features_bp: jax.Array,
                                split_bins_bp: jax.Array,

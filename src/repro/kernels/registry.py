@@ -36,10 +36,6 @@ import dataclasses
 import functools
 from typing import Any, Callable, Optional
 
-from repro.obs.trace import get_tracer
-
-_TRACER = get_tracer()
-
 # The six kernel ops every backend family must cover (histogram is the
 # training-side op; the other five serve prediction).
 CORE_OPS = ("binarize", "leaf_index", "leaf_gather", "l2sq",
@@ -223,37 +219,13 @@ def dispatch(op: str, backend: str, *args: Any,
     """Resolve and call: the single entry every `kernels.ops` public
     wrapper (and its legacy `backend=` shim) funnels through.
 
-    When the obs tracer is enabled, each dispatch records a
-    `dispatch/<op>` span tagged (op, impl, layout, bin-dtype, operand
-    shapes, block kwargs, row pad utilization) — the per-kernel
-    attribution the paper does loop-by-loop on hardware.  Disabled cost
-    is one attribute load + bool test; no span kwargs are built."""
+    It runs while a plan entry is being traced, not per call, so it
+    records no span: `compile/<entry>` instants mark compiles, and each
+    kernel's device time is its named op in a profiler trace."""
     impl = get(op, resolve(op, backend, dtype=dtype, layout=layout))
     _CALL_STATS[op] = _CALL_STATS.get(op, 0) + 1
     _IMPLS_RUN.setdefault(op, set()).add(impl.name)
-    if not _TRACER.enabled:
-        return impl.fn(*args, **kw)
-    attrs: dict[str, Any] = {"op": op, "impl": impl.name,
-                             "layout": layout or "-",
-                             "dtype": dtype or "-"}
-    shapes = [tuple(int(d) for d in a.shape)
-              for a in args if hasattr(a, "shape")]
-    if shapes:
-        attrs["shapes"] = str(shapes)
-    blocks = {k: v for k, v in kw.items()
-              if k.startswith("block") and isinstance(v, int) and v > 0}
-    attrs.update(blocks)
-    # fraction of the row-blocked grid that is real data (the span's
-    # pad-utilization tag; 1.0 = no block padding on the row axis)
-    row_block = blocks.get("block_m") or blocks.get("block_rows")
-    if row_block and shapes:
-        rows = shapes[0][0]
-        padded = -(-rows // row_block) * row_block
-        attrs["pad_util_rows"] = rows / padded if padded else 1.0
-    _TRACER.counter("dispatch_count", "kernel",
-                    **{op: float(_CALL_STATS[op])})
-    with _TRACER.span(f"dispatch/{op}", "kernel", **attrs):
-        return impl.fn(*args, **kw)
+    return impl.fn(*args, **kw)
 
 
 def impls_for_layout(op: str, layout: str) -> list[str]:

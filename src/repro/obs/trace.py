@@ -8,39 +8,51 @@ answered by loading `trace.export_chrome(path)` output into Perfetto
 
 Design constraints, in priority order:
 
-1. **Near-zero overhead when disabled.**  Tracing defaults OFF, and
-   every hot site guards with `if TRACER.enabled:` — one attribute
-   load + bool test (a few ns) — before building any span arguments.
-   `span()` itself returns a shared no-op context manager when
-   disabled, so even unguarded call sites stay cheap (no allocation).
-   The disabled-cost bound is asserted in tests/test_obs.py.
-2. **Thread-safe, bounded memory.**  Events land in a
+1. **On the profiler's clock.**  Every `span()` also enters a
+   `jax.profiler.TraceAnnotation` of the same name, whether or not the
+   ring is enabled.  With no profiler session running it is an inert
+   TraceMe (about 0.2 us per enter and exit on a CPU host); under
+   `jax.profiler.start_trace` the span lands on the host thread's line
+   of the profiler trace, on the same clock as the device's ops, so an
+   idle gap of the device can be set against what each host thread was
+   doing.  Span attributes never reach the annotation.
+2. **Near-zero overhead when the ring is disabled.**  The ring defaults
+   OFF; a disabled `span()` is the bare annotation, with no attribute
+   dict and nothing recorded, and hot sites guard with
+   `if TRACER.enabled:` before building any other arguments.  The
+   disabled-cost bound is asserted in tests/test_obs.py.
+3. **Thread-safe, bounded memory.**  Events land in a
    `collections.deque(maxlen=capacity)` ring buffer — appends are
    atomic under the GIL, eviction is FIFO (oldest events drop first),
    and a runaway trace can never grow past `capacity` events.
-3. **Monotonic clocks.**  Timestamps come from `time.perf_counter_ns`
-   relative to the tracer's epoch; wall-clock adjustments can never
-   produce negative durations.
+4. **Monotonic clocks.**  Ring timestamps come from
+   `time.perf_counter_ns` relative to the tracer's epoch; wall-clock
+   adjustments can never produce negative durations.  `complete()` and
+   `instant()` are ring-only: the profiler cannot take a backdated or
+   zero-length annotation.
 
 Event kinds (Chrome trace `ph` values the exporter emits):
 
   span     `ph="X"` complete event: name, category, ts, dur, args —
            produced by the `span()` context manager
   instant  `ph="i"` instant event — e.g. Predictor compile events
-  counter  `ph="C"` counter event — e.g. dispatch totals over time
+  counter  `ph="C"` counter event — a sampled value over time
   (plus `ph="M"` thread-name metadata rows, emitted at export time)
 
 Span taxonomy (see docs/observability.md for the full contract):
 
-  dispatch/<op>      kernel registry dispatch (op, impl, layout, dtype)
-  compile/<entry>    Predictor XLA trace (entry, layout, batch rows)
-  sharded/<kind>     mesh-sharded predict (shard axis, device count)
-  bulk/quantize      BulkScorer prefetch-worker binarize (per chunk)
-  bulk/score         BulkScorer chunk dispatch (main thread)
-  bulk/sink          BulkScorer device sync + sink write
-  train/level        GBDTTrainer per-level histogram+split pass
-  train/iteration    GBDTTrainer whole boosting iteration
-  serve/batch        GBDTServer scored batch
+  compile/<entry>      Predictor XLA trace (entry, layout, batch rows)
+  sharded/<kind>       mesh-sharded predict (shard axis, device count)
+  bulk/read            BulkScorer source read of a chunk (prefetch worker)
+  bulk/quantize        pad + binarize dispatch, no fence (prefetch worker)
+  bulk/quantize_wait   the binarize fence (prefetch worker)
+  bulk/prefetch_wait   main thread blocked on the prefetch queue
+  bulk/score           BulkScorer chunk dispatch (main thread)
+  bulk/sync            device -> host copy of the lag-1 chunk (main)
+  bulk/sink            sink write of the lag-1 chunk (main)
+  train/level          GBDTTrainer per-level histogram+split pass
+  train/iteration      GBDTTrainer whole boosting iteration
+  serve/batch          GBDTServer scored batch
 """
 from __future__ import annotations
 
@@ -51,36 +63,26 @@ import threading
 import time
 from typing import Any, Optional
 
+from jax.profiler import TraceAnnotation
+
 DEFAULT_CAPACITY = 65536
 
 
-class _NullSpan:
-    """Shared no-op context manager returned while tracing is disabled.
-
-    A singleton: entering/exiting allocates nothing, so an unguarded
-    `with span(...)` costs one call + two no-op methods when tracing
-    is off (hot sites additionally guard on `TRACER.enabled` to skip
-    building the attribute kwargs at all)."""
+class _AnnotatedSpan(TraceAnnotation):
+    """The span `span()` returns while the ring is disabled: the bare
+    profiler annotation, recording nothing in the ring."""
 
     __slots__ = ()
-
-    def __enter__(self) -> "_NullSpan":
-        return self
-
-    def __exit__(self, *exc: Any) -> bool:
-        return False
 
     def set(self, **attrs: Any) -> None:
         """Attribute updates on a disabled span are dropped."""
 
 
-_NULL_SPAN = _NullSpan()
-
-
 class _Span:
-    """A live span: records ts on __enter__, appends on __exit__."""
+    """A live span: enters the profiler annotation and records ts on
+    __enter__, appends to the ring on __exit__."""
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: dict[str, Any]):
@@ -89,13 +91,16 @@ class _Span:
         self.cat = cat
         self.args = args
         self._t0 = 0
+        self._annotation = TraceAnnotation(name)
 
     def __enter__(self) -> "_Span":
+        self._annotation.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc: Any) -> bool:
         t1 = time.perf_counter_ns()
+        self._annotation.__exit__(*exc)
         self._tracer._append(("X", self.name, self.cat, self._t0,
                               t1 - self._t0,
                               threading.get_ident(), self.args))
@@ -152,16 +157,18 @@ class Tracer:
             # racy read, but the count is advisory (exported as
             # metadata); the ring itself evicts correctly regardless
             self._dropped += 1
-        if event[5] not in self._thread_names:
-            self._thread_names[event[5]] = threading.current_thread().name
+        # by the latest thread to record under this ident: the OS hands
+        # a dead thread's ident to the next thread it starts
+        self._thread_names[event[5]] = threading.current_thread().name
         self._ring.append(event)
 
     def span(self, name: str, cat: str = "", **attrs: Any):
-        """Context manager timing a region.  Returns the shared no-op
-        singleton while disabled, so `with span(...)` is always legal
-        and never allocates when tracing is off."""
+        """Context manager timing a region on the profiler's clock and,
+        while the ring is enabled, in the ring.  Use it on the thread
+        that does the work: an annotation opens and closes on one
+        thread."""
         if not self.enabled:
-            return _NULL_SPAN
+            return _AnnotatedSpan(name)
         return _Span(self, name, cat, attrs)
 
     def complete(self, name: str, cat: str = "", *, start_ns: int,

@@ -58,6 +58,15 @@ _OUTPUTS = ("raw", "proba", "classify")
 _TRACER = get_tracer()
 
 
+def _chunk_span(name: str, span: "ChunkSpan", **attrs: Any):
+    """A `bulk/*` span of one chunk; its ring attributes are built only
+    while the ring records."""
+    if not _TRACER.enabled:
+        return _TRACER.span(name)
+    return _TRACER.span(name, "bulk", chunk=span.index, rows=span.n_valid,
+                        **attrs)
+
+
 @dataclasses.dataclass(frozen=True)
 class ScoreConfig:
     """Bulk-scoring configuration.
@@ -416,12 +425,12 @@ class BulkScorer:
         def prepare(item):
             span, x = item
             t0 = time.perf_counter()
-            # this span lands on the Prefetcher worker's thread id, so
-            # the exported timeline shows chunk k+1's quantize riding
-            # under chunk k's bulk/score on the main-thread track
-            with _TRACER.span("bulk/quantize", "bulk", chunk=span.index,
-                              rows=span.n_valid, padded=span.padded):
-                payload: dict[str, Any] = {}
+            payload: dict[str, Any] = {}
+            # these spans land on the Prefetcher worker's thread, so the
+            # timeline shows chunk k+1's quantize riding under chunk k's
+            # bulk/score on the main thread; the dispatch and the fence
+            # are apart, since the fence waits behind chunk k's trees
+            with _chunk_span("bulk/quantize", span, padded=span.padded):
                 need_float = any(not g.use_pool
                                  for g in self._groups.values())
                 if need_float:
@@ -441,12 +450,15 @@ class BulkScorer:
                             # bucket-pad the pool to the planned tail shape
                             pool = pool.slice_rows(0, span.n_valid) \
                                        .pad_rows(span.padded)
-                        # force the binarize to finish HERE, on the worker
-                        # thread: jax dispatch is async, and an unfinished
-                        # pool would push the quantize work onto the main
-                        # thread's sync point, killing the overlap
-                        pool.bins.block_until_ready()
                         payload[fp] = pool
+            # force the binarize to finish HERE, on the worker thread:
+            # jax dispatch is async, and an unfinished pool would push
+            # the quantize work onto the main thread's sync point,
+            # killing the overlap
+            with _chunk_span("bulk/quantize_wait", span):
+                for fp, g in self._groups.items():
+                    if g.use_pool:
+                        payload[fp].bins.block_until_ready()
             metrics.note_quantize(time.perf_counter() - t0)
             return span, payload
         return prepare
@@ -503,7 +515,9 @@ class BulkScorer:
 
         def read_spans():
             for span in todo:
-                yield span, source.read(span.start, span.stop)
+                with _chunk_span("bulk/read", span):
+                    x = source.read(span.start, span.stop)
+                yield span, x
 
         prepare = self._prepare(metrics, chunk_rows)
         if self.config.prefetch_depth > 0 and len(todo) > 1:
@@ -512,12 +526,15 @@ class BulkScorer:
                                 transform=prepare)
         else:
             stream = map(prepare, read_spans())
+
         def drain(entry):
             span, outs, t0 = entry
-            with _TRACER.span("bulk/sink", "bulk", chunk=span.index,
-                              rows=span.n_valid):
-                for name, ys in outs.items():
-                    ys = np.asarray(ys, np.float32)   # host sync point
+            with _chunk_span("bulk/sync", span):
+                # host sync point: waits for the chunk's device work
+                host = {name: np.asarray(ys, np.float32)
+                        for name, ys in outs.items()}
+            with _chunk_span("bulk/sink", span):
+                for name, ys in host.items():
                     if ys.ndim == 1:                  # classify: (N,) ids
                         ys = ys[:, None]
                     sinks[name].write(span.start, ys[:span.n_valid])
@@ -529,16 +546,20 @@ class BulkScorer:
         # device busy while python writes sinks (pending is bounded at
         # 2 chunks — the O(chunk) memory contract includes it)
         pending: list = []
+        chunks = iter(stream)
         try:
-            for span, payload in stream:
+            for _ in todo:
+                # the main thread idles here when the worker's read and
+                # quantize fall behind the device
+                with _TRACER.span("bulk/prefetch_wait", "bulk"):
+                    span, payload = next(chunks)
                 t0 = time.perf_counter()
                 outs = {}
                 # covers dispatch only (jax is async): device compute
-                # overlaps the next iteration; the sync cost is under
-                # the chunk's bulk/sink span
-                with _TRACER.span("bulk/score", "bulk", chunk=span.index,
-                                  rows=span.n_valid, padded=span.padded,
-                                  models=len(self.plans)):
+                # overlaps the next iteration; the wait for it is under
+                # the chunk's bulk/sync span
+                with _chunk_span("bulk/score", span, padded=span.padded,
+                                 models=len(self.plans)):
                     for name, plan in self.plans.items():
                         g = self._group_of[name]
                         x_in = payload[g.fingerprint if g.use_pool

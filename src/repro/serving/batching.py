@@ -87,6 +87,7 @@ class Request:
     rid: int
     payload: np.ndarray
     future: "queue.Queue"
+    t_submit: float = 0.0            # time.perf_counter() at submit
 
 
 class Batcher:
@@ -100,11 +101,20 @@ class Batcher:
         self.q: queue.Queue = queue.Queue()
         self._stop = threading.Event()
         self.batch_sizes: list[int] = []
+        # the batch the batcher thread is dispatching: its oldest
+        # request's queue wait, read on that thread while it runs
+        self._dispatching = threading.local()
         self.thread = threading.Thread(target=self._loop, daemon=True)
         self.thread.start()
 
     def _run_batch(self, xs: np.ndarray) -> np.ndarray:
         return np.asarray(self.serve_fn(xs))
+
+    def dispatch_queue_wait_s(self) -> float:
+        """Seconds the oldest request of the batch this thread is
+        dispatching waited in the queue, from submit to dispatch; 0 for
+        a batch run directly rather than through the queue."""
+        return getattr(self._dispatching, "queue_wait_s", 0.0)
 
     def _loop(self):
         while not self._stop.is_set():
@@ -124,13 +134,19 @@ class Batcher:
                     break
             xs = np.stack([r.payload for r in batch])
             self.batch_sizes.append(len(batch))
-            ys = self._run_batch(xs)
+            # the queue is FIFO, so the first request waited longest
+            self._dispatching.queue_wait_s = \
+                time.perf_counter() - first.t_submit
+            try:
+                ys = self._run_batch(xs)
+            finally:
+                self._dispatching.queue_wait_s = 0.0
             for r, y in zip(batch, ys):
                 r.future.put(y)
 
     def submit(self, rid: int, payload: np.ndarray) -> "queue.Queue":
         fut: queue.Queue = queue.Queue(maxsize=1)
-        self.q.put(Request(rid, payload, fut))
+        self.q.put(Request(rid, payload, fut, time.perf_counter()))
         return fut
 
     def close(self):
@@ -164,5 +180,6 @@ class BucketedBatcher(Batcher):
         t0 = time.perf_counter()
         ys = np.asarray(self.serve_fn(pad_rows(xs, bucket)))
         if self.metrics is not None:
-            self.metrics.note_batch(n, bucket, time.perf_counter() - t0)
+            self.metrics.note_batch(n, bucket, time.perf_counter() - t0,
+                                    self.dispatch_queue_wait_s())
         return ys[:n]

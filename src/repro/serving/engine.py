@@ -114,8 +114,9 @@ class GBDTServer:
 
         def serve(xs: np.ndarray) -> np.ndarray:
             # lands on the batcher thread's track in exported traces
-            with _TRACER.span("serve/batch", "serve", model=name,
-                              rows=int(len(xs))):
+            with _TRACER.span(
+                    "serve/batch", "serve", model=name, rows=int(len(xs)),
+                    queue_wait_s=self.batcher.dispatch_queue_wait_s()):
                 if self._sharded is not None:
                     raw = self._sharded(jnp.asarray(xs, jnp.float32))
                     return np.asarray(proba_from_raw(raw,

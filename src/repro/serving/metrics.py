@@ -128,6 +128,10 @@ class ServerMetrics:
         self.deadline_hits = 0
         self.deadline_misses = 0
         self.shed_requests = 0
+        # per batch, the wait of its oldest request from submit to
+        # dispatch (the deadline batcher's queue): total and maximum
+        self.queue_wait_s = 0.0
+        self.queue_wait_max_s = 0.0
         self._lat = PercentileReservoir(self.MAX_LAT_SAMPLES)
         # latencies restricted to batches that met the deadline; the
         # tail of *served-within-SLO* traffic (p99_under_deadline_ms)
@@ -144,9 +148,14 @@ class ServerMetrics:
             self.traces += 1
 
     def note_batch(self, n_valid: int, n_padded: int,
-                   latency_s: float) -> None:
+                   latency_s: float, queue_wait_s: float = 0.0) -> None:
+        """One scored batch: `latency_s` times the serve call,
+        `queue_wait_s` its oldest request's wait before dispatch."""
         with self._lock:
             self.batches += 1
+            self.queue_wait_s += queue_wait_s
+            self.queue_wait_max_s = max(self.queue_wait_max_s,
+                                        queue_wait_s)
             self.requests += n_valid
             self.served_rows += n_valid
             self.padded_rows += n_padded - n_valid
@@ -174,6 +183,7 @@ class ServerMetrics:
             self.padded_rows = self.served_rows = self.traces = 0
             self.deadline_hits = self.deadline_misses = 0
             self.shed_requests = 0
+            self.queue_wait_s = self.queue_wait_max_s = 0.0
             self._lat = PercentileReservoir(self.MAX_LAT_SAMPLES)
             self._lat_ok = PercentileReservoir(self.MAX_LAT_SAMPLES)
             self._prev_t = self._t0
@@ -209,6 +219,8 @@ class ServerMetrics:
                 (self.served_rows - self._prev_rows) / idt,
             "batch_p50_ms": self._lat.percentile(50) * 1e3,
             "batch_p99_ms": self._lat.percentile(99) * 1e3,
+            "queue_wait_s": self.queue_wait_s,
+            "queue_wait_max_s": self.queue_wait_max_s,
             "pad_overhead": (self.padded_rows / pad_total
                              if pad_total else 0.0),
             "deadline_ms": self.deadline_ms,
@@ -286,6 +298,8 @@ class ServerMetrics:
                 sum(s["interval_rows_per_s"] for s in snaps),
             "batch_p50_ms": lat.percentile(50) * 1e3,
             "batch_p99_ms": lat.percentile(99) * 1e3,
+            "queue_wait_s": sum(s["queue_wait_s"] for s in snaps),
+            "queue_wait_max_s": max(s["queue_wait_max_s"] for s in snaps),
             "pad_overhead": (pad_rows / pad_total if pad_total else 0.0),
             "deadline_ms": (deadlines.pop() if len(deadlines) == 1
                             else None),

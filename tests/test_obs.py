@@ -37,11 +37,15 @@ def _rand_ensemble(seed=3, n_trees=9, depth=4, n_features=7,
 # Tracer core
 # --------------------------------------------------------------------------
 def test_disabled_span_is_shared_noop_and_records_nothing():
+    # a disabled span is the bare profiler annotation (no longer a
+    # shared singleton): it keeps nothing of its attributes and records
+    # nothing in the ring
     tr = Tracer()
     s1 = tr.span("a", "cat", big_attr="x" * 100)
     s2 = tr.span("b")
-    assert s1 is s2                       # singleton: no allocation
     with s1:
+        s1.set(result="dropped")
+    with s2:
         pass
     tr.instant("i")
     tr.counter("c", v=1.0)
@@ -103,10 +107,10 @@ def test_complete_event_matches_span_timebase():
 def test_chrome_export_schema(tmp_path):
     tr = Tracer()
     tr.enable()
-    with tr.span("dispatch/leaf_index", "kernel", op="leaf_index"):
+    with tr.span("bulk/score", "bulk", chunk=0):
         pass
     tr.instant("compile/raw", "compile", batch=64)
-    tr.counter("dispatch_count", "kernel", leaf_index=1.0)
+    tr.counter("queue_depth", "bulk", chunks=1.0)
     path = tmp_path / "trace.json"
     obj = tr.export_chrome(path)
     loaded = json.loads(path.read_text())
@@ -121,7 +125,7 @@ def test_chrome_export_schema(tmp_path):
     i = next(e for e in evs if e["ph"] == "i")
     assert i["s"] == "t"
     c = next(e for e in evs if e["ph"] == "C")
-    assert c["args"] == {"leaf_index": 1.0}
+    assert c["args"] == {"chunks": 1.0}
     assert loaded["otherData"]["dropped_events"] == 0
 
 
@@ -155,30 +159,41 @@ def test_tracing_context_restores_prior_state():
 # --------------------------------------------------------------------------
 # Instrumentation integration: a traced BulkScorer run
 # --------------------------------------------------------------------------
-def test_bulk_scorer_trace_shows_prefetch_overlap(tmp_path):
-    ens = _rand_ensemble()
-    plan = Predictor.build(ens, PredictConfig(strategy="staged",
-                                              backend="ref"))
-    rng = np.random.default_rng(0)
-    x = rng.normal(size=(700, ens.n_features)).astype(np.float32)
-    tracer = get_tracer()
+def _bulk_run(x, tracer, plan=None):
+    """One traced BulkScorer run over x in 256-row chunks; its events."""
+    plan = plan or Predictor.build(_rand_ensemble(),
+                                   PredictConfig(strategy="staged",
+                                                 backend="ref"))
     with tracing(tracer, clear=True):
         scorer = BulkScorer({"m": plan},
                             ScoreConfig(chunk_rows=256, prequantize=True))
         scorer.score(ArraySource(x), {"m": ArraySink()})
-        events = tracer.events()
-    by_name = {}
+        return tracer.events()
+
+
+def _by_name(events):
+    out = {}
     for e in events:
-        by_name.setdefault(e["name"], []).append(e)
+        out.setdefault(e["name"], []).append(e)
+    return out
+
+
+def test_bulk_scorer_trace_shows_prefetch_overlap(tmp_path):
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(700, 7)).astype(np.float32)
+    tracer = get_tracer()
+    by_name = _by_name(_bulk_run(x, tracer))
     # the pipeline spans all fired, once per chunk for quantize/score
     assert len(by_name["bulk/quantize"]) == len(by_name["bulk/score"])
     assert len(by_name["bulk/quantize"]) >= 3
     assert "bulk/sink" in by_name
-    # kernel dispatches are tagged with op/impl/layout
-    disp = [e for n, evs in by_name.items() if n.startswith("dispatch/")
-            for e in evs]
-    assert disp and all({"op", "impl", "layout"} <= set(e["args"])
-                        for e in disp)
+    # the stage spans carry their chunk; the registry records no
+    # dispatch span (it runs at trace time, not per chunk)
+    for name in ("bulk/read", "bulk/quantize", "bulk/quantize_wait",
+                 "bulk/score", "bulk/sync", "bulk/sink"):
+        assert all({"chunk", "rows"} <= set(e["args"])
+                   for e in by_name[name]), name
+    assert not any(n.startswith("dispatch/") for n in by_name)
     # prefetch overlap: quantize happens on the worker thread, scoring
     # on the caller thread — distinct tids is what makes the overlap
     # visible on the exported timeline
@@ -190,6 +205,100 @@ def test_bulk_scorer_trace_shows_prefetch_overlap(tmp_path):
                      if e["ph"] == "M"}
     assert "prefetcher" in thread_labels
     assert not tracer.enabled        # context restored
+
+
+_WORKER_SPANS = ("bulk/read", "bulk/quantize", "bulk/quantize_wait")
+_MAIN_SPANS = ("bulk/prefetch_wait", "bulk/score", "bulk/sync", "bulk/sink")
+
+
+def test_bulk_scorer_emits_each_stage_span_once_per_chunk_on_its_thread():
+    x = np.random.default_rng(1).normal(size=(700, 7)).astype(np.float32)
+    by_name = _by_name(_bulk_run(x, get_tracer()))
+    n_chunks = 3                                 # 256 + 256 + 188-row tail
+    for name in _WORKER_SPANS + _MAIN_SPANS:
+        assert len(by_name[name]) == n_chunks, name
+        if name != "bulk/prefetch_wait":        # waits before the chunk
+            assert sorted(e["args"]["chunk"] for e in by_name[name]) \
+                == list(range(n_chunks)), name
+    main = threading.get_ident()
+    assert {e["tid"] for n in _MAIN_SPANS for e in by_name[n]} == {main}
+    worker = {e["tid"] for n in _WORKER_SPANS for e in by_name[n]}
+    assert len(worker) == 1 and main not in worker
+
+
+class _FencedBins:
+    """Pool bins whose fence leaves a `fence` instant in the ring."""
+
+    def __init__(self, bins, tracer):
+        self._bins, self._tracer = bins, tracer
+        self.shape, self.ndim, self.dtype = bins.shape, bins.ndim, bins.dtype
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self._bins, dtype)
+
+    def block_until_ready(self):
+        self._tracer.instant("fence")
+        self._bins.block_until_ready()
+        return self
+
+
+def test_bulk_quantize_span_holds_no_fence(monkeypatch):
+    from repro.core.quantize import QuantizedPool
+
+    plan = Predictor.build(_rand_ensemble(),
+                           PredictConfig(strategy="staged", backend="ref"))
+    tracer = get_tracer()
+    quantize = plan.quantize
+
+    def fenced(x):
+        pool = quantize(x)
+        return QuantizedPool(_FencedBins(pool.bins, tracer),
+                             pool.fingerprint)
+
+    monkeypatch.setattr(plan, "quantize", fenced)
+    x = np.random.default_rng(2).normal(size=(768, 7)).astype(np.float32)
+    by_name = _by_name(_bulk_run(x, tracer, plan))
+
+    def inside(t, spans):
+        return [e for e in spans
+                if e["ts_us"] <= t <= e["ts_us"] + e["dur_us"]]
+
+    fences = [e["ts_us"] for e in by_name["fence"]]
+    assert len(fences) == 3
+    for t in fences:
+        assert len(inside(t, by_name["bulk/quantize_wait"])) == 1
+        assert not inside(t, by_name["bulk/quantize"])
+
+
+def test_bulk_spans_reach_the_profiler_with_the_ring_off(tmp_path):
+    import pathlib
+    import sys
+
+    bench = pathlib.Path(__file__).resolve().parents[1] / "bench"
+    if str(bench) not in sys.path:
+        sys.path.insert(0, str(bench))
+    from harness import trace as bench_trace
+
+    plan = Predictor.build(_rand_ensemble(),
+                           PredictConfig(strategy="staged", backend="ref"))
+    scorer = BulkScorer(plan, ScoreConfig(chunk_rows=256))
+    x = np.random.default_rng(3).normal(size=(700, 7)).astype(np.float32)
+    scorer.score(ArraySource(x))                 # compile outside the trace
+    tracer = get_tracer()
+    tracer.clear()
+    assert not tracer.enabled
+    with bench_trace.capture(tmp_path) as found:
+        scorer.score(ArraySource(x))
+    assert len(tracer) == 0                      # the ring stayed empty
+    host = bench_trace.load(found[0]).host
+    names = [e.name for e in host]
+    for name in ("bulk/sync", "bulk/prefetch_wait"):
+        assert names.count(name) == 3, name
+    # on the profiler's clock: the sweep's spans lie inside the window
+    (window,) = [e for e in host if e.name == bench_trace.WINDOW_SPAN]
+    for e in host:
+        if e.name.startswith("bulk/"):
+            assert window.start_ns <= e.start_ns <= e.end_ns <= window.end_ns
 
 
 # --------------------------------------------------------------------------
@@ -301,3 +410,48 @@ def test_hub_json_export(tmp_path):
     loaded = json.loads((tmp_path / "m.json").read_text())
     assert loaded["metrics"]["a"]["v"] == 1
     assert "collected_at" in loaded and "collected_at" in obj
+
+
+# --------------------------------------------------------------------------
+# Names on the device trace
+# --------------------------------------------------------------------------
+def test_every_pallas_call_passes_a_name():
+    """A pallas_call without `name=` takes its op name from the
+    enclosing jit, which a rename would silently change under the
+    benchmark's trace readers."""
+    import ast
+    import pathlib
+
+    kernels = pathlib.Path(__file__).resolve().parents[1] / "src" / \
+        "repro" / "kernels"
+    calls = []
+    for path in sorted(kernels.glob("*.py")):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.Call) and \
+                    isinstance(node.func, ast.Attribute) and \
+                    node.func.attr == "pallas_call":
+                calls.append((path.name, node.lineno,
+                              {k.arg for k in node.keywords}))
+    assert len(calls) == 7
+    assert [c[:2] for c in calls if "name" not in c[2]] == []
+
+
+def test_export_names_a_thread_by_its_latest_name(tmp_path):
+    # the OS reuses a dead thread's ident for the next thread: the label
+    # follows the latest thread to record under an ident, never the first
+    tr = Tracer()
+    tr.enable()
+
+    def work():
+        tr.instant("before")
+        threading.current_thread().name = "renamed-worker"
+        tr.instant("after")
+
+    t = threading.Thread(target=work, name="first-worker")
+    t.start()
+    t.join(timeout=5)
+    assert not t.is_alive()
+    obj = tr.export_chrome(tmp_path / "t.json")
+    labels = [e["args"]["name"] for e in obj["traceEvents"]
+              if e["ph"] == "M"]
+    assert labels == ["renamed-worker"]

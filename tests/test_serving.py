@@ -1,5 +1,8 @@
 """Serving path: bucket selection, bounded recompiles, fused parity on
 unpadded ensembles, GBDTServer end-to-end, model registry."""
+import threading
+import time
+
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -66,6 +69,52 @@ def test_bucketed_batcher_pads_and_unpads():
         b.close()
 
 
+def test_batcher_counts_each_batchs_oldest_queue_wait():
+    from repro.serving.metrics import ServerMetrics
+
+    metrics = ServerMetrics("m")
+    gate = threading.Event()
+
+    def serve(xs):
+        gate.wait(5)                # the first batch holds the batcher
+        return xs.sum(axis=1)
+
+    b = batching.BucketedBatcher(serve, max_batch=4, max_wait_ms=1.0,
+                                 buckets=(4,), metrics=metrics)
+    try:
+        futs = [b.submit(i, np.ones(3, np.float32)) for i in range(8)]
+        time.sleep(0.05)
+        gate.set()
+        for f in futs:
+            f.get(timeout=5)
+        snap = metrics.snapshot()
+        assert snap["batches"] >= 2
+        # a later batch's oldest request waited out the held first batch
+        assert snap["queue_wait_max_s"] >= 0.05
+        assert snap["queue_wait_s"] >= snap["queue_wait_max_s"]
+        # a batch run directly never queued
+        b._run_batch(np.ones((2, 3), np.float32))
+        assert metrics.snapshot()["queue_wait_s"] == snap["queue_wait_s"]
+        assert b.dispatch_queue_wait_s() == 0.0
+    finally:
+        b.close()
+
+
+def test_queue_wait_merges_and_resets():
+    from repro.serving.metrics import ServerMetrics
+
+    a, b = ServerMetrics("m"), ServerMetrics("m")
+    a.note_batch(2, 2, 0.001, queue_wait_s=0.004)
+    a.note_batch(2, 2, 0.001, queue_wait_s=0.001)
+    b.note_batch(2, 2, 0.001, queue_wait_s=0.003)
+    merged = ServerMetrics.merge([a, b])
+    assert merged["queue_wait_s"] == pytest.approx(0.008)
+    assert merged["queue_wait_max_s"] == pytest.approx(0.004)
+    a.reset()
+    assert a.snapshot()["queue_wait_s"] == 0.0
+    assert a.snapshot()["queue_wait_max_s"] == 0.0
+
+
 def test_bucketed_batcher_rejects_undersized_buckets():
     with pytest.raises(ValueError):
         batching.BucketedBatcher(lambda x: x, max_batch=64, buckets=(8, 16))
@@ -126,6 +175,29 @@ def test_server_recompiles_bounded_by_buckets(cov_model):
         assert snap["recompiles"] <= len(server.buckets), snap
         assert snap["batches"] == 10
         assert snap["requests"] == 3 + 5 + 9 + 16 + 17 + 33 + 50 + 64 + 2 + 40
+    finally:
+        server.close()
+
+
+def test_serve_batch_span_carries_queue_wait(cov_model):
+    from repro.obs.trace import get_tracer, tracing
+
+    ens, ds = cov_model
+    server = GBDTServer(ens, strategy="staged", backend="ref",
+                        max_batch=16, buckets=(16,))
+    try:
+        server.predict(ds.x_test[0])          # compile outside the ring
+        tracer = get_tracer()
+        with tracing(tracer, clear=True):
+            server.predict(ds.x_test[1])
+            server.predict_batch(ds.x_test[:3])
+            events = [e for e in tracer.events()
+                      if e["name"] == "serve/batch"]
+        queued, direct = events
+        assert queued["args"]["queue_wait_s"] > 0.0
+        assert direct["args"]["queue_wait_s"] == 0.0   # never queued
+        snap = server.metrics.snapshot()
+        assert snap["queue_wait_s"] >= queued["args"]["queue_wait_s"]
     finally:
         server.close()
 

@@ -11,6 +11,7 @@ process may load the TPU library, and pytest-xdist imports this file in
 every worker.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +23,7 @@ from repro.kernels import fused_predict as fk
 from repro.kernels import histogram as hk
 from repro.kernels import leaf_gather as gk
 from repro.kernels import leaf_index as ik
-from repro.kernels import tuning
+from repro.kernels import ops, tuning
 
 F, FP, B, D, C, T = 54, 128, 254, 8, 7, 10_000
 L = 1 << D
@@ -138,3 +139,48 @@ def test_fused_layout_variants(one_chip, layout):
         s((n, FP), jnp.float32), s((B, FP), jnp.float32),
         s((D, 1024), jnp.int32), s((D, 1024), jnp.int32),
         s((1024, L, C), jnp.float32))
+
+
+def test_bulk_entries_name_kernels_and_stages(one_chip, monkeypatch):
+    """The bulk path's plan entries (quantize, then proba_pool) at
+    covertype widths: each kernel's op is named after its benchmark
+    trace name, and every kernel and the softmax carry their
+    `gbdt/<stage>` scope in their op_name metadata."""
+    import numpy as np
+
+    from repro.core.predictor import PredictConfig, Predictor
+    from repro.core.trees import ObliviousEnsemble
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    t, rng = 64, np.random.default_rng(0)
+    ens = ObliviousEnsemble(
+        jnp.asarray(rng.integers(0, F, (t, D)).astype(np.int32)),
+        jnp.asarray(rng.integers(1, B, (t, D)).astype(np.int32)),
+        jnp.asarray(rng.normal(size=(t, L, C)).astype(np.float32)),
+        jnp.asarray(np.sort(rng.normal(size=(B, F)), 0).astype(np.float32)),
+        jnp.full((F,), B, jnp.int32))
+    # the layout the 10,000-tree plan resolves to (few trees would
+    # pick depth_major)
+    plan = Predictor.build(ens, PredictConfig(strategy="staged",
+                                              backend="pallas",
+                                              layout="soa"))
+    model = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
+                         plan.lowered)
+    ops_seen = {}
+    for entry, dtype in (("quantize", jnp.float32),
+                         ("proba_pool", jnp.uint8)):
+        text = plan._entries[entry].lower(
+            model, _sds(one_chip, (256, F), dtype)).compile().as_text()
+        for m in re.finditer(r'%(\S+) = \S+ (\S+?)\(.*op_name="([^"]*)"',
+                             text):
+            ops_seen[m.group(1)] = (m.group(2), m.group(3))
+    kernels = {name.split(".")[0]: op_name
+               for name, (kind, op_name) in ops_seen.items()
+               if kind == "custom-call"}
+    assert sorted(kernels) == ["binarize", "leaf_gather", "leaf_index_u8"]
+    for name, stage in (("binarize", "binarize"),
+                        ("leaf_index_u8", "leaf_index"),
+                        ("leaf_gather", "leaf_gather")):
+        assert f"/gbdt/{stage}/" in kernels[name], kernels[name]
+    assert any("/gbdt/softmax/" in op_name
+               for _, op_name in ops_seen.values())

@@ -178,7 +178,7 @@ def _model_bytes(cell: Cell, refs: list[Any]) -> Optional[int]:
     if cell.op == "leaf_gather":
         idx, lv, _out = refs
         bt, bn = idx.shape
-        _, l, c = lv.shape
+        _, c, l = lv.shape                 # class-major leaf block
         return tuning.leaf_gather_footprint(bn, bt, l, c)
     if cell.op == "fused_predict":
         # x, borders, <layout's model arrays>, lv, out, idx, bins scratch
@@ -192,7 +192,7 @@ def _model_bytes(cell: Cell, refs: list[Any]) -> Optional[int]:
         else:
             d = model.shape[1]
         gather = "bitplane" if cell.layout == "bitpacked" else "mxu"
-        _, l, c = lv.shape
+        _, c, l = lv.shape                 # class-major leaf block
         return tuning.fused_footprint(x.shape[0], bt, x.shape[1], d, l, c,
                                       borders.shape[0],
                                       bins_bytes=ib(scratch),

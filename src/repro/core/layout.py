@@ -16,7 +16,7 @@ tuner picks among them from the ensemble's shape.
 Four layouts:
 
   soa            today's structure-of-arrays — (T, D) splits, one
-                 (T, 2^Dmax, C) leaf table — the compatibility default.
+                 leaf table — the compatibility default.
   depth_major    splits transposed to (D, T) bit-plane order with the
                  one-hot feature-gather matrix onehot(sf) (T, D, F) and
                  the per-depth pow2 vector precomputed at lower time:
@@ -41,6 +41,13 @@ Four layouts:
                  (<= 1 border per feature) the uint8 pool itself packs
                  into u1 feature planes — `pack_pool_u1` — an 8x pool
                  memory shrink.
+
+Every layout holds its leaf tables in one class-major form, (T, Cp, L)
+with Cp = C rounded up to `ops.CLASS_ALIGN` (8) and zero leaves in the
+padded classes: per tree a (Cp, L) table of whole (8, 128) tiles with
+the leaves on lanes, the layout the device stores it in and the one the
+leaf-sum kernels read, so no relayout runs per call.  The model format
+(T, L, C) of `ObliviousEnsemble` and training is unchanged.
 
 Every layout is bit-for-bit the same *math* as the logical model:
 identical leaf indices, identical per-tree leaf values.
@@ -108,9 +115,10 @@ class SoaLayout:
     borders: jax.Array           # (B, Fp) f32
     split_features: jax.Array    # (Tp, D) i32
     split_bins: jax.Array        # (Tp, D) i32
-    leaf_values: jax.Array       # (Tp, L, C) f32
+    leaf_values: jax.Array       # (Tp, Cp, L) f32, class-major
     # staged tree blocking: per-block (sf, sb, lv) slices, pre-cut and
-    # pre-padded at lower time so the per-call loop never touches jnp.pad
+    # pre-padded at lower time so the per-call loop never touches jnp.pad;
+    # the whole-model arrays above are then empty
     tree_blocks: Optional[tuple] = None
     n_outputs: int = 1           # static
     n_model_pads: int = 0        # static: model-side pads spent lowering
@@ -124,25 +132,28 @@ class SoaLayout:
                 idx = ops.leaf_index_prepadded(bins, sf, sb,
                                                backend=backend,
                                                block_t=block_t)
-                acc = acc + ops.leaf_gather_prepadded(idx, lv,
-                                                      backend=backend,
-                                                      block_t=block_t)
+                acc = acc + ops.leaf_gather_prepadded(
+                    idx, lv, n_classes=self.n_outputs, backend=backend,
+                    block_t=block_t)
             return acc
         idx = ops.leaf_index_prepadded(bins, self.split_features,
                                        self.split_bins, backend=backend,
                                        block_t=block_t)
         return ops.leaf_gather_prepadded(idx, self.leaf_values,
+                                         n_classes=self.n_outputs,
                                          backend=backend, block_t=block_t)
 
     def fused_raw(self, x: jax.Array, *, backend: str, block_n: int,
                   block_t: int) -> jax.Array:
         return ops.fused_predict_prepadded(
             x, self.borders, self.split_features, self.split_bins,
-            self.leaf_values, backend=backend, block_n=block_n,
-            block_t=block_t)
+            self.leaf_values, n_classes=self.n_outputs, backend=backend,
+            block_n=block_n, block_t=block_t)
 
     def leaf_table_bytes(self) -> int:
-        return int(np.prod(self.leaf_values.shape)) * 4
+        tables = ([lv for _, _, lv in self.tree_blocks]
+                  if self.tree_blocks else [self.leaf_values])
+        return sum(int(np.prod(lv.shape)) * 4 for lv in tables)
 
     def describe(self) -> dict[str, Any]:
         return {"layout": self.layout_name,
@@ -159,7 +170,7 @@ class DepthMajorLayout:
     onehot: jax.Array            # (Tp, D, Fp) f32 — onehot(sf[t, d])
     split_bins_dm: jax.Array     # (D, Tp) i32 — bit-plane transposed
     pow2: jax.Array              # (D, 1) f32 — hoisted 2^d vector
-    leaf_values: jax.Array       # (Tp, L, C) f32
+    leaf_values: jax.Array       # (Tp, Cp, L) f32, class-major
     n_outputs: int = 1           # static
     n_model_pads: int = 0        # static
 
@@ -169,14 +180,15 @@ class DepthMajorLayout:
                                           self.split_bins_dm, self.pow2,
                                           backend=backend, block_t=block_t)
         return ops.leaf_gather_prepadded(idx, self.leaf_values,
+                                         n_classes=self.n_outputs,
                                          backend=backend, block_t=block_t)
 
     def fused_raw(self, x: jax.Array, *, backend: str, block_n: int,
                   block_t: int) -> jax.Array:
         return ops.fused_predict_dm_prepadded(
             x, self.borders, self.onehot, self.split_bins_dm, self.pow2,
-            self.leaf_values, backend=backend, block_n=block_n,
-            block_t=block_t)
+            self.leaf_values, n_classes=self.n_outputs, backend=backend,
+            block_n=block_n, block_t=block_t)
 
     def leaf_table_bytes(self) -> int:
         return int(np.prod(self.leaf_values.shape)) * 4
@@ -196,7 +208,7 @@ class DepthGroup:
     depth: int                   # static: true depth d of the group
     split_features: jax.Array    # (Tg_p, d) i32
     split_bins: jax.Array        # (Tg_p, d) i32
-    leaf_values: jax.Array       # (Tg_p, 2^d, C) f32
+    leaf_values: jax.Array       # (Tg_p, Cp, 2^d) f32, class-major
 
     @property
     def n_trees(self) -> int:
@@ -219,9 +231,9 @@ class DepthGroupedLayout:
             idx = ops.leaf_index_prepadded(bins, g.split_features,
                                            g.split_bins, backend=backend,
                                            block_t=block_t)
-            acc = acc + ops.leaf_gather_prepadded(idx, g.leaf_values,
-                                                  backend=backend,
-                                                  block_t=block_t)
+            acc = acc + ops.leaf_gather_prepadded(
+                idx, g.leaf_values, n_classes=self.n_outputs,
+                backend=backend, block_t=block_t)
         return acc
 
     def fused_raw(self, x: jax.Array, *, backend: str, block_n: int,
@@ -251,7 +263,7 @@ class BitpackedGroup:
     depth: int                   # static: true depth d of the group
     split_features_bp: jax.Array  # (d, Tg_p) i32 — bit-plane transposed
     split_bins_bp: jax.Array     # (d, Tg_p) u8 when thresholds fit, else i32
-    leaf_values: jax.Array       # (Tg_p, 2^d, C) f32
+    leaf_values: jax.Array       # (Tg_p, Cp, 2^d) f32, class-major
 
     @property
     def n_trees(self) -> int:
@@ -280,9 +292,9 @@ class BitpackedLayout:
                                               g.split_bins_bp,
                                               backend=backend,
                                               block_t=block_t)
-            acc = acc + ops.leaf_gather_prepadded(idx, g.leaf_values,
-                                                  backend=backend,
-                                                  block_t=block_t)
+            acc = acc + ops.leaf_gather_prepadded(
+                idx, g.leaf_values, n_classes=self.n_outputs,
+                backend=backend, block_t=block_t)
         return acc
 
     def fused_raw(self, x: jax.Array, *, backend: str, block_n: int,
@@ -291,8 +303,8 @@ class BitpackedLayout:
             g = self.groups[0]
             return ops.fused_predict_bp_prepadded(
                 x, self.borders, g.split_features_bp, g.split_bins_bp,
-                g.leaf_values, backend=backend, block_n=block_n,
-                block_t=block_t)
+                g.leaf_values, n_classes=self.n_outputs, backend=backend,
+                block_n=block_n, block_t=block_t)
         # multiple groups: binarize once and reuse the grouped
         # index+gather loop (same rationale as DepthGroupedLayout —
         # per-group fusion would re-binarize x against every border
@@ -535,7 +547,7 @@ LAYOUTS: dict[str, LayoutSpec] = {
         paper_analog="CatBoost SoA model arrays (compatibility default)",
         claimed_ops=("binarize", "leaf_index", "leaf_gather",
                      "fused_predict"),
-        memory="T x 2^Dmax x C leaf table; (T, D) splits",
+        memory="T x Cp x 2^Dmax leaf table; (T, D) splits",
         when="uniform shallow models; tracer ensembles (sharded shards)"),
     "depth_major": LayoutSpec(
         name="depth_major", cls=DepthMajorLayout,
@@ -549,7 +561,7 @@ LAYOUTS: dict[str, LayoutSpec] = {
         paper_analog="equal-depth tree grouping (CalcTreesBlockedImpl)",
         claimed_ops=("binarize", "leaf_index", "leaf_gather",
                      "fused_predict"),
-        memory="sum_d T_d x 2^d x C leaf tables (< soa when depths mix)",
+        memory="sum_d T_d x Cp x 2^d leaf tables (< soa when depths mix)",
         when="mixed true depths with enough shallow-tree savings"),
     "bitpacked": LayoutSpec(
         name="bitpacked", cls=BitpackedLayout,
@@ -642,12 +654,15 @@ class _LowerCtx:
         return self.pad(borders, 1, fp, value=np.float32(np.inf))
 
     def pad_trees(self, sf, sb, lv):
-        if not self.pallas:
-            return sf, sb, lv
-        tp = ops._round_up(max(sf.shape[0], 1), self.t_align)
-        return (self.pad(sf, 0, tp), self.pad(sb, 0, tp,
-                                              value=PAD_SPLIT_BIN),
-                self.pad(lv, 0, tp))
+        """Pad the tree axis (pallas) and lower the (T, L, C) leaf table
+        to the class-major (Tp, Cp, L) form every layout holds."""
+        T, _, C = lv.shape
+        tp = (ops._round_up(max(T, 1), self.t_align) if self.pallas
+              else T)
+        lv_cm = ops.class_major(lv, tp)
+        self.n_pads += (tp != T) + (lv_cm.shape[1] != C)
+        return (self.pad(sf, 0, tp),
+                self.pad(sb, 0, tp, value=PAD_SPLIT_BIN), lv_cm)
 
 
 def _lower_soa(ensemble, ctx: _LowerCtx, tree_block: int) -> SoaLayout:
@@ -659,12 +674,12 @@ def _lower_soa(ensemble, ctx: _LowerCtx, tree_block: int) -> SoaLayout:
                 start, min(start + tree_block, ensemble.n_trees))
             blocks.append(ctx.pad_trees(blk.split_features, blk.split_bins,
                                         blk.leaf_values))
-        # the blocked path never reads the whole-ensemble arrays, so keep
-        # the (unpadded) originals rather than holding a second padded
-        # copy of the full model
-        return SoaLayout(borders, ensemble.split_features,
-                         ensemble.split_bins, ensemble.leaf_values,
-                         tuple(blocks), n_outputs=ensemble.n_outputs,
+        # the blocked path never reads the whole-ensemble arrays, so they
+        # are empty rather than a second copy of the full model
+        return SoaLayout(borders, ensemble.split_features[:0],
+                         ensemble.split_bins[:0], blocks[0][2][:0],
+                         tuple(blocks),
+                         n_outputs=ensemble.n_outputs,
                          n_model_pads=ctx.n_pads)
     sf, sb, lv = ctx.pad_trees(ensemble.split_features, ensemble.split_bins,
                                ensemble.leaf_values)

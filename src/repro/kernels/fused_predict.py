@@ -10,7 +10,8 @@ t-block 0 into VMEM scratch and reused for every tree block (the grid's
 T axis is serial on TPU).  The three stages are the standalone kernels'
 own bodies (`binarize.count_borders`, `leaf_index.*_index`,
 `leaf_gather.accumulate_leaves`), so fused and staged plans run the same
-arithmetic.
+arithmetic, and all three kernels read the same class-major (bt, Cp, L)
+leaf block.
 
 Grid: (N / block_n, T / block_t).
 """
@@ -54,7 +55,7 @@ def _fused_call(kernel, x, borders, model_args, model_specs, leaf_values,
                 name):
     N, F = x.shape
     B = borders.shape[0]
-    _, L, C = leaf_values.shape
+    _, C, L = leaf_values.shape
     if N % block_n or T % block_t:
         raise ValueError(
             f"{name} requires padded inputs: N={N} % block_n="
@@ -66,7 +67,7 @@ def _fused_call(kernel, x, borders, model_args, model_specs, leaf_values,
         in_specs=[pl.BlockSpec((block_n, F), lambda i, j: (i, 0)),
                   pl.BlockSpec((B, F), lambda i, j: (0, 0))]
         + model_specs
-        + [pl.BlockSpec((block_t, L, C), lambda i, j: (j, 0, 0))],
+        + [pl.BlockSpec((block_t, C, L), lambda i, j: (j, 0, 0))],
         out_specs=pl.BlockSpec((C, block_n), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((C, N), jnp.float32),
         # the tree-major index block stage 3 walks row by row, then the
@@ -87,13 +88,15 @@ def fused_predict(x: jax.Array, borders: jax.Array, split_features: jax.Array,
                   block_n: int = 128, block_t: int = 16,
                   interpret: bool = False,
                   bins_scratch_dtype=jnp.int32) -> jax.Array:
-    """Fused GBDT predict -> (C, N) float32 (class-major, samples on
-    lanes; `kernels.ops` transposes).
+    """Fused GBDT predict -> (Cp, N) float32 (class-major, samples on
+    lanes; `kernels.ops` transposes and drops the padded classes).
 
     Raw kernel entry: N and T must already be multiples of the block
     shapes (block_n a multiple of 128, block_t of 8), F a multiple of
-    128, and padded trees must carry zero leaf_values and split_bins >
-    #bins (padded samples/features are harmless zeros).
+    128, `leaf_values` the class-major (T, Cp, L) table with Cp a
+    multiple of 8, and padded trees must carry zero leaf_values and
+    split_bins > #bins (padded samples/features/classes are harmless
+    zeros).
     `kernels.ops.fused_predict` is the public wrapper that performs that
     padding and picks the block shapes from the tuner — call it, not
     this, unless you have pre-padded tensors.  `bins_scratch_dtype`
@@ -130,7 +133,7 @@ def fused_predict_dm(x: jax.Array, borders: jax.Array, onehot: jax.Array,
                      block_n: int = 128, block_t: int = 16,
                      interpret: bool = False,
                      bins_scratch_dtype=jnp.int32) -> jax.Array:
-    """Fused GBDT predict over the depth-major lowered layout -> (C, N).
+    """Fused GBDT predict over the depth-major lowered layout -> (Cp, N).
 
     Same contract as `fused_predict` with the model side replaced by
     the `DepthMajorLayout` arrays: `onehot` (T, D, F) f32 precomputed
@@ -168,7 +171,7 @@ def fused_predict_bp(x: jax.Array, borders: jax.Array,
                      block_n: int = 128,
                      interpret: bool = False,
                      bins_scratch_dtype=jnp.int32) -> jax.Array:
-    """Fused GBDT predict over the bitpacked lowered layout -> (C, N).
+    """Fused GBDT predict over the bitpacked lowered layout -> (Cp, N).
 
     Same contract as `fused_predict` with the model side replaced by
     the `BitpackedLayout` bit-plane arrays: `split_features_bp` /

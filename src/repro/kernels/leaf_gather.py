@@ -4,13 +4,17 @@
 This is the hotspot the paper explicitly could NOT vectorize: RVV 0.7.1
 gather/scatter is too slow to pay for the few arithmetic ops per element
 (their Tables 2-3 show speedup 0.98-1.03x).  The TPU answer is to avoid
-the gather unit entirely: `sum_t leaf_values[t, idx[n, t], :]` becomes a
-one-hot matmul `leaf_values[t]^T @ onehot(idx[:, t])` on the 128x128
+the gather unit entirely: `sum_t leaf_values[t, :, idx[n, t]]` becomes a
+one-hot matmul `leaf_values[t] @ onehot(idx[:, t])` on the 128x128
 MXU, one tree at a time.  The indirect addressing turns into dense systolic
 compute.
 
 The index arrives tree-major, (T, N) — the layout `leaf_index` writes —
 so its blocks are lane-dense for any tree block that is a multiple of 8.
+The leaf table arrives class-major, (T, Cp, L) with Cp a multiple of 8
+(`layout.lower` builds it once): each tree's (Cp, L) table is whole
+(8, 128) tiles with the leaves on lanes, which is also the layout the
+device stores the array in, so XLA inserts no relayout before the call.
 
 Grid: (N / block_n, T / block_t) with the T axis as a serial reduction;
 the output tile is initialized at t-block 0 and accumulated in place.
@@ -31,25 +35,26 @@ LEAF_PRECISION = jax.lax.Precision.HIGHEST
 
 
 def accumulate_leaves(idx_t_ref, lv_ref) -> jax.Array:
-    """sum_t lv[t, idx_t[t, :], :] over one tree block -> (C, bn) f32.
+    """sum_t lv[t, :, idx_t[t, :]] over one tree block -> (Cp, bn) f32.
 
     `idx_t_ref` is the tree-major (bt, bn) index block, `lv_ref` the
-    (bt, L, C) leaf-value block.  One tree per loop step: its index row
-    against a sublane iota is the (L, bn) one-hot, contracted with that
-    tree's (L, C) table on the MXU.  The one-hot is exact, and the
-    contraction runs at HIGHEST precision so the sum carries full
-    float32 leaf values.  Samples stay on lanes throughout, so the sum
-    comes out (C, bn); `kernels.ops` transposes the (C, N) result.
-    Shared by `leaf_gather` and the fused kernels' stage 3."""
+    class-major (bt, Cp, L) leaf-value block.  One tree per loop step:
+    its index row against a sublane iota is the (L, bn) one-hot, and
+    that tree's (Cp, L) table times it on the MXU is the tree's (Cp, bn)
+    contribution.  The one-hot is exact, and the contraction runs at
+    HIGHEST precision so the sum carries full float32 leaf values.
+    Samples stay on lanes throughout, so the sum comes out (Cp, bn);
+    `kernels.ops` transposes the (Cp, N) result and drops the padded
+    classes.  Shared by `leaf_gather` and the fused kernels' stage 3."""
     bt, bn = idx_t_ref.shape
-    _, L, C = lv_ref.shape
+    _, C, L = lv_ref.shape
     leaf_iota = jax.lax.broadcasted_iota(jnp.int32, (L, bn), 0)
 
     def body(t, acc):
         onehot = (leaf_iota == idx_t_ref[pl.ds(t, 1), :]).astype(
             jnp.float32)                                     # (L, bn)
         return acc + jax.lax.dot_general(
-            lv_ref[t], onehot, (((0,), (0,)), ((), ())),
+            lv_ref[t], onehot, (((1,), (0,)), ((), ())),
             precision=LEAF_PRECISION, preferred_element_type=jnp.float32)
 
     return jax.lax.fori_loop(0, bt, body, jnp.zeros((C, bn), jnp.float32))
@@ -77,15 +82,17 @@ def _leaf_gather_kernel(idx_ref, lv_ref, out_ref):
 def leaf_gather(idx_t: jax.Array, leaf_values: jax.Array, *,
                 block_n: int = 128, block_t: int = 16,
                 interpret: bool = False) -> jax.Array:
-    """pred^T[c, n] = sum_t leaf_values[t, idx_t[t, n], c] -> (C, N) f32.
+    """pred^T[c, n] = sum_t leaf_values[t, c, idx_t[t, n]] -> (Cp, N) f32.
 
-    `idx_t` is the tree-major (T, N) index `leaf_index` writes; the sum
-    comes out class-major too (samples on lanes).
+    `idx_t` is the tree-major (T, N) index `leaf_index` writes and
+    `leaf_values` the class-major (T, Cp, L) table; the sum comes out
+    class-major too (samples on lanes).
     Pre-padded: N % block_n == 0 (block_n a multiple of 128), T %
-    block_t == 0.  Padded trees must have all-zero leaf_values.
+    block_t == 0, Cp a multiple of 8.  Padded trees and classes must
+    have all-zero leaf_values.
     """
     T, N = idx_t.shape
-    _, L, C = leaf_values.shape
+    _, C, L = leaf_values.shape
     if N % block_n or T % block_t:
         raise ValueError(
             f"leaf_gather requires padded inputs: N={N} % block_n="
@@ -96,7 +103,7 @@ def leaf_gather(idx_t: jax.Array, leaf_values: jax.Array, *,
         grid=(N // block_n, T // block_t),
         in_specs=[
             pl.BlockSpec((block_t, block_n), lambda i, j: (j, i)),
-            pl.BlockSpec((block_t, L, C), lambda i, j: (j, 0, 0)),
+            pl.BlockSpec((block_t, C, L), lambda i, j: (j, 0, 0)),
         ],
         out_specs=pl.BlockSpec((C, block_n), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((C, N), jnp.float32),

@@ -45,6 +45,10 @@ FEATURE_ALIGN = 128
 # representation (CatBoost's 255-border cap: ids span [0, B] <= 255).
 MAX_U8_BORDERS = 255
 
+# Sublane tile height the lowered leaf table pads its class axis to, so
+# a (Cp, L) per-tree table is whole (8, 128) tiles with leaves on lanes.
+CLASS_ALIGN = 8
+
 
 @functools.cache
 def default_platform() -> str:
@@ -107,6 +111,35 @@ def _pad_dim(a: jax.Array, axis: int, target: int, value=0,
         # a typed fill keeps integer constants out of float traces
         value = np.asarray(value, a.dtype)
     return jnp.pad(a, widths, constant_values=value)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _transpose_pad(leaf_values, n_trees, n_classes):
+    T, _, C = leaf_values.shape
+    # a typed fill keeps integer constants out of float traces
+    return jnp.pad(jnp.transpose(leaf_values, (0, 2, 1)),
+                   ((0, n_trees - T), (0, n_classes - C), (0, 0)),
+                   constant_values=np.zeros((), leaf_values.dtype))
+
+
+def class_major(leaf_values: jax.Array,
+                n_trees: int | None = None) -> jax.Array:
+    """Model-format leaf table (T, L, C) -> the lowered class-major form
+    (n_trees, Cp, L), Cp = C rounded up to `CLASS_ALIGN`; padded trees
+    (n_trees defaults to T) and classes hold zero leaves.  The leaf-sum
+    kernels read only this form.  One jitted pass: the transpose and
+    both pads write a single output, with no intermediate copy."""
+    T, _, C = leaf_values.shape
+    n_trees = T if n_trees is None else n_trees
+    n_classes = _round_up(C, CLASS_ALIGN)
+    _PAD_STATS["model"] += (n_trees != T) + (n_classes != C)
+    return _transpose_pad(leaf_values, n_trees, n_classes)
+
+
+def leaf_major(leaf_values_cm: jax.Array, n_classes: int) -> jax.Array:
+    """Inverse of `class_major`: (T, Cp, L) -> the model format
+    (T, L, n_classes), padded classes dropped."""
+    return jnp.transpose(leaf_values_cm[:, :n_classes, :], (0, 2, 1))
 
 
 def pad_features(bins: jax.Array, target_f: int) -> jax.Array:
@@ -348,10 +381,17 @@ def _leaf_index_pallas_bp(bins, sf_bp, sb_bp, *, block_n=256, block_t=None,
 # --------------------------------------------------------------------------
 # Registered implementations: leaf_gather
 # --------------------------------------------------------------------------
+# A registered leaf_gather / fused_predict impl takes the model-format
+# table (T, L, C), or with `prepadded=True` the lowered class-major
+# table (Tp, Cp, L) and the `n_classes` C it holds.  The kernels sum
+# (Cp, N); the impls slice the padded classes off before returning.
 @registry.register("leaf_gather", "ref", dtypes=("int32",),
                    layouts=ALL_LAYOUTS,
                    constraints="any shape; pure-jnp oracle")
-def _leaf_gather_ref(idx, leaf_values, *, prepadded=False, **_blocks):
+def _leaf_gather_ref(idx, leaf_values, *, prepadded=False, n_classes=None,
+                     **_blocks):
+    if prepadded:
+        leaf_values = leaf_major(leaf_values, n_classes)
     return _ref.leaf_gather(idx, leaf_values)
 
 
@@ -359,16 +399,20 @@ def _leaf_gather_ref(idx, leaf_values, *, prepadded=False, **_blocks):
                    layouts=ALL_LAYOUTS,
                    constraints="pads N/T to block multiples")
 def _leaf_gather_pallas(idx, leaf_values, *, block_n=128, block_t=16,
-                        prepadded=False):
+                        prepadded=False, n_classes=None):
     # the kernel reads the index tree-major (see _leaf_index_pallas_impl)
     block_n = _lanes(block_n)
     N, T = idx.shape
-    Tp = T if prepadded else _round_up(T, block_t)
+    if prepadded:
+        Tp, lvp = T, leaf_values
+    else:
+        Tp, n_classes = _round_up(T, block_t), leaf_values.shape[2]
+        # zero leaves: padded trees are no-ops
+        lvp = class_major(leaf_values, Tp)
     idxp = _pad_dim(_pad_dim(idx, 0, _round_up(max(N, 1), block_n)), 1, Tp)
-    lvp = _pad_dim(leaf_values, 0, Tp, kind="model")  # zero leaves: no-op trees
     out = _gather_k.leaf_gather(idxp.T, lvp, block_n=block_n,
                                 block_t=block_t, interpret=_interpret())
-    return out.T[:N]
+    return out.T[:N, :n_classes]
 
 
 # --------------------------------------------------------------------------
@@ -411,9 +455,11 @@ def _l2sq_pallas(a, b, *, block_m=128, block_n=128, block_k=128):
 @registry.register("fused_predict", "ref", dtypes=("int32",),
                    layouts=SOA_LAYOUTS,
                    constraints="any shape; pure-jnp oracle")
-def _fused_ref(x, borders, sf, sb, lv, *, prepadded=False, **_blocks):
+def _fused_ref(x, borders, sf, sb, lv, *, prepadded=False, n_classes=None,
+               **_blocks):
     if prepadded:
         x = _pad_dim(x, 1, borders.shape[1])
+        lv = leaf_major(lv, n_classes)
     return _ref.fused_predict(x, borders, sf, sb, lv)
 
 
@@ -422,7 +468,7 @@ def _fused_ref(x, borders, sf, sb, lv, *, prepadded=False, **_blocks):
                    constraints="pads N/T/F to block multiples; u8 bins "
                                "scratch when <= 255 borders")
 def _fused_pallas(x, borders, sf, sb, lv, *, block_n=None, block_t=None,
-                  prepadded=False):
+                  prepadded=False, n_classes=None):
     # uint8 scratch quarters the VMEM the binarized block occupies
     # across tree blocks whenever the bin ids fit a byte — exact either
     # way, so this is not a user-facing choice.
@@ -437,7 +483,7 @@ def _fused_pallas(x, borders, sf, sb, lv, *, block_n=None, block_t=None,
                                      block_n=block_n, block_t=block_t,
                                      interpret=_interpret(),
                                      bins_scratch_dtype=scratch)
-        return out.T[:N]
+        return out.T[:N, :n_classes]
     N, F = x.shape
     T, D = sf.shape
     _, L, C = lv.shape
@@ -454,20 +500,21 @@ def _fused_pallas(x, borders, sf, sb, lv, *, block_n=None, block_t=None,
     bp = _pad_dim(borders, 1, Fp, value=np.float32(np.inf), kind="model")
     sfp = _pad_dim(sf, 0, Tp, kind="model")
     sbp = _pad_dim(sb, 0, Tp, value=PAD_SPLIT_BIN, kind="model")
-    lvp = _pad_dim(lv, 0, Tp, kind="model")
+    lvp = class_major(lv, Tp)
     out = _fused_k.fused_predict(xp, bp, sfp, sbp, lvp, block_n=block_n,
                                  block_t=block_t, interpret=_interpret(),
                                  bins_scratch_dtype=scratch)
-    return out.T[:N]
+    return out.T[:N, :C]
 
 
 @registry.register("fused_predict", "ref_dm", dtypes=("int32",),
                    layouts=("depth_major",),
                    constraints="depth-major lowered model; any shape")
 def _fused_ref_dm(x, borders, onehot, sb_dm, pow2, lv, *, prepadded=False,
-                  **_blocks):
+                  n_classes=None, **_blocks):
     if prepadded:
         x = _pad_dim(x, 1, borders.shape[1])
+        lv = leaf_major(lv, n_classes)
     return _ref.fused_predict_depth_major(x, borders, onehot, sb_dm,
                                           pow2, lv)
 
@@ -478,18 +525,22 @@ def _fused_ref_dm(x, borders, onehot, sb_dm, pow2, lv, *, prepadded=False,
                                "at lower time); pads N per call; u8 bins "
                                "scratch when <= 255 borders")
 def _fused_pallas_dm(x, borders, onehot, sb_dm, pow2, lv, *,
-                     block_n=None, block_t=None, prepadded=False):
+                     block_n=None, block_t=None, prepadded=False,
+                     n_classes=None):
     scratch = (jnp.uint8 if borders.shape[0] <= MAX_U8_BORDERS
                else jnp.int32)
+    T, D, F = onehot.shape
+    if not prepadded:
+        n_classes, lv = lv.shape[2], class_major(lv, T)
     if block_n is None or block_t is None:
         # same autotune fallback as the soa impl (plans always pass
         # concrete blocks; direct registry dispatch may not) — except
         # the model side is already lowered here, so block_t must
         # divide the pre-padded T rather than drive its padding
-        T, D, F = onehot.shape
-        _, L, C = lv.shape
+        L = lv.shape[2]
         tn, tt = _tuning.best_fused_blocks(
-            F, D, L, C, borders.shape[0], n_rows=x.shape[0], n_trees=T)
+            F, D, L, n_classes, borders.shape[0], n_rows=x.shape[0],
+            n_trees=T)
         block_n = block_n or tn
         if block_t is None:
             block_t = next(bt for bt in (tt, 64, 32, 16, 8, 4, 2, 1)
@@ -502,16 +553,17 @@ def _fused_pallas_dm(x, borders, onehot, sb_dm, pow2, lv, *,
                                     block_n=block_n, block_t=block_t,
                                     interpret=_interpret(),
                                     bins_scratch_dtype=scratch)
-    return out.T[:N]
+    return out.T[:N, :n_classes]
 
 
 @registry.register("fused_predict", "ref_bp", dtypes=("int32",),
                    layouts=("bitpacked",),
                    constraints="bitpacked lowered model; any shape")
 def _fused_ref_bp(x, borders, sf_bp, sb_bp, lv, *, prepadded=False,
-                  **_blocks):
+                  n_classes=None, **_blocks):
     if prepadded:
         x = _pad_dim(x, 1, borders.shape[1])
+        lv = leaf_major(lv, n_classes)
     return _ref.fused_predict_bitpacked(x, borders, sf_bp, sb_bp, lv)
 
 
@@ -526,17 +578,18 @@ def _fused_ref_bp(x, borders, sf_bp, sb_bp, lv, *, prepadded=False,
                        "widens to int32 in registers before the integer "
                        "gather",))
 def _fused_pallas_bp(x, borders, sf_bp, sb_bp, lv, *, block_n=None,
-                     block_t=None, prepadded=False):
+                     block_t=None, prepadded=False, n_classes=None):
     # block_t is accepted for the shared call convention; the bitplane
     # kernel's tree block is always one 128-tree lane
     scratch = (jnp.uint8 if borders.shape[0] <= MAX_U8_BORDERS
                else jnp.int32)
     D, T = sf_bp.shape
+    if not prepadded:
+        n_classes, lv = lv.shape[2], class_major(lv)
     if block_n is None:
-        _, L, C = lv.shape
         block_n, _ = _tuning.best_fused_blocks(
-            borders.shape[1], D, L, C, borders.shape[0], n_rows=x.shape[0],
-            n_trees=T, gather="bitplane")
+            borders.shape[1], D, lv.shape[2], n_classes, borders.shape[0],
+            n_rows=x.shape[0], n_trees=T, gather="bitplane")
     block_n = _lanes(block_n)
     N = x.shape[0]
     bp = _pad_dim(borders, 1, _round_up(borders.shape[1], FEATURE_ALIGN),
@@ -547,7 +600,7 @@ def _fused_pallas_bp(x, borders, sf_bp, sb_bp, lv, *, block_n=None,
                                     block_n=block_n,
                                     interpret=_interpret(),
                                     bins_scratch_dtype=scratch)
-    return out.T[:N]
+    return out.T[:N, :n_classes]
 
 
 # --------------------------------------------------------------------------
@@ -662,7 +715,10 @@ def leaf_index(bins: jax.Array, split_features: jax.Array,
 def leaf_gather(idx: jax.Array, leaf_values: jax.Array, *,
                 backend: Backend = "auto", block_n: int = 128,
                 block_t: int = 16) -> jax.Array:
-    """(N, T) i32, (T, L, C) f32 -> (N, C) f32 summed leaf values."""
+    """(N, T) i32, (T, L, C) f32 -> (N, C) f32 summed leaf values.
+
+    Takes the model format; the pallas impl lowers it to the kernel's
+    class-major form per call (the plans lower it once)."""
     return registry.dispatch("leaf_gather", backend, idx, leaf_values,
                              block_n=block_n, block_t=block_t)
 
@@ -728,9 +784,12 @@ def fused_predict(x: jax.Array, borders: jax.Array, split_features: jax.Array,
 # Invariants the builder guarantees for the pallas backend:
 #   borders  F padded to a FEATURE_ALIGN multiple with +inf
 #   splits   T padded to a block_t multiple (bins=PAD_SPLIT_BIN: go left)
-#   leaves   T padded with zeros (padded trees contribute nothing)
-# On the ref backend the same arrays work unpadded — ref kernels accept
-# any shape — so a ref plan carries the original arrays through.
+#   leaves   class-major (Tp, Cp, L), Cp = C rounded up to CLASS_ALIGN,
+#            padded trees and classes all zero (they contribute nothing)
+# On the ref backend the structure arrays stay unpadded — ref kernels
+# accept any shape — and the leaf table is class-major all the same.
+# The leaf-summing entries take `n_classes`, the C the table holds, and
+# return (N, C): the padded classes never leave them.
 # Each entry runs under `jax.named_scope("gbdt/<stage>")`, so every HLO
 # op a plan stage emits (the kernel, its pads, slices and transposes)
 # carries the stage in its op_name metadata.
@@ -738,15 +797,16 @@ def fused_predict(x: jax.Array, borders: jax.Array, split_features: jax.Array,
 @jax.named_scope("gbdt/fused_predict")
 def fused_predict_prepadded(x: jax.Array, borders: jax.Array,
                             split_features: jax.Array, split_bins: jax.Array,
-                            leaf_values: jax.Array, *,
+                            leaf_values: jax.Array, *, n_classes: int,
                             backend: Backend = "auto",
                             block_n: int = 128,
                             block_t: int = 16) -> jax.Array:
-    """Fused predict on a prepadded model -> (N, C) f32."""
+    """Fused predict on a prepadded model (class-major leaf table)
+    -> (N, n_classes) f32."""
     return registry.dispatch("fused_predict", backend, x, borders,
                              split_features, split_bins, leaf_values,
                              block_n=block_n, block_t=block_t,
-                             prepadded=True)
+                             prepadded=True, n_classes=n_classes)
 
 
 @jax.named_scope("gbdt/binarize")
@@ -792,12 +852,14 @@ def leaf_index_prepadded(bins: jax.Array, split_features: jax.Array,
 
 @jax.named_scope("gbdt/leaf_gather")
 def leaf_gather_prepadded(idx: jax.Array, leaf_values: jax.Array, *,
-                          backend: Backend = "auto", block_n: int = 128,
+                          n_classes: int, backend: Backend = "auto",
+                          block_n: int = 128,
                           block_t: int = 16) -> jax.Array:
-    """Sum prepadded leaf values at idx -> (N, C) f32."""
+    """Sum a class-major (Tp, Cp, L) leaf table at idx
+    -> (N, n_classes) f32."""
     return registry.dispatch("leaf_gather", backend, idx, leaf_values,
                              block_n=block_n, block_t=block_t,
-                             prepadded=True)
+                             prepadded=True, n_classes=n_classes)
 
 
 # --------------------------------------------------------------------------
@@ -827,15 +889,16 @@ def leaf_index_dm_prepadded(bins: jax.Array, onehot: jax.Array,
 def fused_predict_dm_prepadded(x: jax.Array, borders: jax.Array,
                                onehot: jax.Array, split_bins_dm: jax.Array,
                                pow2: jax.Array, leaf_values: jax.Array, *,
-                               backend: Backend = "auto",
+                               n_classes: int, backend: Backend = "auto",
                                block_n: int = 128,
                                block_t: int = 16) -> jax.Array:
-    """Fused predict on a depth-major lowered model -> (N, C) f32."""
+    """Fused predict on a depth-major lowered model
+    -> (N, n_classes) f32."""
     return registry.dispatch("fused_predict", backend, x, borders, onehot,
                              split_bins_dm, pow2, leaf_values,
                              layout="depth_major",
                              block_n=block_n, block_t=block_t,
-                             prepadded=True)
+                             prepadded=True, n_classes=n_classes)
 
 
 # --------------------------------------------------------------------------
@@ -865,12 +928,13 @@ def fused_predict_bp_prepadded(x: jax.Array, borders: jax.Array,
                                split_features_bp: jax.Array,
                                split_bins_bp: jax.Array,
                                leaf_values: jax.Array, *,
-                               backend: Backend = "auto",
+                               n_classes: int, backend: Backend = "auto",
                                block_n: int = 128,
                                block_t: int = 16) -> jax.Array:
-    """Fused predict on a bitpacked lowered model -> (N, C) f32."""
+    """Fused predict on a bitpacked lowered model
+    -> (N, n_classes) f32."""
     return registry.dispatch("fused_predict", backend, x, borders,
                              split_features_bp, split_bins_bp, leaf_values,
                              layout="bitpacked",
                              block_n=block_n, block_t=block_t,
-                             prepadded=True)
+                             prepadded=True, n_classes=n_classes)

@@ -13,6 +13,11 @@ Conventions (match CatBoost's oblivious-tree model):
   split_bins     (T, D)  int32     border id; go right iff bins[f] >= split_bin
   leaf_values    (T, 2^D, C) float32
   leaf index     idx[n, t] = sum_d  2^d * [ bins[n, sf[t,d]] >= sb[t,d] ]
+
+The oracles keep this model format.  The Pallas kernels read the leaf
+table in the class-major form `layout.lower` builds, (T, Cp, 2^D) with
+the classes padded to a multiple of 8; the registered `ref` impls in
+`kernels.ops` convert it back before calling these.
 """
 from __future__ import annotations
 

@@ -11,8 +11,9 @@ The footprint models count what Mosaic allocates, not the logical
 bytes: every VMEM buffer is padded to whole (sublane, lane) tiles —
 the lane dim to 128, the sublane dim to 8 rows of 32-bit words (16
 for 2-byte, 32 for 1-byte dtypes) — and every pipelined input/output
-block is double-buffered.  A (T, L, C=7) leaf table block therefore
-costs 128/7 of its logical size; a (1, N) uint8 row costs 32 rows.
+block is double-buffered.  A class-major (T, Cp, L) leaf table block
+at C=7 therefore costs 8/7 of its logical size, where the 7 classes on
+lanes would cost 128/7; a (1, N) uint8 row costs 32 rows.
 """
 from __future__ import annotations
 
@@ -96,11 +97,12 @@ def _bitplane_stage_bytes(block_n: int, F: int) -> int:
 
 def _accumulate_stage_bytes(block_n: int, block_t: int, L: int,
                             C: int) -> int:
-    """Stage-3 (leaf accumulate) working set: the (bt, L, C) leaf block
-    (lane-padded C), two live (bn, L) one-hots and the (bn, C) sum."""
-    return (2 * block_t * tile_bytes((L, C), 4)
-            + 2 * tile_bytes((block_n, L), 4)
-            + tile_bytes((block_n, C), 4))
+    """Stage-3 (leaf accumulate) working set: the class-major (bt, C, L)
+    leaf block (C sublane-padded to 8, L lane-padded to 128), two live
+    (L, bn) one-hots and the (C, bn) sum."""
+    return (_block((block_t, C, L), 4)
+            + 2 * tile_bytes((L, block_n), 4)
+            + tile_bytes((C, block_n), 4))
 
 
 def leaf_index_footprint(block_n: int, block_t: int, F: int, D: int, *,
@@ -125,7 +127,7 @@ def leaf_index_footprint(block_n: int, block_t: int, F: int, D: int, *,
 
 def leaf_gather_footprint(block_n: int, block_t: int, L: int, C: int) -> int:
     return (_block((block_t, block_n), 4)                # idx (trees, rows)
-            + _block((block_n, C), 4)                    # out
+            + _block((C, block_n), 4)                    # out (classes, rows)
             + tile_bytes((block_n, block_t), 4)          # idx transposed
             + _accumulate_stage_bytes(block_n, block_t, L, C))
 
@@ -147,7 +149,7 @@ def fused_footprint(block_n: int, block_t: int, F: int, D: int, L: int,
     else:
         stage2 = (2 * _block((block_t, D), 4)
                   + _gather_stage_bytes(block_n, block_t, F, bins_bytes))
-    return (stage1 + stage2 + _block((block_n, C), 4)
+    return (stage1 + stage2 + _block((C, block_n), 4)
             + _accumulate_stage_bytes(block_n, block_t, L, C))
 
 
@@ -368,12 +370,14 @@ def layout_costs(true_depths, n_outputs: int, n_features: int
                  ) -> dict[str, int]:
     """Leaf-table / lowered-array byte costs per layout for an ensemble
     with the given per-tree true depths (the inputs `best_layout` ranks
-    on; exposed for the bench and docs)."""
+    on; exposed for the bench and docs).  Leaf tables are counted as
+    lowered: classes padded to a sublane tile."""
     import numpy as np
     d = np.asarray(true_depths, np.int64)
     dmax = int(d.max()) if d.size else 1
-    soa_leaf = int(d.size) * (1 << dmax) * n_outputs * 4
-    grouped_leaf = int(((1 << np.maximum(d, 1)) * n_outputs * 4).sum())
+    cp = _round_up(max(n_outputs, 1), SUBLANE)
+    soa_leaf = int(d.size) * (1 << dmax) * cp * 4
+    grouped_leaf = int(((1 << np.maximum(d, 1)) * cp * 4).sum())
     onehot = int(d.size) * dmax * n_features * 4
     # bitpacked shares depth_grouped's leaf tables; its extra state is
     # two (d, T_d) integer bit planes per group — int32 worst case

@@ -160,7 +160,7 @@ def test_cell_matches_ref_oracle(op, impl, lay, dtype, scenario):
     elif lay == "depth_major":
         low = layout_mod.lower(ens, "depth_major", backend=_family(impl))
         got = fn(x, low.borders, low.onehot, low.split_bins_dm, low.pow2,
-                 low.leaf_values)
+                 low.leaf_values, prepadded=True, n_classes=ens.n_outputs)
     else:  # bitpacked
         got = fn(x, borders, jnp.transpose(sf), jnp.transpose(sb), lv)
     _assert_close(got, want)

@@ -59,8 +59,16 @@ def test_footprints_under_budget():
 
 
 def test_footprint_model_counts_all_tiles():
-    # covertype-scale: 54 features, depth 8 -> fused tile must include the
-    # (bn, bt*L) one-hot; verify the model scales as expected
+    # covertype-scale: 54 features, depth 8, 7 classes.  The leaf block
+    # is class-major (bt, 8, 256): one (8, 256) tile pair per tree, 512
+    # KB a buffer at bt=64 and double-buffered (leaf-major, 7 classes on
+    # 128 lanes, it would be 8 MB a buffer)
+    leaf = (tuning._accumulate_stage_bytes(128, 64, 256, 7)
+            - tuning._accumulate_stage_bytes(128, 8, 256, 7))
+    assert leaf == 2 * (64 - 8) * 8 * 256 * 4
+    assert tuning.tile_bytes((64, 7, 256), 4) == 512 * 1024
+    # every tree-block-linear term (splits, per-depth one-hot, index
+    # planes, leaf block) grows the fused tile with bt
     small = tuning.fused_footprint(128, 8, 54, 8, 256, 7, 255)
     big = tuning.fused_footprint(128, 64, 54, 8, 256, 7, 255)
-    assert big > small * 4      # one-hot term dominates, linear in bt
+    assert big - small > leaf

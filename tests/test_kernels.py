@@ -47,7 +47,8 @@ def test_leaf_index_kernel(N, F, T, D):
 
 
 @pytest.mark.parametrize("N,T,D,C", [(128, 16, 6, 1), (100, 37, 8, 7),
-                                     (64, 100, 4, 20), (256, 8, 1, 2)])
+                                     (64, 100, 4, 20), (256, 8, 1, 2),
+                                     (96, 24, 5, 8), (130, 19, 3, 9)])
 def test_leaf_gather_kernel(N, T, D, C):
     rng = np.random.default_rng(2)
     idx = jnp.asarray(rng.integers(0, 2 ** D, size=(N, T)).astype(np.int32))

@@ -129,6 +129,67 @@ def test_layout_parity_quantized_pool(layout):
                                   np.asarray(plan.raw(x)))
 
 
+@pytest.mark.parametrize("layout", layout_mod.LAYOUT_NAMES)
+@pytest.mark.parametrize("strategy", ["staged", "fused"])
+def test_layout_parity_padded_classes(layout, strategy):
+    # 9 classes pad to 16 in the lowered table: the pallas plans must
+    # sum the padded classes to nothing and never return them
+    ens = _mixed_depth(_rand_ensemble(n_outputs=9))
+    x = _rand_x(ens, 37)
+    plan = Predictor.build(ens, PredictConfig(
+        strategy=strategy, backend="pallas", layout=layout),
+        expected_batch=37)
+    got = np.asarray(plan.raw(x))
+    assert got.shape == (37, 9)
+    np.testing.assert_allclose(got, _want(ens, x), rtol=1e-5, atol=1e-4)
+
+
+def _leaf_tables(low, ens, tree_block):
+    """Each lowered leaf table beside the model-format (T, L, C) rows it
+    must hold."""
+    lv = np.asarray(ens.leaf_values)
+    if isinstance(low, (DepthGroupedLayout, BitpackedLayout)):
+        depths = np.maximum(np.asarray(ens.true_depths), 1)
+        return [(g.leaf_values, lv[depths == g.depth][:, :1 << g.depth])
+                for g in low.groups]
+    if tree_block:
+        return [(blk[2], lv[i * tree_block:(i + 1) * tree_block])
+                for i, blk in enumerate(low.tree_blocks)]
+    return [(low.leaf_values, lv)]
+
+
+@pytest.mark.parametrize("n_outputs", [1, 7, 8, 9])
+@pytest.mark.parametrize("backend", ["ref", "pallas"])
+@pytest.mark.parametrize("layout,tree_block", [
+    ("soa", 0), ("soa", 4), ("depth_major", 0), ("depth_grouped", 0),
+    ("bitpacked", 0)])
+def test_lowered_leaf_table_is_class_major(layout, tree_block, backend,
+                                           n_outputs):
+    # every layout holds its leaf tables as (Tp, round_up(C, 8), L):
+    # the model's values transposed, zero in padded classes and trees
+    ens = _mixed_depth(_rand_ensemble(n_outputs=n_outputs))
+    low = lower(ens, layout, backend=backend, t_align=16,
+                tree_block=tree_block)
+    cp = -(-n_outputs // 8) * 8
+    tables = _leaf_tables(low, ens, tree_block)
+    for table, model in tables:
+        table = np.asarray(table)
+        n, n_leaves, _ = model.shape
+        tp = table.shape[0]
+        assert table.shape == (tp, cp, n_leaves)
+        if backend == "pallas":
+            assert tp % (128 if layout == "bitpacked" else 16) == 0
+        else:
+            assert tp == n
+        np.testing.assert_array_equal(table[:n, :n_outputs],
+                                      model.transpose(0, 2, 1))
+        assert not table[:, n_outputs:].any()
+        assert not table[n:].any()
+    assert sum(t.shape[0] for t, _ in tables) >= ens.n_trees
+    assert low.leaf_table_bytes() == sum(np.asarray(t).nbytes
+                                         for t, _ in tables)
+
+
 def test_depth_major_ref_is_bit_exact():
     # the one-hot matmul touches only f32-exact integers: depth_major
     # on the jnp reference must be BIT-identical to soa, not just close

@@ -27,6 +27,7 @@ from repro.kernels import ops, tuning
 
 F, FP, B, D, C, T = 54, 128, 254, 8, 7, 10_000
 L = 1 << D
+CP = ops._round_up(C, ops.CLASS_ALIGN)   # the lowered table's class axis
 TRAIN_ROWS = 464_800
 
 
@@ -78,7 +79,7 @@ def test_fused_predict_u8_10k_trees(one_chip, n_rows):
         bins_scratch_dtype=jnp.uint8),
         s((_pad(n_rows, bn), FP), jnp.float32), s((B, FP), jnp.float32),
         s((tp, D), jnp.int32), s((tp, D), jnp.int32),
-        s((tp, L, C), jnp.float32))
+        s((tp, CP, L), jnp.float32))
 
 
 def test_staged_chain_u8(one_chip):
@@ -97,7 +98,7 @@ def test_staged_chain_u8(one_chip):
 
     text = _compile(chain, s((n, FP), jnp.float32), s((B, FP), jnp.float32),
                     s((tp, D), jnp.int32), s((tp, D), jnp.int32),
-                    s((tp, L, C), jnp.float32))
+                    s((tp, CP, L), jnp.float32))
     assert text.count("tpu_custom_call") >= 3
 
 
@@ -130,7 +131,7 @@ def test_fused_layout_variants(one_chip, layout):
             bins_scratch_dtype=jnp.uint8),
             s((n, FP), jnp.float32), s((B, FP), jnp.float32),
             s((1024, D, FP), jnp.float32), s((D, 1024), jnp.int32),
-            s((D, 1), jnp.float32), s((1024, L, C), jnp.float32))
+            s((D, 1), jnp.float32), s((1024, CP, L), jnp.float32))
         return
     bn, _ = tuning.best_fused_blocks(F, D, L, C, B, n_rows=n, n_trees=1024,
                                      gather="bitplane")
@@ -138,7 +139,27 @@ def test_fused_layout_variants(one_chip, layout):
         x, b, sf, sb, lv, block_n=bn, bins_scratch_dtype=jnp.uint8),
         s((n, FP), jnp.float32), s((B, FP), jnp.float32),
         s((D, 1024), jnp.int32), s((D, 1024), jnp.int32),
-        s((1024, L, C), jnp.float32))
+        s((1024, CP, L), jnp.float32))
+
+
+def _covertype_plan(n_trees, **config):
+    """A pallas soa plan over a seeded ensemble at covertype widths."""
+    import numpy as np
+
+    from repro.core.predictor import PredictConfig, Predictor
+    from repro.core.trees import ObliviousEnsemble
+
+    rng = np.random.default_rng(0)
+    ens = ObliviousEnsemble(
+        jnp.asarray(rng.integers(0, F, (n_trees, D)).astype(np.int32)),
+        jnp.asarray(rng.integers(1, B, (n_trees, D)).astype(np.int32)),
+        jnp.asarray(rng.normal(size=(n_trees, L, C)).astype(np.float32)),
+        jnp.asarray(np.sort(rng.normal(size=(B, F)), 0).astype(np.float32)),
+        jnp.full((F,), B, jnp.int32))
+    # the layout the 10,000-tree plan resolves to (few trees would
+    # pick depth_major)
+    return Predictor.build(ens, PredictConfig(backend="pallas",
+                                              layout="soa", **config))
 
 
 def test_bulk_entries_name_kernels_and_stages(one_chip, monkeypatch):
@@ -146,24 +167,8 @@ def test_bulk_entries_name_kernels_and_stages(one_chip, monkeypatch):
     covertype widths: each kernel's op is named after its benchmark
     trace name, and every kernel and the softmax carry their
     `gbdt/<stage>` scope in their op_name metadata."""
-    import numpy as np
-
-    from repro.core.predictor import PredictConfig, Predictor
-    from repro.core.trees import ObliviousEnsemble
-
     monkeypatch.setattr(ops, "_interpret", lambda: False)
-    t, rng = 64, np.random.default_rng(0)
-    ens = ObliviousEnsemble(
-        jnp.asarray(rng.integers(0, F, (t, D)).astype(np.int32)),
-        jnp.asarray(rng.integers(1, B, (t, D)).astype(np.int32)),
-        jnp.asarray(rng.normal(size=(t, L, C)).astype(np.float32)),
-        jnp.asarray(np.sort(rng.normal(size=(B, F)), 0).astype(np.float32)),
-        jnp.full((F,), B, jnp.int32))
-    # the layout the 10,000-tree plan resolves to (few trees would
-    # pick depth_major)
-    plan = Predictor.build(ens, PredictConfig(strategy="staged",
-                                              backend="pallas",
-                                              layout="soa"))
+    plan = _covertype_plan(64, strategy="staged")
     model = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
                          plan.lowered)
     ops_seen = {}
@@ -184,3 +189,29 @@ def test_bulk_entries_name_kernels_and_stages(one_chip, monkeypatch):
         assert f"/gbdt/{stage}/" in kernels[name], kernels[name]
     assert any("/gbdt/softmax/" in op_name
                for _, op_name in ops_seen.values())
+
+
+@pytest.mark.parametrize("entry,dtype", [("proba_pool", jnp.uint8),
+                                         ("proba", jnp.float32)])
+def test_plan_entries_read_the_leaf_table_in_place(one_chip, monkeypatch,
+                                                   entry, dtype):
+    """The bulk (`proba_pool`) and online (fused `proba`) entries of the
+    covertype plan (fused, pallas, soa, blocks 1024 x 64) at 640 trees:
+    the class-major (Tp, 8, L) table is read in the layout the device
+    stores it in.  A leaf-major (Tp, L, 7) table was relaid out by a
+    `copy` of the parameter before every kernel call, into a temp 16x
+    the table (83,886,080 B at 640 trees)."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    plan = _covertype_plan(640, strategy="fused", block_n=1024,
+                           block_t=64)
+    assert plan.lowered.leaf_values.shape == (640, CP, L)
+    model = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
+                         plan.lowered)
+    compiled = plan._entries[entry].lower(
+        model, _sds(one_chip, (256, F), dtype)).compile()
+    copies = [line for line in compiled.as_text().splitlines()
+              if re.search(r"= \S+ copy\(", line)
+              and 'op_name="p.leaf_values"' in line]
+    assert not copies, copies
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < plan.lowered.leaf_table_bytes() == 5_242_880, temp

@@ -1,10 +1,16 @@
 """Bulk sweep: whole passes of `BulkScorer` over the configuration's table.
 
 Set-up writes the table as a .npy under the run's scratch directory,
-builds the default `Predictor` plan and scores one full chunk and the
-tail chunk, so that every shape the sweeps use is compiled.  The window
-then starts whole sweeps, `NpyMemmapSource` -> `BulkScorer.score` ->
-`NpySink` (one output file per sweep), until `--seconds` have passed.
+builds the default `Predictor` plan, reads every page of the table once
+through the window's `NpyMemmapSource`, and scores the last full chunk
+and the tail chunk through that source, the window's scorer and an
+`NpySink`, so that every shape the sweeps use is compiled and the first
+timed sweep pays nothing the later ones do not.  The window then starts
+whole sweeps, `NpyMemmapSource` -> `BulkScorer.score` -> `NpySink` (one
+output file per sweep), until `--seconds` have passed.  Its stdout line
+gives each sweep's seconds and rows (`sweep_s`, `sweep_rows`), and the
+part of each sweep spent in the scorer's chunk loop (`sweep_chunks_s`),
+so that a slow sweep shows and where its time went.
 
     rows_per_s   rows written to the sinks / (end of the last sweep that
                  started in the window - window start)
@@ -24,13 +30,29 @@ from harness import data, device, model, program, reference, trace
 from harness.runner import Check, Outcome
 
 
-def _sweep(scorer, source, sink_path):
+TOUCH_ROWS = 16384        # rows read at a time to fault the table in
+
+
+def _sweep(scorer, source, sink_path, resume_from=0):
     import jax
 
     from repro.scoring.sinks import NpySink
 
     with jax.profiler.TraceAnnotation("bench.scoring.score"):
-        return scorer.score(source, NpySink(sink_path))
+        return scorer.score(source, NpySink(sink_path),
+                            resume_from=resume_from)
+
+
+def _warm(scorer, source, chunk, sink_path) -> None:
+    """The timed path once over its last two chunks (a full one and
+    the tail), after every page of the source has been read once."""
+    from repro.scoring.scorer import plan_chunks
+
+    n = source.n_rows
+    for s in range(0, n, TOUCH_ROWS):
+        source.read(s, min(s + TOUCH_ROWS, n)).sum()
+    spans = plan_chunks(n, chunk)
+    _sweep(scorer, source, sink_path, resume_from=max(len(spans) - 2, 0))
 
 
 def run(ctx) -> Outcome:
@@ -38,7 +60,7 @@ def run(ctx) -> Outcome:
 
     from repro.core.predictor import Predictor
     from repro.scoring.scorer import BulkScorer, ScoreConfig
-    from repro.scoring.sources import ArraySource, NpyMemmapSource
+    from repro.scoring.sources import NpyMemmapSource
 
     cfg, tr = ctx.cell.config, ctx.cell.traffic
     x, _ = data.generate(cfg, ctx.seed)
@@ -53,10 +75,10 @@ def run(ctx) -> Outcome:
     source = NpyMemmapSource(rows_path)
     scorer = BulkScorer(plan, ScoreConfig(output=tr["output"]))
     chunk = scorer.resolve_chunk_rows(n)
-    warm = chunk + (n % chunk or chunk)
-    BulkScorer(plan, ScoreConfig(output=tr["output"], chunk_rows=chunk)) \
-        .score(ArraySource(x[:warm]))
     del x
+    t_warm = time.perf_counter()
+    _warm(scorer, source, chunk, ctx.workdir / "warm.npy")
+    warm_s = time.perf_counter() - t_warm
     devices = jax.devices()[:ctx.cell.chips]
 
     t0 = time.perf_counter()
@@ -64,18 +86,23 @@ def run(ctx) -> Outcome:
     ctx.emit({"phase": "setup", "device": device.record(devices),
               "plan": program.plan_record(plan), "impls": program.impls(),
               "rows": n, "chunk_rows": chunk, "setup_s": setup_s,
+              "warm_s": warm_s,
               **ctx.clock.take()})
 
-    outs, sweeps = [], []
-    while not sweeps or time.perf_counter() < t0 + ctx.seconds:
+    outs, sweeps, ends = [], [], [t0]
+    while not sweeps or ends[-1] < t0 + ctx.seconds:
         outs.append(ctx.workdir / f"out{len(outs)}.npy")
         sweeps.append(_sweep(scorer, source, outs[-1]))
-    t_end = time.perf_counter()
+        ends.append(time.perf_counter())
+    t_end = ends[-1]
     in_window = ctx.clock.take()
     rows = sum(r.n_rows for r in sweeps)
     chunks = sum(r.metrics["chunks"] for r in sweeps)
     ctx.emit({"phase": "window", "sweeps": len(sweeps), "rows": rows,
               "seconds": t_end - t0,
+              "sweep_s": [b - a for a, b in zip(ends, ends[1:])],
+              "sweep_rows": [r.n_rows for r in sweeps],
+              "sweep_chunks_s": [r.metrics["wall_s"] for r in sweeps],
               "compiles_in_window": in_window["compiles"]
               + in_window["cache_hits"], **in_window})
 

@@ -19,16 +19,12 @@ for p in (str(BENCH), str(ROOT / "src")):
 TINY = {"covertype": {"rows": 1500, "trees": 24, "depth": 3},
         "santander": {"rows": 1200, "trees": 24, "depth": 1}}
 
-# Cells whose files bench/ holds but whose BENCHMARK.json entries wait
-# for their proof on the chip (PERF.md, Open questions); the tests run
-# them from these entries.
+# The cell whose files bench/ holds but whose BENCHMARK.json entries wait
+# for its proof on the chip (PERF.md, Open questions); the tests run it
+# from these entries.
 _ONLINE = ["covertype-online"]
 DEFERRED = {
-    "configs": [{"name": "santander", "file": "bench/configs/santander.json",
-                 "source": "test", "reduced": [], "why": "test"}],
     "workloads": [
-        {"name": "santander-bulk", "config": "santander",
-         "traffic": "bulk_sweep", "chips": 1, "why": "test"},
         {"name": "covertype-online", "config": "covertype",
          "traffic": "online_poisson", "chips": 1, "why": "test"}],
     "end_to_end": [
@@ -49,20 +45,15 @@ DEFERRED = {
 
 
 def with_deferred(spec: dict) -> dict:
-    """BENCHMARK.json's entries plus the deferred cells'; santander-bulk
-    reports what covertype-bulk reports."""
+    """BENCHMARK.json's entries plus the deferred cell's."""
     for key, entries in DEFERRED.items():
         have = {e["name"] for e in spec[key]}
         spec[key] += [dict(e) for e in entries if e["name"] not in have]
-    for m in spec["end_to_end"] + spec["per_layer"]:
-        w = m.get("workloads", [])
-        if "covertype-bulk" in w and "santander-bulk" not in w:
-            w.append("santander-bulk")
     return spec
 
 
 def make_tiny_root(dst: pathlib.Path) -> pathlib.Path:
-    """BENCHMARK.json (with the deferred cells) and bench/ copied to dst,
+    """BENCHMARK.json (with the deferred cell) and bench/ copied to dst,
     every configuration cut to a few trees and rows, the online rate cut
     to what the CPU serves."""
     shutil.copytree(BENCH, dst / "bench",

@@ -12,7 +12,7 @@ import pytest
 
 from harness import runner, spec
 
-from conftest import BENCH, ROOT
+from conftest import BENCH, ROOT, TINY
 
 SEED = 2**33 + 17            # wider than 32 bits, as a check's seeds may be
 
@@ -30,6 +30,52 @@ def test_bulk_cell_sound_run_is_correct(tiny_root, workload):
     assert set(line["metrics"]) == {"rows_per_s", "setup_s"}
     assert list(line)[-1] == "checks"
     assert line["checks"]["proba_max_abs_err"]["value"] < 1e-6
+
+
+@pytest.mark.parametrize("workload", ["covertype-bulk", "santander-bulk"])
+def test_bulk_window_line_gives_each_sweep(tiny_root, capsys, workload):
+    _run(tiny_root, workload)
+    lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()]
+    (window,) = [x for x in lines if x.get("phase") == "window"]
+    assert len(window["sweep_s"]) == len(window["sweep_rows"]) \
+        == window["sweeps"] >= 1
+    assert sum(window["sweep_rows"]) == window["rows"]
+    assert all(s > 0 for s in window["sweep_s"])
+    assert sum(window["sweep_s"]) == pytest.approx(window["seconds"])
+
+
+def test_bulk_setup_warms_the_timed_path(tiny_root, monkeypatch):
+    """Before the window, every row of the table is read through the
+    window's NpyMemmapSource, and its last chunks are scored from that
+    source into an NpySink by the window's scorer."""
+    from repro.scoring.scorer import BulkScorer
+    from repro.scoring.sinks import NpySink
+    from repro.scoring.sources import NpyMemmapSource
+
+    seen = []
+    real_read, real_score = NpyMemmapSource.read, BulkScorer.score
+
+    def read(self, start, stop):
+        seen.append(("read", id(self), start, stop))
+        return real_read(self, start, stop)
+
+    def score(self, source, sinks=None, **kw):
+        seen.append(("score", id(self), id(source), type(source),
+                     type(sinks), kw.get("resume_from", 0)))
+        return real_score(self, source, sinks, **kw)
+    monkeypatch.setattr(NpyMemmapSource, "read", read)
+    monkeypatch.setattr(BulkScorer, "score", score)
+    _run(tiny_root, "covertype-bulk")
+
+    scores = [x for x in seen if x[0] == "score"]
+    warm, first = scores[0], scores[1]
+    assert warm[3:5] == (NpyMemmapSource, NpySink) and warm[5] > 0
+    assert warm[1:3] == first[1:3] and first[5] == 0
+    rows = 0
+    for x in seen[:seen.index(warm)]:
+        assert x[1] == warm[2] and x[2] == rows
+        rows = x[3]
+    assert rows == 1500 == TINY["covertype"]["rows"]
 
 
 def test_online_cell_sound_run_is_correct(tiny_root):
@@ -55,7 +101,8 @@ def _perturb_proba(monkeypatch):
     monkeypatch.setattr(Predictor, "proba", altered)
 
 
-@pytest.mark.parametrize("workload", ["covertype-bulk", "covertype-online"])
+@pytest.mark.parametrize("workload", ["covertype-bulk", "santander-bulk",
+                                      "covertype-online"])
 def test_altered_answers_are_not_correct(tiny_root, monkeypatch, workload):
     _perturb_proba(monkeypatch)
     line = _run(tiny_root, workload)
@@ -96,7 +143,7 @@ def _control_in_programs_place(monkeypatch):
         if sinks is not None:
             sinks.close()
         return SimpleNamespace(n_rows=n, metrics={
-            "chunks": -(-n // chunk), "quantize_s": 0.0})
+            "chunks": -(-n // chunk), "quantize_s": 0.0, "wall_s": 0.0})
 
     monkeypatch.setattr(model, "random_ensemble", capture)
     monkeypatch.setattr(Predictor, "proba", lambda self, x: control(x))
@@ -198,6 +245,19 @@ def test_cell_config_mix_and_metric_added_as_files(tiny_root):
                      {"chunks": 3})
     assert runner.per_layer(cell, run) == {
         "new.rows_seen": {"value": 3.0, "unit": "chunks"}}
+
+
+def test_bulk_cells_are_read_from_the_benchmark():
+    """Both bulk cells stand in BENCHMARK.json itself and report the
+    same metrics."""
+    bulk = [spec.load_cell(w) for w in ("covertype-bulk", "santander-bulk")]
+    assert [c.config_name for c in bulk] == ["covertype", "santander"]
+    assert {c.traffic_name for c in bulk} == {"bulk_sweep"}
+    assert [m["name"] for m in bulk[0].end_to_end] == \
+        [m["name"] for m in bulk[1].end_to_end] == ["rows_per_s", "setup_s"]
+    assert [m["name"] for m in bulk[0].per_layer] == \
+        [m["name"] for m in bulk[1].per_layer]
+    assert len(bulk[1].per_layer) == 5
 
 
 def test_unknown_workload_is_refused():

@@ -29,6 +29,7 @@ from repro.analysis import jaxpr_tools
 # and the lint rules are dtype/structure properties, not size ones.
 N, F, B, T, D, L, C = 64, 7, 9, 6, 4, 16, 2
 TP, FP = 16, 128      # padded tree/feature dims the lowered layouts carry
+NW = 32               # node/leaf slots a tree of the nodes layout: 4 a row
 B_WIDE = 300          # >255 borders: forces the int32 bins scratch path
 
 
@@ -102,6 +103,16 @@ def cell_variants(cell: Cell) -> list[tuple[tuple, dict]]:
                                and cell.impl.startswith("ref")) else i32)
         return [((_sds((N, F), bt), _sds((D, TP), plane),
                   _sds((D, TP), plane)), {})]
+
+    if cell.op == "node_index":
+        # the nodes layout's packed arrays: one kernel tree block of
+        # trees, 128 // NW to each 128-lane row
+        from repro.kernels.node_index import tree_block
+        rows = tree_block(NW) * NW // 128
+        packed = _sds((rows, 128), i32)
+        return [((_sds((N, FP), bt), packed, packed, packed, packed,
+                  _sds((rows, NW, 128), jnp.bfloat16), packed),
+                 {"width": NW})]
 
     if cell.op == "leaf_gather":
         return [((_sds((N, T), i32), _sds((T, L, C), f32)), {})]

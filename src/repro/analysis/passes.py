@@ -175,6 +175,12 @@ def _model_bytes(cell: Cell, refs: list[Any]) -> Optional[int]:
         return tuning.leaf_index_footprint(bn, bt, bins.shape[1],
                                            model.shape[1],
                                            bins_bytes=ib(bins))
+    if cell.op == "node_index":
+        bins, _nf, _nb, paths, _plen, out = refs
+        bt, bn = out.shape                 # tree-major index block
+        return tuning.node_index_footprint(bn, bt, bins.shape[1],
+                                           paths.shape[1],
+                                           bins_bytes=ib(bins))
     if cell.op == "leaf_gather":
         idx, lv, _out = refs
         bt, bn = idx.shape

@@ -13,7 +13,7 @@ the physical order a kernel family wants, pre-padded to that family's
 block contracts.  Kernels consume lowered arrays; plans store them; the
 tuner picks among them from the ensemble's shape.
 
-Four layouts:
+Five layouts:
 
   soa            today's structure-of-arrays — (T, D) splits, one
                  leaf table — the compatibility default.
@@ -41,6 +41,12 @@ Four layouts:
                  (<= 1 border per feature) the uint8 pool itself packs
                  into u1 feature planes — `pack_pool_u1` — an 8x pool
                  memory shrink.
+  nodes          non-symmetric trees (`trees.NodeEnsemble`): each
+                 tree's node table and its per-leaf path matrix, packed
+                 lane-dense; `node_index` tests every node and matches
+                 whole paths on the MXU, exactly (the only layout a
+                 NodeEnsemble takes; an oblivious model is rewritten
+                 as a node table when asked for it).
 
 Every layout holds its leaf tables in one class-major form, (T, Cp, L)
 with Cp = C rounded up to `ops.CLASS_ALIGN` (8) and zero leaves in the
@@ -62,7 +68,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.trees import NodeEnsemble
 from repro.kernels import ops
+from repro.kernels import node_index as node_k
 from repro.kernels.leaf_index import BP_TREE_BLOCK
 from repro.kernels.ops import FEATURE_ALIGN, PAD_SPLIT_BIN
 
@@ -76,7 +84,9 @@ def is_concrete(ensemble) -> bool:
     """Whether the ensemble's structure arrays are concrete (lowerings
     that regroup trees need to *read* split_bins; per-shard plans built
     inside shard_map hold tracers and must stay on "soa")."""
-    return not isinstance(ensemble.split_bins, jax.core.Tracer)
+    structure = (ensemble.node_bins if isinstance(ensemble, NodeEnsemble)
+                 else ensemble.split_bins)
+    return not isinstance(structure, jax.core.Tracer)
 
 
 # --------------------------------------------------------------------------
@@ -348,6 +358,79 @@ class BitpackedLayout:
                 "pool_shrink_x": (u8 / u1) if self.binary_split else 1.0}
 
 
+@dataclasses.dataclass(frozen=True)
+class NodesLayout:
+    """Non-symmetric trees (`trees.NodeEnsemble`) as path matrices.
+
+    W node slots and W leaf slots a tree (a power of two), k = 128 / W
+    trees to each 128-lane row of the node arrays, and the trees padded
+    to Tp, so that every array is lane-dense (see `kernels.node_index`):
+
+      node_features, node_bins     (Tp/k, k*W) int32, slot j of tree t
+      node_left, node_right          at [t // k, (t % k) * W + j]
+      paths                        (Tp/k, W, k*W) bfloat16: P[t, l, j]
+                                     at [t // k, l, (t % k) * W + j],
+                                     +1 / -1 where the path to leaf l
+                                     goes right / left at node j
+      path_len                     (Tp/k, k*W) int32: the path's length
+                                     (`node_index.PAD_PATH_LEN` for an
+                                     unreached or padded leaf)
+      leaf_values                  (Tp, Cp, W) float32, class-major
+
+    The pallas kernel matches paths; the reference walks the node
+    arrays from the root.  Both give each row its leaf, which the
+    shared `leaf_gather` then sums.
+    """
+    layout_name = "nodes"
+    borders: jax.Array           # (B, Fp) f32
+    node_features: jax.Array     # (Tp/k, k*W) i32
+    node_bins: jax.Array         # (Tp/k, k*W) i32
+    node_left: jax.Array         # (Tp/k, k*W) i32
+    node_right: jax.Array        # (Tp/k, k*W) i32
+    paths: jax.Array             # (Tp/k, W, k*W) bf16
+    path_len: jax.Array          # (Tp/k, k*W) i32
+    leaf_values: jax.Array       # (Tp, Cp, W) f32, class-major
+    n_outputs: int = 1           # static
+    n_model_pads: int = 0        # static
+    width: int = 8               # static: W
+    nodes_per_tree: int = 0      # static: the most nodes a tree tests
+    leaves_per_tree: int = 0     # static: the most leaves a tree has
+
+    def leaf_sum(self, bins: jax.Array, *, backend: str,
+                 block_t: int) -> jax.Array:
+        idx = ops.node_index_prepadded(
+            bins, self.node_features, self.node_bins, self.node_left,
+            self.node_right, self.paths, self.path_len, width=self.width,
+            backend=backend)
+        return ops.leaf_gather_prepadded(idx, self.leaf_values,
+                                         n_classes=self.n_outputs,
+                                         backend=backend, block_t=block_t)
+
+    def fused_raw(self, x: jax.Array, *, backend: str, block_n: int,
+                  block_t: int) -> jax.Array:
+        # no fused kernel walks node tables: binarize, then the sum
+        bins = ops.binarize_prepadded(x, self.borders, backend=backend)
+        return self.leaf_sum(bins, backend=backend, block_t=block_t)
+
+    def leaf_table_bytes(self) -> int:
+        return int(np.prod(self.leaf_values.shape)) * 4
+
+    def lowered_bytes(self) -> int:
+        """Bytes of every lowered model array but the borders."""
+        return sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                   for a in (self.node_features, self.node_bins,
+                             self.node_left, self.node_right, self.paths,
+                             self.path_len, self.leaf_values))
+
+    def describe(self) -> dict[str, Any]:
+        return {"layout": self.layout_name,
+                "leaf_table_bytes": self.leaf_table_bytes(),
+                "lowered_bytes": self.lowered_bytes(),
+                "width": self.width,
+                "nodes_per_tree": self.nodes_per_tree,
+                "leaves_per_tree": self.leaves_per_tree}
+
+
 def _pad_tree_axis(a, axis: int, target: int, value=0):
     return ops._pad_dim(a, axis, target, value=value, kind="model")
 
@@ -523,10 +606,15 @@ _register_lowered(BitpackedLayout,
                   ("borders", "groups"),
                   ("n_outputs", "n_model_pads", "binary_split",
                    "n_features"))
+_register_lowered(NodesLayout,
+                  ("borders", "node_features", "node_bins", "node_left",
+                   "node_right", "paths", "path_len", "leaf_values"),
+                  ("n_outputs", "n_model_pads", "width", "nodes_per_tree",
+                   "leaves_per_tree"))
 
 # The union type plans hold.
 LoweredEnsemble = (SoaLayout | DepthMajorLayout | DepthGroupedLayout
-                   | BitpackedLayout)
+                   | BitpackedLayout | NodesLayout)
 
 
 # --------------------------------------------------------------------------
@@ -573,6 +661,15 @@ LAYOUTS: dict[str, LayoutSpec] = {
                "u1 pool planes when binary-split",
         when="mixed depths whose one-hot/f32 working set blows the "
              "VMEM budget"),
+    "nodes": LayoutSpec(
+        name="nodes", cls=NodesLayout,
+        paper_analog="non-symmetric trees (Lossguide / Depthwise): "
+                     "per-node tests, then a path match on the MXU",
+        claimed_ops=("binarize", "node_index", "leaf_gather"),
+        memory="T x Cp x W leaf table; (T, W, W) bf16 path matrices; "
+               "four (T, W) int32 node arrays",
+        when="every NodeEnsemble (its only layout); oblivious models "
+             "only when asked for"),
 }
 
 LAYOUT_NAMES = tuple(LAYOUTS)
@@ -620,6 +717,10 @@ def lower(ensemble, layout: str = "soa", *, backend: str = "ref",
     if layout not in LAYOUTS:
         raise ValueError(f"unknown layout {layout!r}; known: "
                          f"{LAYOUT_NAMES}")
+    if isinstance(ensemble, NodeEnsemble) and layout != "nodes":
+        raise ValueError(
+            f"a NodeEnsemble (non-symmetric trees) lowers to the 'nodes' "
+            f"layout only; {layout!r} holds one split per depth")
     if layout == "bitpacked" and backend == "pallas":
         t_align = math.lcm(max(int(t_align), 1), BP_TREE_BLOCK)
     ctx = _LowerCtx(pallas=backend == "pallas", t_align=t_align)
@@ -629,6 +730,8 @@ def lower(ensemble, layout: str = "soa", *, backend: str = "ref",
         return _lower_depth_major(ensemble, ctx)
     if layout == "bitpacked":
         return _lower_bitpacked(ensemble, ctx)
+    if layout == "nodes":
+        return _lower_nodes(ensemble, ctx)
     return _lower_depth_grouped(ensemble, ctx)
 
 
@@ -770,3 +873,60 @@ def _lower_bitpacked(ensemble, ctx: _LowerCtx) -> BitpackedLayout:
                            n_model_pads=ctx.n_pads,
                            binary_split=bool((n_borders <= 1).all()),
                            n_features=int(ensemble.borders.shape[1]))
+
+
+def node_width(n_nodes: int, n_leaves: int) -> int:
+    """W, the node and leaf slots a tree gets in the `nodes` layout:
+    the power of two >= both counts, at least 8 (one sublane tile)."""
+    return max(8, 1 << (max(n_nodes, n_leaves, 1) - 1).bit_length())
+
+
+def _lower_nodes(ensemble, ctx: _LowerCtx) -> NodesLayout:
+    """Each tree's path matrix (`NodeEnsemble.path_matrix`), in W slots,
+    with k = 128 / W trees packed to each 128-lane row."""
+    if not is_concrete(ensemble):
+        raise ValueError(
+            "nodes lowering reads each tree's structure to build its path "
+            "matrix; the ensemble holds tracers")
+    if not isinstance(ensemble, NodeEnsemble):
+        ensemble = NodeEnsemble.from_oblivious(ensemble)
+    borders = ctx.pad_borders(ensemble.borders)
+    path, path_len = ensemble.path_matrix()
+    T, n_slots = ensemble.node_features.shape
+    n_leaves = ensemble.leaf_values.shape[1]
+    width = node_width(n_slots, n_leaves)
+    if ctx.pallas and width > ops.FEATURE_ALIGN:
+        raise ValueError(
+            f"the pallas node_index kernel takes trees of at most "
+            f"{ops.FEATURE_ALIGN} leaves and {ops.FEATURE_ALIGN - 1} nodes; "
+            f"this ensemble needs {width} slots a tree")
+    k = max(1, ops.FEATURE_ALIGN // width)
+    align = (math.lcm(ctx.t_align, node_k.tree_block(width)) if ctx.pallas
+             else k)
+    tp = ops._round_up(max(T, 1), align)
+
+    def pack(a, fill):      # (T, n) -> padded (tp, width) -> (tp/k, k*W)
+        out = np.full((tp, width), fill, np.int32)
+        out[:T, :a.shape[1]] = np.asarray(a)
+        return jnp.asarray(out.reshape(tp // k, k * width))
+
+    # padded slots and trees: feature 0, never right, both children
+    # leaf 0, so a walk through a padded tree ends in its zero leaf
+    p = np.zeros((tp, width, width), np.float32)
+    p[:T, :n_leaves, :n_slots] = path
+    packed_p = (p.reshape(tp // k, k, width, width).transpose(0, 2, 1, 3)
+                .reshape(tp // k, width, k * width))
+    lv = ops._pad_dim(ensemble.leaf_values, 1, width, kind="model")
+    lv_cm = ops.class_major(lv, tp)
+    ctx.n_pads += (tp != T) + (lv_cm.shape[1] != ensemble.n_outputs) \
+        + (width != n_leaves)
+    return NodesLayout(
+        borders, pack(ensemble.node_features, 0),
+        pack(ensemble.node_bins, PAD_SPLIT_BIN),
+        pack(ensemble.node_left, -1), pack(ensemble.node_right, -1),
+        jnp.asarray(packed_p, jnp.bfloat16),
+        pack(path_len, node_k.PAD_PATH_LEN), lv_cm,
+        n_outputs=ensemble.n_outputs, n_model_pads=ctx.n_pads, width=width,
+        nodes_per_tree=int((path != 0).any(axis=1).sum(axis=1).max(
+            initial=0)),
+        leaves_per_tree=int((path_len >= 0).sum(axis=1).max(initial=0)))

@@ -60,7 +60,7 @@ from repro.core import layout as layout_mod
 from repro.core.layout import LoweredEnsemble, STAGED_TREE_ALIGN
 from repro.core.quantize import (QuantizedPool, borders_fingerprint,
                                  MAX_BINS)
-from repro.core.trees import ObliviousEnsemble
+from repro.core.trees import NodeEnsemble, ObliviousEnsemble
 from repro.kernels import ops
 from repro.kernels import registry
 from repro.kernels import tuning
@@ -93,8 +93,9 @@ class PredictConfig:
                  block contracts
       layout     physical model layout the plan lowers to (see
                  `repro.core.layout`): soa | depth_major |
-                 depth_grouped | bitpacked; auto picks from the
-                 ensemble's depth histogram / leaf-table bytes via
+                 depth_grouped | bitpacked | nodes; auto picks nodes
+                 for a `NodeEnsemble` (non-symmetric trees), else from
+                 the ensemble's depth histogram / leaf-table bytes via
                  `kernels.tuning.best_layout`
       tree_block staged-path tree blocking (CalcTreesBlockedImpl); 0 = off
                  (soa layout only — an auto layout resolves to soa when
@@ -142,7 +143,7 @@ class PredictConfig:
                      or (self.block_n is not None
                          and self.block_t is not None)))
 
-    def resolve(self, ensemble: ObliviousEnsemble, *,
+    def resolve(self, ensemble: ObliviousEnsemble | NodeEnsemble, *,
                 n_rows: Optional[int] = None) -> "PredictConfig":
         """Concretize every `auto` choice for one ensemble.
 
@@ -153,8 +154,19 @@ class PredictConfig:
         `n_rows`, the expected batch size, when known); the `auto`
         layout comes from `tuning.best_layout` on the ensemble's depth
         histogram (tracer ensembles — per-shard plans built inside
-        shard_map — pin to soa: grouping needs to read split_bins).
+        shard_map — pin to soa: grouping needs to read split_bins).  A
+        `NodeEnsemble` has one layout, nodes; the oblivious layouts
+        refuse it.
         """
+        nodes = isinstance(ensemble, NodeEnsemble)
+        if nodes and self.layout not in ("auto", "nodes"):
+            raise ValueError(
+                f"layout {self.layout!r} holds one split per depth and "
+                "cannot score a NodeEnsemble (non-symmetric trees); use "
+                "layout='nodes' or 'auto'")
+        if nodes and self.tree_block:
+            raise ValueError("tree_block is a soa-layout feature; a "
+                             "NodeEnsemble lowers to the nodes layout")
         strategy, backend = self.strategy, self.backend
         if strategy == "auto":
             strategy = "fused" if ops.default_platform() == "tpu" \
@@ -162,7 +174,9 @@ class PredictConfig:
         if backend == "auto":
             backend = registry.default_backend()
         layout = self.layout
-        if layout == "auto":
+        if layout == "auto" and nodes:
+            layout = "nodes"
+        elif layout == "auto":
             if self.tree_block or not layout_mod.is_concrete(ensemble):
                 layout = "soa"
             else:
@@ -280,7 +294,7 @@ class Predictor:
 
     # -- construction ------------------------------------------------------
     @classmethod
-    def build(cls, ensemble: ObliviousEnsemble,
+    def build(cls, ensemble: ObliviousEnsemble | NodeEnsemble,
               config: Optional[PredictConfig] = None, *,
               expected_batch: Optional[int] = None,
               on_trace: Optional[Callable[[], None]] = None,
@@ -551,6 +565,8 @@ class Predictor:
 
         `shard_axis` ("rows" | "trees" | "auto") picks how a pure data
         mesh is used; "auto" asks `tuning.best_shard_axis` per batch.
+        Plans on the nodes layout (non-symmetric trees) are not
+        sharded: this raises ValueError for them.
         Row counts need not divide the mesh: ragged batches are padded
         to the row-shard multiple inside the jitted entry and sliced
         back (pad rows are zeros; they never reach the caller).
@@ -560,6 +576,10 @@ class Predictor:
         shard_map closures are built once per (mesh, axes, strategy,
         shard_axis) and cached on the plan; jit handles per-shape
         caching under that."""
+        if self.config.layout == "nodes":
+            raise ValueError(
+                "sharded scoring is not supported on the nodes layout "
+                "(non-symmetric trees): score node tables on one device")
         key = (id(mesh), tuple(data_axes), model_axis, strategy,
                shard_axis)
         fn = self._sharded_cache.get(key)

@@ -10,6 +10,9 @@ Structure-of-arrays layout (exactly what the paper's hotspots consume):
 All trees share a single depth D (CatBoost pads shallower trees the same
 way: repeat a split or use an always-false one; we use split_bin = PAD so
 the padded levels always go left).
+
+`NodeEnsemble` holds the non-symmetric trees of CatBoost's Lossguide and
+Depthwise grow policies as node tables (see its docstring).
 """
 from __future__ import annotations
 
@@ -163,6 +166,189 @@ def _ensemble_unflatten(_aux, children) -> "ObliviousEnsemble":
 jax.tree_util.register_pytree_with_keys(
     ObliviousEnsemble, _ensemble_flatten_with_keys, _ensemble_unflatten,
     _ensemble_flatten)
+
+
+@dataclasses.dataclass(frozen=True)
+class NodeEnsemble:
+    """Non-symmetric trees (CatBoost's Lossguide and Depthwise grow
+    policies) as a node table, one row of nodes per tree:
+
+      node_features (T, Nmax) int32 — feature id node j of tree t tests
+      node_bins     (T, Nmax) int32 — go right iff bin >= node_bins
+      node_left     (T, Nmax) int32 — child on the left: a node id >= 0,
+      node_right    (T, Nmax) int32   or leaf l written as -1 - l
+      leaf_values   (T, Lmax, C) float32
+      borders, n_borders, base_score — as `ObliviousEnsemble`
+
+    Node 0 is each tree's root; slots no path from the root reaches
+    are padding.  A tree of a single leaf is a root whose two children
+    are that leaf.  Every other leaf is reached by exactly one path
+    (`path_matrix` checks), so a row's leaf is where the walk from the
+    root, `node = right if bin >= node_bins else left`, ends.
+    """
+    node_features: jax.Array     # (T, Nmax) int32
+    node_bins: jax.Array         # (T, Nmax) int32
+    node_left: jax.Array         # (T, Nmax) int32
+    node_right: jax.Array        # (T, Nmax) int32
+    leaf_values: jax.Array       # (T, Lmax, C) float32
+    borders: jax.Array           # (B, F) float32
+    n_borders: jax.Array         # (F,) int32
+    base_score: jax.Array = None  # (C,) float32 additive offset
+
+    def __post_init__(self):
+        if self.base_score is None:
+            object.__setattr__(
+                self, "base_score",
+                jnp.zeros((self.leaf_values.shape[2],), jnp.float32))
+
+    @property
+    def n_trees(self) -> int:
+        return self.node_features.shape[0]
+
+    @property
+    def n_outputs(self) -> int:
+        return self.leaf_values.shape[2]
+
+    @property
+    def n_features(self) -> int:
+        return self.borders.shape[1]
+
+    def path_matrix(self) -> tuple[np.ndarray, np.ndarray]:
+        """Each leaf's path from its tree's root, as numpy:
+
+          P    (T, Lmax, Nmax) int8 — +1 / -1 where the path to leaf l
+               goes right / left at node j, 0 off the path
+          plen (T, Lmax) int32 — the path's length; -1 for a leaf no
+               path reaches
+
+        A node whose two children are the same leaf tests nothing and
+        takes no step.  Model structure, so concrete arrays only;
+        computed once per instance.  Raises ValueError on a child out
+        of range, a node reached twice (a cycle or a shared subtree) or
+        a leaf reached by two paths."""
+        cached = self.__dict__.get("_path_matrix")
+        if cached is not None:
+            return cached
+        left = np.asarray(self.node_left).astype(np.int64)
+        right = np.asarray(self.node_right).astype(np.int64)
+        t, n = left.shape
+        n_leaves = self.leaf_values.shape[1]
+        if ((left >= n) | (right >= n) | (-1 - left >= n_leaves)
+                | (-1 - right >= n_leaves)).any():
+            raise ValueError("a child is out of range of the node table "
+                             f"({n} nodes, {n_leaves} leaves)")
+        trees = np.arange(t)[:, None]
+        # reach the nodes from each root, one level a pass; a node with
+        # two reached parents (or the root with one) is not a tree
+        reached = np.zeros((t, n), bool)
+        reached[:, 0] = True
+        for _ in range(n):
+            parents = np.zeros((t, n), np.int64)
+            for kids in (left, right):
+                at = reached & (kids >= 0)
+                np.add.at(parents, (np.broadcast_to(trees, (t, n))[at],
+                                    kids[at]), 1)
+            if (parents > 1).any() or (parents[:, 0] > 0).any():
+                raise ValueError("a node is reached twice: the table "
+                                 "holds a cycle or a shared subtree")
+            now = reached | (parents > 0)
+            if (now == reached).all():
+                break
+            reached = now
+        # each node's and each leaf's parent, and the side it hangs on
+        up = np.full((t, n), -1, np.int64)
+        up_side = np.zeros((t, n), np.int8)
+        leaf_up = np.full((t, n_leaves), -1, np.int64)
+        leaf_side = np.zeros((t, n_leaves), np.int8)
+        hits = np.zeros((t, n_leaves), np.int64)
+        no_test = reached & (left == right) & (left < 0)
+        for kids, direction in ((left, -1), (right, 1)):
+            tt, jj = np.nonzero(reached & (kids >= 0))
+            up[tt, kids[tt, jj]], up_side[tt, kids[tt, jj]] = jj, direction
+            at = reached & (kids < 0) & ~(no_test & (direction == 1))
+            tt, jj = np.nonzero(at)
+            leaf = -1 - kids[tt, jj]
+            np.add.at(hits, (tt, leaf), 1)
+            leaf_up[tt, leaf] = jj
+            leaf_side[tt, leaf] = np.where(no_test[tt, jj], 0, direction)
+        if (hits > 1).any():
+            raise ValueError("a leaf is reached by two paths")
+        # climb from every leaf to its root, marking each step
+        p = np.zeros((t, n_leaves, n), np.int8)
+        plen = np.where(hits > 0, 0, -1).astype(np.int32)
+        tl = np.broadcast_to(trees, (t, n_leaves))
+        ll = np.broadcast_to(np.arange(n_leaves)[None], (t, n_leaves))
+        node, side = leaf_up.copy(), leaf_side.copy()
+        while (node >= 0).any():
+            on = (node >= 0) & (side != 0)
+            p[tl[on], ll[on], node[on]] = side[on]
+            plen += on
+            at = np.maximum(node, 0)
+            node, side = (np.where(node >= 0, up[tl, at], -1),
+                          np.where(node >= 0, up_side[tl, at], 0))
+        object.__setattr__(self, "_path_matrix", (p, plen))
+        return p, plen
+
+    @property
+    def depth(self) -> int:
+        """The longest root-to-leaf path over all trees."""
+        return int(max(self.path_matrix()[1].max(initial=0), 0))
+
+    @classmethod
+    def from_oblivious(cls, ensemble: "ObliviousEnsemble") -> "NodeEnsemble":
+        """The same trees as a node table: depth d of an oblivious tree
+        becomes the 2^d nodes of level d, numbered breadth first, and
+        leaf ids keep the oblivious index sum_d 2^d right_d.  Padded
+        levels (`PAD_SPLIT_BIN`) stay nodes that always go left."""
+        sf, sb = np.asarray(ensemble.split_features), \
+            np.asarray(ensemble.split_bins)
+        t, d = sf.shape
+        n = max((1 << d) - 1, 1)
+        nf, nb = np.zeros((t, n), np.int32), np.zeros((t, n), np.int32)
+        left, right = np.full((t, n), -1, np.int32), \
+            np.full((t, n), -1, np.int32)
+        for level in range(d):
+            for prefix in range(1 << level):
+                j = (1 << level) - 1 + prefix
+                nf[:, j], nb[:, j] = sf[:, level], sb[:, level]
+                kids = (prefix, prefix + (1 << level))
+                if level + 1 < d:
+                    left[:, j], right[:, j] = ((1 << (level + 1)) - 1
+                                               + k for k in kids)
+                else:
+                    left[:, j], right[:, j] = (-1 - k for k in kids)
+        return cls(jnp.asarray(nf), jnp.asarray(nb), jnp.asarray(left),
+                   jnp.asarray(right), ensemble.leaf_values,
+                   ensemble.borders, ensemble.n_borders,
+                   ensemble.base_score)
+
+    def describe(self) -> dict[str, Any]:
+        return dict(n_trees=self.n_trees, depth=self.depth,
+                    n_outputs=self.n_outputs, n_features=self.n_features,
+                    max_nodes=int(self.node_features.shape[1]),
+                    max_leaves=int(self.leaf_values.shape[1]),
+                    n_leaf_params=int(np.prod(self.leaf_values.shape)))
+
+
+_NODE_FIELDS = ("node_features", "node_bins", "node_left", "node_right",
+                "leaf_values", "borders", "n_borders", "base_score")
+
+
+def _node_flatten_with_keys(e: NodeEnsemble):
+    return tuple((jax.tree_util.GetAttrKey(f), getattr(e, f))
+                 for f in _NODE_FIELDS), None
+
+
+def _node_unflatten(_aux, children) -> NodeEnsemble:
+    obj = object.__new__(NodeEnsemble)
+    for f, c in zip(_NODE_FIELDS, children):
+        object.__setattr__(obj, f, c)
+    return obj
+
+
+jax.tree_util.register_pytree_with_keys(
+    NodeEnsemble, _node_flatten_with_keys, _node_unflatten,
+    lambda e: (tuple(getattr(e, f) for f in _NODE_FIELDS), None))
 
 
 def empty_ensemble(n_features: int, depth: int, n_outputs: int,

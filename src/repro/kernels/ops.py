@@ -26,6 +26,7 @@ from repro.kernels import histogram as _hist_k
 from repro.kernels import l2dist as _l2_k
 from repro.kernels import leaf_gather as _gather_k
 from repro.kernels import leaf_index as _index_k
+from repro.kernels import node_index as _node_k
 from repro.kernels import ref as _ref
 from repro.kernels import registry
 from repro.kernels import tuning as _tuning
@@ -161,8 +162,9 @@ def _require_u8_borders(borders: jax.Array) -> None:
 # no tree-structure arrays work under every physical layout; soa tree
 # kernels also serve depth_grouped, which evaluates group-by-group
 # through them.  bitpacked has its own `_bp` structure kernels, so soa
-# tree kernels do NOT claim it.
-ALL_LAYOUTS = ("soa", "depth_major", "depth_grouped", "bitpacked")
+# tree kernels do NOT claim it; the nodes layout's trees are read by
+# node_index alone.
+ALL_LAYOUTS = ("soa", "depth_major", "depth_grouped", "bitpacked", "nodes")
 SOA_LAYOUTS = ("soa", "depth_grouped")
 
 
@@ -376,6 +378,42 @@ def _leaf_index_pallas_bp(bins, sf_bp, sb_bp, *, block_n=256, block_t=None,
     out = _index_k.leaf_index_bp(binsp, sfp, sbp, block_n=block_n,
                                  interpret=_interpret())
     return out.T[:N, :T]
+
+
+# --------------------------------------------------------------------------
+# Registered implementations: node_index (non-symmetric trees)
+# --------------------------------------------------------------------------
+# Both take the `nodes` layout's packed arrays (`layout.NodesLayout`):
+# the reference walks the node arrays, the kernel matches the path
+# matrices.  The model side is always lowered pre-padded.
+@registry.register("node_index", "ref", dtypes=("int32", "uint8"),
+                   layouts=("nodes",),
+                   constraints="nodes lowered model; any shape; walks "
+                               "each tree from the root")
+def _node_index_ref(bins, node_features, node_bins, node_left, node_right,
+                    paths, path_len, *, width, **_blocks):
+    def tree_rows(a):
+        return a.reshape(-1, width)
+    return _ref.node_index(bins, tree_rows(node_features),
+                           tree_rows(node_bins), tree_rows(node_left),
+                           tree_rows(node_right))
+
+
+@registry.register("node_index", "pallas", dtypes=("int32", "uint8"),
+                   layouts=("nodes",),
+                   constraints="nodes lowered model (T pre-padded to the "
+                               "kernel's tree block); pads N per call; "
+                               "bf16 one-hot gather and path match")
+def _node_index_pallas(bins, node_features, node_bins, node_left,
+                       node_right, paths, path_len, *, width, block_n=256):
+    block_n = _lanes(block_n)
+    N = bins.shape[0]
+    binsp = _pad_dim(_pad_dim(bins, 0, _round_up(max(N, 1), block_n)), 1,
+                     _round_up(bins.shape[1], FEATURE_ALIGN))
+    out = _node_k.node_index(binsp, node_features, node_bins, paths,
+                             path_len, width=width, block_n=block_n,
+                             interpret=_interpret())
+    return out.T[:N]
 
 
 # --------------------------------------------------------------------------
@@ -860,6 +898,22 @@ def leaf_gather_prepadded(idx: jax.Array, leaf_values: jax.Array, *,
     return registry.dispatch("leaf_gather", backend, idx, leaf_values,
                              block_n=block_n, block_t=block_t,
                              prepadded=True, n_classes=n_classes)
+
+
+@jax.named_scope("gbdt/node_index")
+def node_index_prepadded(bins: jax.Array, node_features: jax.Array,
+                         node_bins: jax.Array, node_left: jax.Array,
+                         node_right: jax.Array, paths: jax.Array,
+                         path_len: jax.Array, *, width: int,
+                         backend: Backend = "auto",
+                         block_n: int = 256) -> jax.Array:
+    """Leaf ids of non-symmetric trees from the `nodes` lowered model
+    -> (N, Tp) int32 (padded trees land in leaf 0, which holds a zero
+    leaf value).  Accepts int32 or uint8 bins."""
+    return registry.dispatch("node_index", backend, bins, node_features,
+                             node_bins, node_left, node_right, paths,
+                             path_len, dtype=_bins_dtype(bins),
+                             layout="nodes", width=width, block_n=block_n)
 
 
 # --------------------------------------------------------------------------

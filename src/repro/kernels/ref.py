@@ -160,6 +160,42 @@ def leaf_index_bitpacked(bins: jax.Array, split_features_bp: jax.Array,
     return idx
 
 
+def _at_or_above(bin_ids: jax.Array, thresholds: jax.Array) -> jax.Array:
+    """bin_ids >= thresholds, uint8 bins compared as uint8: a threshold
+    above 255 is never reached and one at or below 0 always is."""
+    if bin_ids.dtype != jnp.uint8:
+        return bin_ids >= thresholds
+    narrow = jnp.clip(thresholds, 0, 255).astype(jnp.uint8)
+    return (thresholds <= 0) | ((thresholds <= 255) & (bin_ids >= narrow))
+
+
+def node_index(bins: jax.Array, node_features: jax.Array,
+               node_bins: jax.Array, node_left: jax.Array,
+               node_right: jax.Array) -> jax.Array:
+    """The leaf each row reaches in each non-symmetric tree -> (N, T)
+    int32, by walking: from the root (node 0), node = right if
+    bins[n, node_features] >= node_bins else left, until a leaf
+    (a child < 0 is leaf -1 - child).
+
+    The node arrays are (T, Nmax) (`trees.NodeEnsemble`); `bins` may be
+    int32 or uint8."""
+    n, t = bins.shape[0], node_features.shape[0]
+    trees = jnp.arange(t)[None, :]
+
+    def step(node):
+        at = jnp.maximum(node, 0)
+        feature = node_features[trees, at]                  # (N, T)
+        right = _at_or_above(jnp.take_along_axis(bins, feature, axis=1),
+                             node_bins[trees, at])
+        child = jnp.where(right, node_right[trees, at],
+                          node_left[trees, at])
+        return jnp.where(node >= 0, child, node)
+
+    node = jax.lax.while_loop(lambda v: jnp.any(v >= 0), step,
+                              jnp.zeros((n, t), jnp.int32))
+    return -1 - node
+
+
 def leaf_gather(idx: jax.Array, leaf_values: jax.Array) -> jax.Array:
     """pred[n, c] = sum_t leaf_values[t, idx[n, t], c]  -> (N, C) float32."""
     N, T = idx.shape

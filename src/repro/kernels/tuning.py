@@ -125,6 +125,30 @@ def leaf_index_footprint(block_n: int, block_t: int, F: int, D: int, *,
             + _gather_stage_bytes(block_n, block_t, F, bins_bytes))
 
 
+def node_index_footprint(block_n: int, block_t: int, F: int, W: int, *,
+                         bins_bytes: int = 4) -> int:
+    """One grid step of `kernels.node_index`: block_t trees of W node
+    slots, R = block_t * W node rows.  Blocks: the bins panel, three
+    packed (R / 128, 128) int32 node arrays, the (R / 128, W, 128) bf16
+    path rows, the (bt, bn) index.  Temporaries: the widened bins
+    operand, a column's (R, 128) spread, the (R, F) one-hot, the
+    gathered, sign, score and match planes (R, bn), the group's
+    block-diagonal path matrices and the (bt, R) leaf weights."""
+    rows = block_t * W
+    groups = max(rows // LANE, 1)
+    blocks = (_block((block_n, F), bins_bytes)
+              + 3 * _block((groups, LANE), 4)
+              + _block((groups, W, LANE), 2)
+              + _block((block_t, block_n), 4))
+    temps = (2 * tile_bytes((block_n, F), 4)
+             + 2 * tile_bytes((rows, LANE), 4)
+             + tile_bytes((rows, F), 4)
+             + 4 * tile_bytes((rows, block_n), 4)
+             + tile_bytes((groups, LANE, LANE), 2)
+             + tile_bytes((block_t, rows), 2))
+    return blocks + temps
+
+
 def leaf_gather_footprint(block_n: int, block_t: int, L: int, C: int) -> int:
     return (_block((block_t, block_n), 4)                # idx (trees, rows)
             + _block((C, block_n), 4)                    # out (classes, rows)

@@ -129,6 +129,19 @@ def test_cell_matches_ref_oracle(op, impl, lay, dtype, scenario):
             _assert_int_equal(got, want_idx)
         return
 
+    if op == "node_index":
+        # the oblivious trees rewritten as node tables: leaf ids keep
+        # the oblivious index, so the soa oracle's ids are the answer
+        low = layout_mod.lower(ens, "nodes", backend=_family(impl))
+        binsp = ops.pad_features(bins, low.borders.shape[1])
+        got = fn(binsp, low.node_features, low.node_bins, low.node_left,
+                 low.node_right, low.paths, low.path_len, width=low.width)
+        _assert_int_equal(got[:, :ens.n_trees], want_idx)
+        # padded trees must land in leaf 0
+        _assert_int_equal(got[:, ens.n_trees:],
+                          np.zeros_like(got[:, ens.n_trees:]))
+        return
+
     if op == "leaf_gather":
         _assert_close(fn(want_idx, lv), ref.leaf_gather(want_idx, lv))
         return

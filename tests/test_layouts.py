@@ -12,7 +12,8 @@ import pytest
 
 from repro.core import layout as layout_mod
 from repro.core.layout import (BitpackedLayout, DepthGroupedLayout,
-                               DepthMajorLayout, SoaLayout, lower)
+                               DepthMajorLayout, NodesLayout, SoaLayout,
+                               lower)
 from repro.core.predictor import PredictConfig, Predictor
 from repro.core.trees import (ObliviousEnsemble, PAD_SPLIT_BIN,
                               truncate_tree_depths)
@@ -379,4 +380,5 @@ def test_lowered_pytree_roundtrip(layout):
     nones = jax.tree_util.tree_map(lambda _: None, low,
                                    is_leaf=lambda v: v is None)
     assert isinstance(nones, (SoaLayout, DepthMajorLayout,
-                              DepthGroupedLayout, BitpackedLayout))
+                              DepthGroupedLayout, BitpackedLayout,
+                              NodesLayout))

@@ -432,7 +432,7 @@ def test_every_pallas_call_passes_a_name():
                     node.func.attr == "pallas_call":
                 calls.append((path.name, node.lineno,
                               {k.arg for k in node.keywords}))
-    assert len(calls) == 7
+    assert len(calls) == 8
     assert [c[:2] for c in calls if "name" not in c[2]] == []
 
 

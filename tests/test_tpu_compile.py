@@ -23,6 +23,7 @@ from repro.kernels import fused_predict as fk
 from repro.kernels import histogram as hk
 from repro.kernels import leaf_gather as gk
 from repro.kernels import leaf_index as ik
+from repro.kernels import node_index as nk
 from repro.kernels import ops, tuning
 
 F, FP, B, D, C, T = 54, 128, 254, 8, 7, 10_000
@@ -215,3 +216,64 @@ def test_plan_entries_read_the_leaf_table_in_place(one_chip, monkeypatch,
     assert not copies, copies
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < plan.lowered.leaf_table_bytes() == 5_242_880, temp
+
+
+def _lossguide_plan(n_trees):
+    """A pallas plan over a seeded node table at covertype-lossguide's
+    widths: chains of 30 nodes (31 leaves), 7 classes."""
+    import numpy as np
+
+    from repro.core.predictor import PredictConfig, Predictor
+    from repro.core.trees import NodeEnsemble
+
+    rng = np.random.default_rng(0)
+    nodes = np.arange(30)
+    left = np.broadcast_to(-1 - nodes, (n_trees, 30))
+    right = np.broadcast_to(np.append(nodes[1:], -31), (n_trees, 30))
+    ens = NodeEnsemble(
+        jnp.asarray(rng.integers(0, F, (n_trees, 30)).astype(np.int32)),
+        jnp.asarray(rng.integers(1, B, (n_trees, 30)).astype(np.int32)),
+        jnp.asarray(left.astype(np.int32)),
+        jnp.asarray(right.astype(np.int32)),
+        jnp.asarray(rng.normal(size=(n_trees, 31, C)).astype(np.float32)),
+        jnp.asarray(np.sort(rng.normal(size=(B, F)), 0).astype(np.float32)),
+        jnp.full((F,), B, jnp.int32))
+    return Predictor.build(ens, PredictConfig(backend="pallas"))
+
+
+def test_node_index_lossguide_widths(one_chip):
+    """The non-symmetric trees' leaf index at covertype-lossguide's
+    widths (54 features padded to 128, 30 nodes and 31 leaves in 32
+    slots, four trees to a 128-lane row, 10,048 trees) on a 256-row
+    uint8 chunk, as the bulk path calls it."""
+    w, tp = 32, 10_048
+    k = 128 // w
+    s = lambda shape, dt: _sds(one_chip, shape, dt)  # noqa: E731
+    packed = s((tp // k, 128), jnp.int32)
+    _compile(lambda b, nf, nb, p, pl_: nk.node_index(
+        b, nf, nb, p, pl_, width=w, block_n=256),
+        s((256, FP), jnp.uint8), packed, packed,
+        s((tp // k, w, 128), jnp.bfloat16), packed)
+
+
+def test_lossguide_bulk_entry_names_node_index(one_chip, monkeypatch):
+    """The nodes plan's bulk entry (proba_pool) runs binarize-free:
+    `node_index` then the shared `leaf_gather`, each named after its
+    benchmark trace name and carrying its `gbdt/<stage>` scope; the
+    node arrays and path matrices are read in place, never relaid out."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    plan = _lossguide_plan(128)
+    assert plan.config.layout == "nodes"
+    model = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
+                         plan.lowered)
+    text = plan._entries["proba_pool"].lower(
+        model, _sds(one_chip, (256, F), jnp.uint8)).compile().as_text()
+    kernels = {m.group(1).split(".")[0]: m.group(2) for m in re.finditer(
+        r'%(\S+) = \S+ custom-call\(.*op_name="([^"]*)"', text)}
+    assert sorted(kernels) == ["leaf_gather", "node_index"]
+    for name in kernels:
+        assert f"/gbdt/{name}/" in kernels[name], kernels[name]
+    copied = [line for line in text.splitlines()
+              if re.search(r"= \S+ copy\(", line)
+              and re.search(r'op_name="p\.(node|path)', line)]
+    assert not copied, copied

@@ -184,8 +184,8 @@ def _model_bytes(cell: Cell, refs: list[Any]) -> Optional[int]:
     if cell.op == "leaf_gather":
         idx, lv, _out = refs
         bt, bn = idx.shape
-        _, c, l = lv.shape                 # class-major leaf block
-        return tuning.leaf_gather_footprint(bn, bt, l, c)
+        bq, c, w = lv.shape                # packed (bt/G, Cp, G*Lp) block
+        return tuning.leaf_gather_footprint(bn, bt, w // (bt // bq), c)
     if cell.op == "fused_predict":
         # x, borders, <layout's model arrays>, lv, out, idx, bins scratch
         x, borders, model = refs[0], refs[1], refs[2]
@@ -198,7 +198,8 @@ def _model_bytes(cell: Cell, refs: list[Any]) -> Optional[int]:
         else:
             d = model.shape[1]
         gather = "bitplane" if cell.layout == "bitpacked" else "mxu"
-        _, c, l = lv.shape                 # class-major leaf block
+        bq, c, w = lv.shape                # packed (bt/G, Cp, G*Lp) block
+        l = w // (bt // bq)
         return tuning.fused_footprint(x.shape[0], bt, x.shape[1], d, l, c,
                                       borders.shape[0],
                                       bins_bytes=ib(scratch),

@@ -48,11 +48,18 @@ Five layouts:
                  NodeEnsemble takes; an oblivious model is rewritten
                  as a node table when asked for it).
 
-Every layout holds its leaf tables in one class-major form, (T, Cp, L)
-with Cp = C rounded up to `ops.CLASS_ALIGN` (8) and zero leaves in the
-padded classes: per tree a (Cp, L) table of whole (8, 128) tiles with
-the leaves on lanes, the layout the device stores it in and the one the
-leaf-sum kernels read, so no relayout runs per call.  The model format
+Every layout holds its leaf tables in one packed class-major form,
+(Tp/G, Cp, G*Lp) (`ops.class_major`): Cp = C rounded up to
+`ops.CLASS_ALIGN` (8), Lp = L rounded up to 8, and G =
+`tuning.trees_per_step(L)` trees side by side on the lanes of each row,
+tree t in lanes [(t % G) * Lp, (t % G) * Lp + L) of row t // G, with
+zero leaves in the padded classes, leaf slots and trees.  A row is whole
+(8, 128) tiles with the leaves on lanes, the layout the device stores it
+in and the one the leaf-sum kernels read, so no relayout runs per call,
+and one MXU contraction sums a row's G trees.  Every tree axis is padded
+to whole groups (pallas lowerings to the kernel's tree block as well),
+so G = Tp / rows can be read off the shapes; tree shards and
+`tree_block` blocks are cut on group boundaries.  The model format
 (T, L, C) of `ObliviousEnsemble` and training is unchanged.
 
 Every layout is bit-for-bit the same *math* as the logical model:
@@ -71,6 +78,8 @@ import numpy as np
 from repro.core.trees import NodeEnsemble
 from repro.kernels import ops
 from repro.kernels import node_index as node_k
+from repro.kernels import tuning
+from repro.kernels.leaf_gather import trees_per_row
 from repro.kernels.leaf_index import BP_TREE_BLOCK
 from repro.kernels.ops import FEATURE_ALIGN, PAD_SPLIT_BIN
 
@@ -118,6 +127,18 @@ def _register_lowered(cls, data_fields: tuple[str, ...],
     return cls
 
 
+def _leaf_steps(tables) -> dict[str, Any]:
+    """`describe()`'s leaf-sum engagement over (packed table, padded
+    tree count) pairs: trees_per_step, G = Tp / rows of each table (one
+    int when they agree, else a list in table order), and
+    leaf_steps_per_block, the loop steps the leaf sum runs over one row
+    block (Tp/G rows a table)."""
+    gs = [trees_per_row(n, lv) for lv, n in tables]
+    return {"trees_per_step": gs[0] if len(set(gs)) == 1 else gs,
+            "leaf_steps_per_block": sum(int(lv.shape[0])
+                                        for lv, _ in tables)}
+
+
 @dataclasses.dataclass(frozen=True)
 class SoaLayout:
     """Structure-of-arrays, a single shared depth (today's layout)."""
@@ -125,7 +146,7 @@ class SoaLayout:
     borders: jax.Array           # (B, Fp) f32
     split_features: jax.Array    # (Tp, D) i32
     split_bins: jax.Array        # (Tp, D) i32
-    leaf_values: jax.Array       # (Tp, Cp, L) f32, class-major
+    leaf_values: jax.Array       # (Tp/G, Cp, G*Lp) f32, packed class-major
     # staged tree blocking: per-block (sf, sb, lv) slices, pre-cut and
     # pre-padded at lower time so the per-call loop never touches jnp.pad;
     # the whole-model arrays above are then empty
@@ -160,16 +181,21 @@ class SoaLayout:
             self.leaf_values, n_classes=self.n_outputs, backend=backend,
             block_n=block_n, block_t=block_t)
 
+    def _tables(self) -> list:
+        """(packed leaf table, padded tree count) of each tree block."""
+        blocks = self.tree_blocks or [(self.split_features, None,
+                                       self.leaf_values)]
+        return [(lv, sf.shape[0]) for sf, _, lv in blocks]
+
     def leaf_table_bytes(self) -> int:
-        tables = ([lv for _, _, lv in self.tree_blocks]
-                  if self.tree_blocks else [self.leaf_values])
-        return sum(int(np.prod(lv.shape)) * 4 for lv in tables)
+        return sum(int(np.prod(lv.shape)) * 4 for lv, _ in self._tables())
 
     def describe(self) -> dict[str, Any]:
         return {"layout": self.layout_name,
                 "leaf_table_bytes": self.leaf_table_bytes(),
                 "tree_blocks": (len(self.tree_blocks)
-                                if self.tree_blocks else 0)}
+                                if self.tree_blocks else 0),
+                **_leaf_steps(self._tables())}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -180,7 +206,7 @@ class DepthMajorLayout:
     onehot: jax.Array            # (Tp, D, Fp) f32 — onehot(sf[t, d])
     split_bins_dm: jax.Array     # (D, Tp) i32 — bit-plane transposed
     pow2: jax.Array              # (D, 1) f32 — hoisted 2^d vector
-    leaf_values: jax.Array       # (Tp, Cp, L) f32, class-major
+    leaf_values: jax.Array       # (Tp/G, Cp, G*Lp) f32, packed class-major
     n_outputs: int = 1           # static
     n_model_pads: int = 0        # static
 
@@ -209,7 +235,9 @@ class DepthMajorLayout:
     def describe(self) -> dict[str, Any]:
         return {"layout": self.layout_name,
                 "leaf_table_bytes": self.leaf_table_bytes(),
-                "onehot_bytes": self.onehot_bytes()}
+                "onehot_bytes": self.onehot_bytes(),
+                **_leaf_steps([(self.leaf_values,
+                                self.onehot.shape[0])])}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -218,7 +246,7 @@ class DepthGroup:
     depth: int                   # static: true depth d of the group
     split_features: jax.Array    # (Tg_p, d) i32
     split_bins: jax.Array        # (Tg_p, d) i32
-    leaf_values: jax.Array       # (Tg_p, Cp, 2^d) f32, class-major
+    leaf_values: jax.Array       # (Tg_p/G, Cp, G*Lp) f32, packed (L = 2^d)
 
     @property
     def n_trees(self) -> int:
@@ -264,7 +292,9 @@ class DepthGroupedLayout:
         return {"layout": self.layout_name,
                 "leaf_table_bytes": self.leaf_table_bytes(),
                 "groups": {int(g.depth): int(g.n_trees)
-                           for g in self.groups}}
+                           for g in self.groups},
+                **_leaf_steps([(g.leaf_values, g.n_trees)
+                               for g in self.groups])}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -273,7 +303,7 @@ class BitpackedGroup:
     depth: int                   # static: true depth d of the group
     split_features_bp: jax.Array  # (d, Tg_p) i32 — bit-plane transposed
     split_bins_bp: jax.Array     # (d, Tg_p) u8 when thresholds fit, else i32
-    leaf_values: jax.Array       # (Tg_p, Cp, 2^d) f32, class-major
+    leaf_values: jax.Array       # (Tg_p/G, Cp, G*Lp) f32, packed (L = 2^d)
 
     @property
     def n_trees(self) -> int:
@@ -352,6 +382,8 @@ class BitpackedLayout:
                 "plane_bytes": self.plane_bytes(),
                 "groups": {int(g.depth): int(g.n_trees)
                            for g in self.groups},
+                **_leaf_steps([(g.leaf_values, g.n_trees)
+                               for g in self.groups]),
                 "binary_split": self.binary_split,
                 "pool_row_bytes_u8": u8,
                 "pool_row_bytes_u1": u1,
@@ -375,7 +407,8 @@ class NodesLayout:
       path_len                     (Tp/k, k*W) int32: the path's length
                                      (`node_index.PAD_PATH_LEN` for an
                                      unreached or padded leaf)
-      leaf_values                  (Tp, Cp, W) float32, class-major
+      leaf_values                  (Tp/G, Cp, G*W) float32, packed
+                                     class-major (`ops.class_major`)
 
     The pallas kernel matches paths; the reference walks the node
     arrays from the root.  Both give each row its leaf, which the
@@ -389,7 +422,7 @@ class NodesLayout:
     node_right: jax.Array        # (Tp/k, k*W) i32
     paths: jax.Array             # (Tp/k, W, k*W) bf16
     path_len: jax.Array          # (Tp/k, k*W) i32
-    leaf_values: jax.Array       # (Tp, Cp, W) f32, class-major
+    leaf_values: jax.Array       # (Tp/G, Cp, G*W) f32, packed class-major
     n_outputs: int = 1           # static
     n_model_pads: int = 0        # static
     width: int = 8               # static: W
@@ -428,19 +461,34 @@ class NodesLayout:
                 "lowered_bytes": self.lowered_bytes(),
                 "width": self.width,
                 "nodes_per_tree": self.nodes_per_tree,
-                "leaves_per_tree": self.leaves_per_tree}
+                "leaves_per_tree": self.leaves_per_tree,
+                **_leaf_steps([(self.leaf_values,
+                                self.node_features.size // self.width)])}
 
 
 def _pad_tree_axis(a, axis: int, target: int, value=0):
     return ops._pad_dim(a, axis, target, value=value, kind="model")
 
 
-def _shard_bounds(n_trees: int, n_shards: int, t_align: int):
+def _shard_bounds(n_trees: int, n_shards: int, t_align: int, lv):
     """(padded total, per-shard size) for an equal T-axis split where
-    every shard stays a `t_align` multiple."""
-    unit = max(n_shards * max(t_align, 1), 1)
+    every shard stays a multiple of `t_align` and of the group G of the
+    packed leaf table `lv` (so shards cut on group boundaries)."""
+    align = math.lcm(max(t_align, 1), trees_per_row(n_trees, lv))
+    unit = max(n_shards * align, 1)
     total = ops._round_up(max(n_trees, 1), unit)
     return total, total // n_shards
+
+
+def _shard_table(lv, n_trees: int, total: int, per: int,
+                 n_shards: int) -> list:
+    """Each tree shard's rows of a packed leaf table of n_trees trees:
+    padded with zero rows to `total` trees, cut every `per` trees
+    (whole groups)."""
+    g = trees_per_row(n_trees, lv)
+    lv = _pad_tree_axis(lv, 0, total // g)
+    rows = per // g
+    return [lv[k * rows:(k + 1) * rows] for k in range(n_shards)]
 
 
 def shard_trees(lowered: LoweredEnsemble, n_shards: int, *,
@@ -475,47 +523,50 @@ def shard_trees(lowered: LoweredEnsemble, n_shards: int, *,
                 "shard_trees on a tree-blocked soa plan is unsupported: "
                 "the block slices were cut for the single-device loop; "
                 "lower with tree_block=0 before tree-sharding")
-        total, per = _shard_bounds(lowered.split_features.shape[0],
-                                   n_shards, t_align)
+        n = lowered.split_features.shape[0]
+        total, per = _shard_bounds(n, n_shards, t_align,
+                                   lowered.leaf_values)
         sf = _pad_tree_axis(lowered.split_features, 0, total)
         sb = _pad_tree_axis(lowered.split_bins, 0, total,
                             value=PAD_SPLIT_BIN)
-        lv = _pad_tree_axis(lowered.leaf_values, 0, total)
+        lv = _shard_table(lowered.leaf_values, n, total, per, n_shards)
         return [SoaLayout(lowered.borders,
                           sf[k * per:(k + 1) * per],
                           sb[k * per:(k + 1) * per],
-                          lv[k * per:(k + 1) * per], None,
+                          lv[k], None,
                           n_outputs=lowered.n_outputs,
                           n_model_pads=lowered.n_model_pads)
                 for k in range(n_shards)]
     if isinstance(lowered, DepthMajorLayout):
-        total, per = _shard_bounds(lowered.onehot.shape[0], n_shards,
-                                   t_align)
+        n = lowered.onehot.shape[0]
+        total, per = _shard_bounds(n, n_shards, t_align,
+                                   lowered.leaf_values)
         oh = _pad_tree_axis(lowered.onehot, 0, total)
         sb = _pad_tree_axis(lowered.split_bins_dm, 1, total,
                             value=PAD_SPLIT_BIN)
-        lv = _pad_tree_axis(lowered.leaf_values, 0, total)
+        lv = _shard_table(lowered.leaf_values, n, total, per, n_shards)
         return [DepthMajorLayout(lowered.borders,
                                  oh[k * per:(k + 1) * per],
                                  sb[:, k * per:(k + 1) * per],
                                  lowered.pow2,
-                                 lv[k * per:(k + 1) * per],
+                                 lv[k],
                                  n_outputs=lowered.n_outputs,
                                  n_model_pads=lowered.n_model_pads)
                 for k in range(n_shards)]
     if isinstance(lowered, DepthGroupedLayout):
         shard_groups = [[] for _ in range(n_shards)]
         for g in lowered.groups:
-            total, per = _shard_bounds(g.n_trees, n_shards, t_align)
+            total, per = _shard_bounds(g.n_trees, n_shards, t_align,
+                                       g.leaf_values)
             sf = _pad_tree_axis(g.split_features, 0, total)
             sb = _pad_tree_axis(g.split_bins, 0, total,
                                 value=PAD_SPLIT_BIN)
-            lv = _pad_tree_axis(g.leaf_values, 0, total)
+            lv = _shard_table(g.leaf_values, g.n_trees, total, per,
+                              n_shards)
             for k in range(n_shards):
                 shard_groups[k].append(
                     DepthGroup(g.depth, sf[k * per:(k + 1) * per],
-                               sb[k * per:(k + 1) * per],
-                               lv[k * per:(k + 1) * per]))
+                               sb[k * per:(k + 1) * per], lv[k]))
         return [DepthGroupedLayout(lowered.borders, tuple(gs),
                                    n_outputs=lowered.n_outputs,
                                    n_model_pads=lowered.n_model_pads)
@@ -527,7 +578,8 @@ def shard_trees(lowered: LoweredEnsemble, n_shards: int, *,
             t_align = math.lcm(max(t_align, 1), BP_TREE_BLOCK)
         shard_groups = [[] for _ in range(n_shards)]
         for g in lowered.groups:
-            total, per = _shard_bounds(g.n_trees, n_shards, t_align)
+            total, per = _shard_bounds(g.n_trees, n_shards, t_align,
+                                       g.leaf_values)
             sf = _pad_tree_axis(g.split_features_bp, 1, total)
             # uint8 planes can't hold PAD_SPLIT_BIN; pad 0 instead —
             # the padded trees' leaf rows are all-zero, so whichever
@@ -535,12 +587,12 @@ def shard_trees(lowered: LoweredEnsemble, n_shards: int, *,
             pad_bin = (0 if g.split_bins_bp.dtype == jnp.uint8
                        else PAD_SPLIT_BIN)
             sb = _pad_tree_axis(g.split_bins_bp, 1, total, value=pad_bin)
-            lv = _pad_tree_axis(g.leaf_values, 0, total)
+            lv = _shard_table(g.leaf_values, g.n_trees, total, per,
+                              n_shards)
             for k in range(n_shards):
                 shard_groups[k].append(
                     BitpackedGroup(g.depth, sf[:, k * per:(k + 1) * per],
-                                   sb[:, k * per:(k + 1) * per],
-                                   lv[k * per:(k + 1) * per]))
+                                   sb[:, k * per:(k + 1) * per], lv[k]))
         return [BitpackedLayout(lowered.borders, tuple(gs),
                                 n_outputs=lowered.n_outputs,
                                 n_model_pads=lowered.n_model_pads,
@@ -757,24 +809,30 @@ class _LowerCtx:
         return self.pad(borders, 1, fp, value=np.float32(np.inf))
 
     def pad_trees(self, sf, sb, lv):
-        """Pad the tree axis (pallas) and lower the (T, L, C) leaf table
-        to the class-major (Tp, Cp, L) form every layout holds."""
-        T, _, C = lv.shape
-        tp = (ops._round_up(max(T, 1), self.t_align) if self.pallas
-              else T)
-        lv_cm = ops.class_major(lv, tp)
-        self.n_pads += (tp != T) + (lv_cm.shape[1] != C)
+        """Pad the tree axis to whole groups of the packed table (pallas:
+        and to the tree block) and lower the (T, L, C) leaf table to the
+        packed class-major (Tp/G, Cp, G*Lp) form every layout holds."""
+        T, L, _ = lv.shape
+        align = tuning.trees_per_step(L)
+        if self.pallas:
+            align = math.lcm(self.t_align, align)
+        tp = ops._round_up(max(T, 1), align)
+        self.n_pads += ops.leaf_pads(lv.shape, tp)[0]
         return (self.pad(sf, 0, tp),
-                self.pad(sb, 0, tp, value=PAD_SPLIT_BIN), lv_cm)
+                self.pad(sb, 0, tp, value=PAD_SPLIT_BIN),
+                ops.class_major(lv, tp))
 
 
 def _lower_soa(ensemble, ctx: _LowerCtx, tree_block: int) -> SoaLayout:
     borders = ctx.pad_borders(ensemble.borders)
     if tree_block and ensemble.n_trees > tree_block:
+        # blocks cut on group boundaries of the packed leaf table
+        step = ops._round_up(tree_block, tuning.trees_per_step(
+            ensemble.leaf_values.shape[1]))
         blocks = []
-        for start in range(0, ensemble.n_trees, tree_block):
+        for start in range(0, ensemble.n_trees, step):
             blk = ensemble.slice_trees(
-                start, min(start + tree_block, ensemble.n_trees))
+                start, min(start + step, ensemble.n_trees))
             blocks.append(ctx.pad_trees(blk.split_features, blk.split_bins,
                                         blk.leaf_values))
         # the blocked path never reads the whole-ensemble arrays, so they
@@ -865,7 +923,10 @@ def _lower_bitpacked(ensemble, ctx: _LowerCtx) -> BitpackedLayout:
                                       jnp.asarray(lv[rows][:, :1 << d]))
         gsb_bp = jnp.transpose(gsb)
         if narrow:
-            gsb_bp = gsb_bp.astype(jnp.uint8)
+            # trees padded to whole groups have all-zero leaves, so any
+            # bin will do for them: 0 in place of PAD_SPLIT_BIN
+            gsb_bp = jnp.where(gsb_bp == PAD_SPLIT_BIN, 0,
+                               gsb_bp).astype(jnp.uint8)
         groups.append(BitpackedGroup(d, jnp.transpose(gsf), gsb_bp, glv))
     n_borders = np.asarray(ensemble.n_borders)
     return BitpackedLayout(borders, tuple(groups),
@@ -901,8 +962,9 @@ def _lower_nodes(ensemble, ctx: _LowerCtx) -> NodesLayout:
             f"{ops.FEATURE_ALIGN} leaves and {ops.FEATURE_ALIGN - 1} nodes; "
             f"this ensemble needs {width} slots a tree")
     k = max(1, ops.FEATURE_ALIGN // width)
-    align = (math.lcm(ctx.t_align, node_k.tree_block(width)) if ctx.pallas
-             else k)
+    align = math.lcm(k, tuning.trees_per_step(width))
+    if ctx.pallas:
+        align = math.lcm(align, ctx.t_align, node_k.tree_block(width))
     tp = ops._round_up(max(T, 1), align)
 
     def pack(a, fill):      # (T, n) -> padded (tp, width) -> (tp/k, k*W)
@@ -917,9 +979,8 @@ def _lower_nodes(ensemble, ctx: _LowerCtx) -> NodesLayout:
     packed_p = (p.reshape(tp // k, k, width, width).transpose(0, 2, 1, 3)
                 .reshape(tp // k, width, k * width))
     lv = ops._pad_dim(ensemble.leaf_values, 1, width, kind="model")
+    ctx.n_pads += ops.leaf_pads(lv.shape, tp)[0] + (width != n_leaves)
     lv_cm = ops.class_major(lv, tp)
-    ctx.n_pads += (tp != T) + (lv_cm.shape[1] != ensemble.n_outputs) \
-        + (width != n_leaves)
     return NodesLayout(
         borders, pack(ensemble.node_features, 0),
         pack(ensemble.node_bins, PAD_SPLIT_BIN),

@@ -10,8 +10,8 @@ t-block 0 into VMEM scratch and reused for every tree block (the grid's
 T axis is serial on TPU).  The three stages are the standalone kernels'
 own bodies (`binarize.count_borders`, `leaf_index.*_index`,
 `leaf_gather.accumulate_leaves`), so fused and staged plans run the same
-arithmetic, and all three kernels read the same class-major (bt, Cp, L)
-leaf block.
+arithmetic, and all three kernels read the same packed class-major
+(bt/G, Cp, G*Lp) leaf block (G trees a row; see `kernels.leaf_gather`).
 
 Grid: (N / block_n, T / block_t).
 """
@@ -26,7 +26,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import tuning
 from repro.kernels.binarize import count_borders
-from repro.kernels.leaf_gather import accumulate_into, accumulate_leaves
+from repro.kernels.leaf_gather import (accumulate_into, accumulate_leaves,
+                                      trees_per_row)
 from repro.kernels.leaf_index import (BP_TREE_BLOCK, bitplane_index,
                                       depth_major_index, soa_index)
 
@@ -55,19 +56,21 @@ def _fused_call(kernel, x, borders, model_args, model_specs, leaf_values,
                 name):
     N, F = x.shape
     B = borders.shape[0]
-    _, C, L = leaf_values.shape
-    if N % block_n or T % block_t:
+    _, C, W = leaf_values.shape
+    G = trees_per_row(T, leaf_values)
+    if N % block_n or T % block_t or block_t % G:
         raise ValueError(
             f"{name} requires padded inputs: N={N} % block_n="
-            f"{block_n} and T={T} % block_t={block_t} must be 0 "
-            "(use kernels.ops.fused_predict for automatic padding)")
+            f"{block_n}, T={T} % block_t={block_t} and block_t % G={G} "
+            "must be 0 (use kernels.ops.fused_predict for automatic "
+            "padding)")
     return pl.pallas_call(
         functools.partial(kernel, n_borders=B),
         grid=(N // block_n, T // block_t),
         in_specs=[pl.BlockSpec((block_n, F), lambda i, j: (i, 0)),
                   pl.BlockSpec((B, F), lambda i, j: (0, 0))]
         + model_specs
-        + [pl.BlockSpec((block_t, C, L), lambda i, j: (j, 0, 0))],
+        + [pl.BlockSpec((block_t // G, C, W), lambda i, j: (j, 0, 0))],
         out_specs=pl.BlockSpec((C, block_n), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((C, N), jnp.float32),
         # the tree-major index block stage 3 walks row by row, then the
@@ -92,11 +95,11 @@ def fused_predict(x: jax.Array, borders: jax.Array, split_features: jax.Array,
     lanes; `kernels.ops` transposes and drops the padded classes).
 
     Raw kernel entry: N and T must already be multiples of the block
-    shapes (block_n a multiple of 128, block_t of 8), F a multiple of
-    128, `leaf_values` the class-major (T, Cp, L) table with Cp a
-    multiple of 8, and padded trees must carry zero leaf_values and
-    split_bins > #bins (padded samples/features/classes are harmless
-    zeros).
+    shapes (block_n a multiple of 128, block_t of 8 and of G), F a
+    multiple of 128, `leaf_values` the packed class-major (T/G, Cp,
+    G*Lp) table `ops.class_major` builds, with Cp a multiple of 8, and
+    padded trees must carry zero leaf_values and split_bins > #bins
+    (padded samples/features/classes/leaf slots are harmless zeros).
     `kernels.ops.fused_predict` is the public wrapper that performs that
     padding and picks the block shapes from the tuner — call it, not
     this, unless you have pre-padded tensors.  `bins_scratch_dtype`

@@ -15,6 +15,7 @@ implementation names (e.g. `backend="pallas_u8"`) to pin a variant.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -47,7 +48,8 @@ FEATURE_ALIGN = 128
 MAX_U8_BORDERS = 255
 
 # Sublane tile height the lowered leaf table pads its class axis to, so
-# a (Cp, L) per-tree table is whole (8, 128) tiles with leaves on lanes.
+# a (Cp, G*Lp) row of G trees is whole (8, 128) tiles with leaves on
+# lanes.
 CLASS_ALIGN = 8
 
 
@@ -114,33 +116,69 @@ def _pad_dim(a: jax.Array, axis: int, target: int, value=0,
     return jnp.pad(a, widths, constant_values=value)
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2))
-def _transpose_pad(leaf_values, n_trees, n_classes):
-    T, _, C = leaf_values.shape
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4))
+def _pack(leaf_values, n_trees, n_classes, n_lanes, group):
+    T, L, C = leaf_values.shape
     # a typed fill keeps integer constants out of float traces
-    return jnp.pad(jnp.transpose(leaf_values, (0, 2, 1)),
-                   ((0, n_trees - T), (0, n_classes - C), (0, 0)),
-                   constant_values=np.zeros((), leaf_values.dtype))
+    lv = jnp.pad(leaf_values, ((0, n_trees - T), (0, n_lanes - L),
+                               (0, n_classes - C)),
+                 constant_values=np.zeros((), leaf_values.dtype))
+    return (lv.reshape(n_trees // group, group, n_lanes, n_classes)
+            .transpose(0, 3, 1, 2)
+            .reshape(n_trees // group, n_classes, group * n_lanes))
+
+
+def leaf_pads(shape: tuple[int, int, int],
+              n_trees: int | None = None) -> tuple[int, int]:
+    """(model pad ops, packed tree count) `class_major` spends on a
+    model-format (T, L, C) leaf table: one pad for each of the tree,
+    leaf and class axes it widens, and n_trees (default T) rounded up
+    to whole groups of `tuning.trees_per_step(L)` trees."""
+    T, L, C = shape
+    tp = _round_up(T if n_trees is None else n_trees,
+                   _tuning.trees_per_step(L))
+    pads = ((tp != T) + (_tuning.leaf_lanes(L) != L)
+            + (_round_up(C, CLASS_ALIGN) != C))
+    return pads, tp
 
 
 def class_major(leaf_values: jax.Array,
                 n_trees: int | None = None) -> jax.Array:
-    """Model-format leaf table (T, L, C) -> the lowered class-major form
-    (n_trees, Cp, L), Cp = C rounded up to `CLASS_ALIGN`; padded trees
-    (n_trees defaults to T) and classes hold zero leaves.  The leaf-sum
-    kernels read only this form.  One jitted pass: the transpose and
-    both pads write a single output, with no intermediate copy."""
-    T, _, C = leaf_values.shape
-    n_trees = T if n_trees is None else n_trees
-    n_classes = _round_up(C, CLASS_ALIGN)
-    _PAD_STATS["model"] += (n_trees != T) + (n_classes != C)
-    return _transpose_pad(leaf_values, n_trees, n_classes)
+    """Model-format leaf table (T, L, C) -> the lowered packed
+    class-major form (Tp/G, Cp, G*Lp): G = `tuning.trees_per_step(L)`
+    trees a row, Lp = L rounded up to 8, Cp = C rounded up to
+    `CLASS_ALIGN`, and Tp = n_trees (default T) rounded up to whole
+    groups.  Tree t's value of leaf l for class c sits at
+    [t // G, c, (t % G) * Lp + l]; padded trees, leaf slots and classes
+    hold zero leaves.  The leaf-sum kernels read only this form.  One
+    jitted pass: the pads, the transpose and the packing write a single
+    output."""
+    T, L, C = leaf_values.shape
+    pads, tp = leaf_pads(leaf_values.shape, n_trees)
+    _PAD_STATS["model"] += pads
+    return _pack(leaf_values, tp, _round_up(C, CLASS_ALIGN),
+                 _tuning.leaf_lanes(L), _tuning.trees_per_step(L))
 
 
-def leaf_major(leaf_values_cm: jax.Array, n_classes: int) -> jax.Array:
-    """Inverse of `class_major`: (T, Cp, L) -> the model format
-    (T, L, n_classes), padded classes dropped."""
-    return jnp.transpose(leaf_values_cm[:, :n_classes, :], (0, 2, 1))
+def leaf_major(leaf_values_cm: jax.Array, n_classes: int,
+               n_trees: int) -> jax.Array:
+    """Inverse of `class_major`: a packed table of n_trees trees (a
+    whole number of groups) -> the model format (n_trees, Lp,
+    n_classes), padded classes dropped.  G = n_trees / rows and Lp =
+    G*Lp / G are read off the shapes; the padded leaf slots stay, all
+    zero."""
+    rows, cp, width = leaf_values_cm.shape
+    g = _gather_k.trees_per_row(n_trees, leaf_values_cm)
+    return (leaf_values_cm.reshape(rows, cp, g, width // g)
+            .transpose(0, 2, 3, 1)
+            .reshape(n_trees, width // g, cp)[:, :, :n_classes])
+
+
+def _tree_block(block_t: int, n_trees: int, leaf_values: jax.Array) -> int:
+    """The tree block a leaf-sum kernel runs on a packed table: block_t
+    rounded up to whole groups of its rows."""
+    return math.lcm(int(block_t),
+                    _gather_k.trees_per_row(n_trees, leaf_values))
 
 
 def pad_features(bins: jax.Array, target_f: int) -> jax.Array:
@@ -420,22 +458,25 @@ def _node_index_pallas(bins, node_features, node_bins, node_left,
 # Registered implementations: leaf_gather
 # --------------------------------------------------------------------------
 # A registered leaf_gather / fused_predict impl takes the model-format
-# table (T, L, C), or with `prepadded=True` the lowered class-major
-# table (Tp, Cp, L) and the `n_classes` C it holds.  The kernels sum
-# (Cp, N); the impls slice the padded classes off before returning.
+# table (T, L, C), or with `prepadded=True` the lowered packed
+# class-major table (Tp/G, Cp, G*Lp) and the `n_classes` C it holds;
+# G is read off the tree axis Tp of the index or split arrays.  The
+# kernels sum (Cp, N); the impls slice the padded classes off before
+# returning.
 @registry.register("leaf_gather", "ref", dtypes=("int32",),
                    layouts=ALL_LAYOUTS,
                    constraints="any shape; pure-jnp oracle")
 def _leaf_gather_ref(idx, leaf_values, *, prepadded=False, n_classes=None,
                      **_blocks):
     if prepadded:
-        leaf_values = leaf_major(leaf_values, n_classes)
+        leaf_values = leaf_major(leaf_values, n_classes, idx.shape[1])
     return _ref.leaf_gather(idx, leaf_values)
 
 
 @registry.register("leaf_gather", "pallas", dtypes=("int32",),
                    layouts=ALL_LAYOUTS,
-                   constraints="pads N/T to block multiples")
+                   constraints="pads N/T to block multiples; block_t "
+                               "rounds up to whole groups of G trees")
 def _leaf_gather_pallas(idx, leaf_values, *, block_n=128, block_t=16,
                         prepadded=False, n_classes=None):
     # the kernel reads the index tree-major (see _leaf_index_pallas_impl)
@@ -443,8 +484,11 @@ def _leaf_gather_pallas(idx, leaf_values, *, block_n=128, block_t=16,
     N, T = idx.shape
     if prepadded:
         Tp, lvp = T, leaf_values
+        block_t = _tree_block(block_t, Tp, lvp)
     else:
-        Tp, n_classes = _round_up(T, block_t), leaf_values.shape[2]
+        _, L, n_classes = leaf_values.shape
+        block_t = math.lcm(block_t, _tuning.trees_per_step(L))
+        Tp = _round_up(T, block_t)
         # zero leaves: padded trees are no-ops
         lvp = class_major(leaf_values, Tp)
     idxp = _pad_dim(_pad_dim(idx, 0, _round_up(max(N, 1), block_n)), 1, Tp)
@@ -497,7 +541,7 @@ def _fused_ref(x, borders, sf, sb, lv, *, prepadded=False, n_classes=None,
                **_blocks):
     if prepadded:
         x = _pad_dim(x, 1, borders.shape[1])
-        lv = leaf_major(lv, n_classes)
+        lv = leaf_major(lv, n_classes, sf.shape[0])
     return _ref.fused_predict(x, borders, sf, sb, lv)
 
 
@@ -517,10 +561,10 @@ def _fused_pallas(x, borders, sf, sb, lv, *, block_n=None, block_t=None,
         N = x.shape[0]
         Np = _round_up(max(N, 1), block_n)
         xp = _pad_dim(_pad_dim(x, 0, Np), 1, borders.shape[1])
-        out = _fused_k.fused_predict(xp, borders, sf, sb, lv,
-                                     block_n=block_n, block_t=block_t,
-                                     interpret=_interpret(),
-                                     bins_scratch_dtype=scratch)
+        out = _fused_k.fused_predict(
+            xp, borders, sf, sb, lv, block_n=block_n,
+            block_t=_tree_block(block_t, sf.shape[0], lv),
+            interpret=_interpret(), bins_scratch_dtype=scratch)
         return out.T[:N, :n_classes]
     N, F = x.shape
     T, D = sf.shape
@@ -531,6 +575,7 @@ def _fused_pallas(x, borders, sf, sb, lv, *, block_n=None, block_t=None,
         block_n = block_n or tn
         block_t = block_t or tt
     block_n = _lanes(block_n)
+    block_t = math.lcm(block_t, _tuning.trees_per_step(L))
     Np = _round_up(N, block_n)
     Tp = _round_up(T, block_t)
     Fp = _round_up(F, FEATURE_ALIGN)
@@ -552,7 +597,7 @@ def _fused_ref_dm(x, borders, onehot, sb_dm, pow2, lv, *, prepadded=False,
                   n_classes=None, **_blocks):
     if prepadded:
         x = _pad_dim(x, 1, borders.shape[1])
-        lv = leaf_major(lv, n_classes)
+        lv = leaf_major(lv, n_classes, onehot.shape[0])
     return _ref.fused_predict_depth_major(x, borders, onehot, sb_dm,
                                           pow2, lv)
 
@@ -568,22 +613,29 @@ def _fused_pallas_dm(x, borders, onehot, sb_dm, pow2, lv, *,
     scratch = (jnp.uint8 if borders.shape[0] <= MAX_U8_BORDERS
                else jnp.int32)
     T, D, F = onehot.shape
+    L = 1 << D
+    g = _tuning.trees_per_step(L)
     if not prepadded:
+        # whole groups of the packed table: trees padded here go left
+        # to a zero leaf
         n_classes, lv = lv.shape[2], class_major(lv, T)
+        T = _round_up(T, g)
+        onehot = _pad_dim(onehot, 0, T, kind="model")
+        sb_dm = _pad_dim(sb_dm, 1, T, value=PAD_SPLIT_BIN, kind="model")
     if block_n is None or block_t is None:
         # same autotune fallback as the soa impl (plans always pass
         # concrete blocks; direct registry dispatch may not) — except
         # the model side is already lowered here, so block_t must
         # divide the pre-padded T rather than drive its padding
-        L = lv.shape[2]
         tn, tt = _tuning.best_fused_blocks(
             F, D, L, n_classes, borders.shape[0], n_rows=x.shape[0],
             n_trees=T)
         block_n = block_n or tn
         if block_t is None:
             block_t = next(bt for bt in (tt, 64, 32, 16, 8, 4, 2, 1)
-                           if T % bt == 0)
+                           if T % bt == 0 and bt % g == 0)
     block_n = _lanes(block_n)
+    block_t = _tree_block(block_t, T, lv)
     N = x.shape[0]
     Np = _round_up(max(N, 1), block_n)
     xp = _pad_dim(_pad_dim(x, 0, Np), 1, borders.shape[1])
@@ -601,7 +653,7 @@ def _fused_ref_bp(x, borders, sf_bp, sb_bp, lv, *, prepadded=False,
                   n_classes=None, **_blocks):
     if prepadded:
         x = _pad_dim(x, 1, borders.shape[1])
-        lv = leaf_major(lv, n_classes)
+        lv = leaf_major(lv, n_classes, sf_bp.shape[1])
     return _ref.fused_predict_bitpacked(x, borders, sf_bp, sb_bp, lv)
 
 
@@ -626,14 +678,16 @@ def _fused_pallas_bp(x, borders, sf_bp, sb_bp, lv, *, block_n=None,
         n_classes, lv = lv.shape[2], class_major(lv)
     if block_n is None:
         block_n, _ = _tuning.best_fused_blocks(
-            borders.shape[1], D, lv.shape[2], n_classes, borders.shape[0],
+            borders.shape[1], D, 1 << D, n_classes, borders.shape[0],
             n_rows=x.shape[0], n_trees=T, gather="bitplane")
     block_n = _lanes(block_n)
     N = x.shape[0]
     bp = _pad_dim(borders, 1, _round_up(borders.shape[1], FEATURE_ALIGN),
                   value=np.float32(np.inf), kind="model")
     xp, sfp, sbp = _bp_pad(x, sf_bp, sb_bp, block_n)
-    lvp = _pad_dim(lv, 0, sfp.shape[1], kind="model")
+    # whole packed rows: a 128-tree lane holds whole groups
+    lvp = _pad_dim(lv, 0, sfp.shape[1] // _tuning.trees_per_step(1 << D),
+                   kind="model")
     out = _fused_k.fused_predict_bp(xp, bp, sfp, sbp, lvp,
                                     block_n=block_n,
                                     interpret=_interpret(),
@@ -756,7 +810,7 @@ def leaf_gather(idx: jax.Array, leaf_values: jax.Array, *,
     """(N, T) i32, (T, L, C) f32 -> (N, C) f32 summed leaf values.
 
     Takes the model format; the pallas impl lowers it to the kernel's
-    class-major form per call (the plans lower it once)."""
+    packed class-major form per call (the plans lower it once)."""
     return registry.dispatch("leaf_gather", backend, idx, leaf_values,
                              block_n=block_n, block_t=block_t)
 
@@ -822,10 +876,12 @@ def fused_predict(x: jax.Array, borders: jax.Array, split_features: jax.Array,
 # Invariants the builder guarantees for the pallas backend:
 #   borders  F padded to a FEATURE_ALIGN multiple with +inf
 #   splits   T padded to a block_t multiple (bins=PAD_SPLIT_BIN: go left)
-#   leaves   class-major (Tp, Cp, L), Cp = C rounded up to CLASS_ALIGN,
-#            padded trees and classes all zero (they contribute nothing)
-# On the ref backend the structure arrays stay unpadded — ref kernels
-# accept any shape — and the leaf table is class-major all the same.
+#   leaves   packed class-major (Tp/G, Cp, G*Lp) (`class_major`), Tp a
+#            multiple of G, padded trees, leaf slots and classes all
+#            zero (they contribute nothing)
+# On the ref backend — ref kernels accept any shape — only the tree
+# axis is padded, to whole groups of the packed leaf table, so every
+# impl reads G off the shapes alike.
 # The leaf-summing entries take `n_classes`, the C the table holds, and
 # return (N, C): the padded classes never leave them.
 # Each entry runs under `jax.named_scope("gbdt/<stage>")`, so every HLO
@@ -839,8 +895,8 @@ def fused_predict_prepadded(x: jax.Array, borders: jax.Array,
                             backend: Backend = "auto",
                             block_n: int = 128,
                             block_t: int = 16) -> jax.Array:
-    """Fused predict on a prepadded model (class-major leaf table)
-    -> (N, n_classes) f32."""
+    """Fused predict on a prepadded model (packed class-major leaf
+    table) -> (N, n_classes) f32."""
     return registry.dispatch("fused_predict", backend, x, borders,
                              split_features, split_bins, leaf_values,
                              block_n=block_n, block_t=block_t,
@@ -893,8 +949,8 @@ def leaf_gather_prepadded(idx: jax.Array, leaf_values: jax.Array, *,
                           n_classes: int, backend: Backend = "auto",
                           block_n: int = 128,
                           block_t: int = 16) -> jax.Array:
-    """Sum a class-major (Tp, Cp, L) leaf table at idx
-    -> (N, n_classes) f32."""
+    """Sum a packed class-major (Tp/G, Cp, G*Lp) leaf table at the
+    (N, Tp) idx -> (N, n_classes) f32."""
     return registry.dispatch("leaf_gather", backend, idx, leaf_values,
                              block_n=block_n, block_t=block_t,
                              prepadded=True, n_classes=n_classes)

@@ -15,9 +15,10 @@ Conventions (match CatBoost's oblivious-tree model):
   leaf index     idx[n, t] = sum_d  2^d * [ bins[n, sf[t,d]] >= sb[t,d] ]
 
 The oracles keep this model format.  The Pallas kernels read the leaf
-table in the class-major form `layout.lower` builds, (T, Cp, 2^D) with
-the classes padded to a multiple of 8; the registered `ref` impls in
-`kernels.ops` convert it back before calling these.
+table in the packed class-major form `layout.lower` builds, (T/G,
+Cp, G*Lp): G trees a row, the classes padded to a multiple of 8 and
+the 2^D leaves to Lp; the registered `ref` impls in `kernels.ops`
+convert it back (`ops.leaf_major`) before calling these.
 """
 from __future__ import annotations
 

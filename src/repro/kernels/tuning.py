@@ -11,9 +11,9 @@ The footprint models count what Mosaic allocates, not the logical
 bytes: every VMEM buffer is padded to whole (sublane, lane) tiles —
 the lane dim to 128, the sublane dim to 8 rows of 32-bit words (16
 for 2-byte, 32 for 1-byte dtypes) — and every pipelined input/output
-block is double-buffered.  A class-major (T, Cp, L) leaf table block
-at C=7 therefore costs 8/7 of its logical size, where the 7 classes on
-lanes would cost 128/7; a (1, N) uint8 row costs 32 rows.
+block is double-buffered.  A packed class-major (T/G, Cp, G*Lp) leaf
+table block at C=7 therefore costs 8/7 of its logical size, where the 7
+classes on lanes would cost 128/7; a (1, N) uint8 row costs 32 rows.
 """
 from __future__ import annotations
 
@@ -36,6 +36,16 @@ ROW_BLOCKS = (128, 256, 512, 1024)
 # put trees on lanes and always take one full lane of trees.
 TREE_BLOCKS = (8, 16, 32, 64)
 BITPLANE_TREE_BLOCK = LANE
+# The leaf sum contracts G trees' leaf tables per MXU step (see
+# `kernels.leaf_gather`): G = LEAF_STEP_LANES / Lp, a power of two in
+# [1, MAX_TREES_PER_STEP], where Lp is a tree's leaf count rounded up to
+# whole sublanes.  A step costs about 99 ns whatever it contracts, plus
+# 0.27 ns a contracted lane (one v5e, PERF.md section 5); of 128, 256,
+# 512 and 1024 lanes, 1024 sums 2-, 32- and 256-leaf tables fastest
+# (kernel-only sweep on one v5e, PERF.md section 6).  1024 is the edge
+# of the swept range, not an interior optimum: wider steps are untried.
+LEAF_STEP_LANES = 1024
+MAX_TREES_PER_STEP = 64
 
 
 def compiler_params(*dimension_semantics: str):
@@ -48,6 +58,22 @@ def compiler_params(*dimension_semantics: str):
 
 def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
+
+
+def leaf_lanes(n_leaves: int) -> int:
+    """Lp: the lanes one tree's leaves take in the packed leaf table,
+    its leaf count rounded up to whole sublanes, so each tree's one-hot
+    is whole (8, 128) tiles."""
+    return _round_up(max(int(n_leaves), 1), SUBLANE)
+
+
+def trees_per_step(n_leaves: int) -> int:
+    """G: the trees one leaf-sum step contracts at once, from the leaf
+    count alone.  The largest power of two with G * Lp <=
+    LEAF_STEP_LANES, at least 1 and at most MAX_TREES_PER_STEP; a power
+    of two divides every tree block the kernels run."""
+    g = max(LEAF_STEP_LANES // leaf_lanes(n_leaves), 1)
+    return min(1 << (g.bit_length() - 1), MAX_TREES_PER_STEP)
 
 
 def tile_bytes(shape, itemsize: int) -> int:
@@ -97,11 +123,13 @@ def _bitplane_stage_bytes(block_n: int, F: int) -> int:
 
 def _accumulate_stage_bytes(block_n: int, block_t: int, L: int,
                             C: int) -> int:
-    """Stage-3 (leaf accumulate) working set: the class-major (bt, C, L)
-    leaf block (C sublane-padded to 8, L lane-padded to 128), two live
-    (L, bn) one-hots and the (C, bn) sum."""
-    return (_block((block_t, C, L), 4)
-            + 2 * tile_bytes((L, block_n), 4)
+    """Stage-3 (leaf accumulate) working set: the packed class-major
+    (bt/G, C, G*Lp) leaf block (C sublane-padded to 8, G*Lp lane-padded
+    to 128), two live (G*Lp, bn) one-hots and the (C, bn) sum."""
+    g = trees_per_step(L)
+    width = g * leaf_lanes(L)
+    return (_block((-(-block_t // g), C, width), 4)
+            + 2 * tile_bytes((width, block_n), 4)
             + tile_bytes((C, block_n), 4))
 
 
@@ -207,8 +235,10 @@ def candidates_fused(F: int, D: int, L: int, C: int, n_borders: int,
     """
     Fp = _round_up(max(F, 1), LANE)
     bins_bytes = 1 if n_borders <= 255 else 4
+    # a tree block holds whole groups of the packed leaf table
     tree_blocks = ((BITPLANE_TREE_BLOCK,) if gather == "bitplane"
-                   else TREE_BLOCKS)
+                   else tuple(bt for bt in TREE_BLOCKS
+                              if bt % trees_per_step(L) == 0))
     out = []
     for bn in ROW_BLOCKS:
         for bt in tree_blocks:
@@ -395,17 +425,28 @@ def layout_costs(true_depths, n_outputs: int, n_features: int
     """Leaf-table / lowered-array byte costs per layout for an ensemble
     with the given per-tree true depths (the inputs `best_layout` ranks
     on; exposed for the bench and docs).  Leaf tables are counted as
-    lowered: classes padded to a sublane tile."""
+    lowered, in the packed form: classes padded to a sublane tile, each
+    tree's leaves to `leaf_lanes` and each table's trees (one per depth
+    group for depth_grouped) to whole groups of `trees_per_step`."""
     import numpy as np
     d = np.asarray(true_depths, np.int64)
     dmax = int(d.max()) if d.size else 1
     cp = _round_up(max(n_outputs, 1), SUBLANE)
-    soa_leaf = int(d.size) * (1 << dmax) * cp * 4
-    grouped_leaf = int(((1 << np.maximum(d, 1)) * cp * 4).sum())
-    onehot = int(d.size) * dmax * n_features * 4
+
+    def padded_trees(n, depth):
+        return _round_up(n, trees_per_step(1 << depth))
+
+    def table(n, depth):
+        return padded_trees(n, depth) * leaf_lanes(1 << depth) * cp * 4
+
+    groups = np.unique(np.maximum(d, 1), return_counts=True)
+    soa_leaf = table(int(d.size), dmax)
+    grouped_leaf = sum(table(int(n), int(g)) for g, n in zip(*groups))
+    onehot = padded_trees(int(d.size), dmax) * dmax * n_features * 4
     # bitpacked shares depth_grouped's leaf tables; its extra state is
     # two (d, T_d) integer bit planes per group — int32 worst case
-    plane = int((2 * np.maximum(d, 1) * 4).sum())
+    plane = sum(2 * int(g) * padded_trees(int(n), int(g)) * 4
+                for g, n in zip(*groups))
     return {"soa_leaf_bytes": soa_leaf,
             "depth_grouped_leaf_bytes": grouped_leaf,
             "depth_major_onehot_bytes": onehot,

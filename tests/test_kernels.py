@@ -48,7 +48,13 @@ def test_leaf_index_kernel(N, F, T, D):
 
 @pytest.mark.parametrize("N,T,D,C", [(128, 16, 6, 1), (100, 37, 8, 7),
                                      (64, 100, 4, 20), (256, 8, 1, 2),
-                                     (96, 24, 5, 8), (130, 19, 3, 9)])
+                                     (96, 24, 5, 8), (130, 19, 3, 9),
+                                     # L in {2, 4, 32, 256} x C in {1, 7},
+                                     # 37 trees: no whole number of groups
+                                     (100, 37, 1, 1), (100, 37, 1, 7),
+                                     (100, 37, 2, 1), (100, 37, 2, 7),
+                                     (100, 37, 5, 1), (100, 37, 5, 7),
+                                     (100, 37, 8, 1)])
 def test_leaf_gather_kernel(N, T, D, C):
     rng = np.random.default_rng(2)
     idx = jnp.asarray(rng.integers(0, 2 ** D, size=(N, T)).astype(np.int32))

@@ -15,8 +15,8 @@ from repro.core.layout import (BitpackedLayout, DepthGroupedLayout,
                                DepthMajorLayout, NodesLayout, SoaLayout,
                                lower)
 from repro.core.predictor import PredictConfig, Predictor
-from repro.core.trees import (ObliviousEnsemble, PAD_SPLIT_BIN,
-                              truncate_tree_depths)
+from repro.core.trees import (NodeEnsemble, ObliviousEnsemble,
+                              PAD_SPLIT_BIN, truncate_tree_depths)
 from repro.kernels import ops, ref, registry, tuning
 
 
@@ -57,6 +57,19 @@ def _want(ens, x):
                           ens.split_bins, ens.leaf_values))
 
 
+def _want64(ens, x):
+    """float64 numpy: bin each row, walk each tree's depths, sum."""
+    x = np.asarray(x, np.float64)
+    bins = (x[:, None, :] > np.asarray(ens.borders, np.float64)[None]
+            ).sum(1)                                        # (N, F)
+    sf, sb = np.asarray(ens.split_features), np.asarray(ens.split_bins)
+    right = bins[:, sf] >= sb[None]                         # (N, T, D)
+    leaf = (right << np.arange(sf.shape[1])).sum(-1)        # (N, T)
+    lv = np.asarray(ens.leaf_values, np.float64)
+    vals = lv[np.arange(sf.shape[0])[None, :], leaf]        # (N, T, C)
+    return np.asarray(ens.base_score, np.float64)[None] + vals.sum(1)
+
+
 # --------------------------------------------------------------------------
 # true_depths metadata
 # --------------------------------------------------------------------------
@@ -94,26 +107,34 @@ def test_true_depths_all_padded_tree():
 # depth-padded, and mixed-depth ensembles, both kernel families,
 # both strategies
 # --------------------------------------------------------------------------
-@pytest.mark.parametrize("layout", layout_mod.LAYOUT_NAMES)
+@pytest.mark.parametrize("layout,tree_block", [
+    *((name, 0) for name in layout_mod.LAYOUT_NAMES), ("soa", 4)])
 @pytest.mark.parametrize("backend", ["ref", "pallas"])
 @pytest.mark.parametrize("strategy", ["staged", "fused"])
-def test_layout_parity_mixed_depth(layout, backend, strategy):
+def test_layout_parity_mixed_depth(layout, tree_block, backend, strategy):
+    # 13 trees: no layout's packed leaf table holds a whole number of
+    # groups, and tree_block=4 blocks cut on group boundaries
     ens = _mixed_depth(_rand_ensemble())
     x = _rand_x(ens, 37)
     plan = Predictor.build(ens, PredictConfig(
-        strategy=strategy, backend=backend, layout=layout),
-        expected_batch=37)
-    np.testing.assert_allclose(np.asarray(plan.raw(x)), _want(ens, x),
+        strategy=strategy, backend=backend, layout=layout,
+        tree_block=tree_block), expected_batch=37)
+    np.testing.assert_allclose(np.asarray(plan.raw(x)), _want64(ens, x),
                                rtol=1e-5, atol=1e-4)
 
 
 @pytest.mark.parametrize("layout", layout_mod.LAYOUT_NAMES)
-def test_layout_parity_unpadded_uniform(layout):
+@pytest.mark.parametrize("backend,strategy", [("ref", "staged"),
+                                              ("pallas", "fused")])
+def test_layout_parity_unpadded_uniform(layout, backend, strategy):
+    # one depth, so the fused plans run their own kernels: fused_predict
+    # (soa), fused_predict_dm (depth_major), fused_predict_bp (one
+    # bitpacked group)
     ens = _rand_ensemble()                 # no padded levels at all
     x = _rand_x(ens, 21)
     plan = Predictor.build(ens, PredictConfig(
-        strategy="staged", backend="ref", layout=layout))
-    np.testing.assert_allclose(np.asarray(plan.raw(x)), _want(ens, x),
+        strategy=strategy, backend=backend, layout=layout))
+    np.testing.assert_allclose(np.asarray(plan.raw(x)), _want64(ens, x),
                                rtol=1e-5, atol=1e-4)
 
 
@@ -154,7 +175,10 @@ def _leaf_tables(low, ens, tree_block):
         return [(g.leaf_values, lv[depths == g.depth][:, :1 << g.depth])
                 for g in low.groups]
     if tree_block:
-        return [(blk[2], lv[i * tree_block:(i + 1) * tree_block])
+        # blocks are cut on group boundaries of the packed table
+        step = -(-tree_block // tuning.trees_per_step(lv.shape[1])) \
+            * tuning.trees_per_step(lv.shape[1])
+        return [(blk[2], lv[i * step:(i + 1) * step])
                 for i, blk in enumerate(low.tree_blocks)]
     return [(low.leaf_values, lv)]
 
@@ -163,30 +187,56 @@ def _leaf_tables(low, ens, tree_block):
 @pytest.mark.parametrize("backend", ["ref", "pallas"])
 @pytest.mark.parametrize("layout,tree_block", [
     ("soa", 0), ("soa", 4), ("depth_major", 0), ("depth_grouped", 0),
-    ("bitpacked", 0)])
+    ("bitpacked", 0), ("nodes", 0)])
 def test_lowered_leaf_table_is_class_major(layout, tree_block, backend,
                                            n_outputs):
-    # every layout holds its leaf tables as (Tp, round_up(C, 8), L):
-    # the model's values transposed, zero in padded classes and trees
+    # every layout holds its leaf tables packed class-major, (Tp/G,
+    # round_up(C, 8), G * Lp): G trees side by side on lanes, tree t's
+    # leaves at lanes (t % G) * Lp + l of row t // G, zero in padded
+    # classes, leaf slots and trees; ops.leaf_major inverts it bit for
+    # bit
     ens = _mixed_depth(_rand_ensemble(n_outputs=n_outputs))
     low = lower(ens, layout, backend=backend, t_align=16,
                 tree_block=tree_block)
     cp = -(-n_outputs // 8) * 8
-    tables = _leaf_tables(low, ens, tree_block)
+    if layout == "nodes":
+        # the oblivious trees rewritten as node tables, leaves in W slots
+        lv = np.asarray(NodeEnsemble.from_oblivious(ens).leaf_values)
+        model = np.zeros((lv.shape[0], low.width, n_outputs), np.float32)
+        model[:, :lv.shape[1]] = lv
+        tables = [(low.leaf_values, model)]
+    else:
+        tables = _leaf_tables(low, ens, tree_block)
     for table, model in tables:
         table = np.asarray(table)
         n, n_leaves, _ = model.shape
-        tp = table.shape[0]
-        assert table.shape == (tp, cp, n_leaves)
+        g = tuning.trees_per_step(n_leaves)
+        lp = -(-n_leaves // 8) * 8
+        rows = table.shape[0]
+        assert table.shape == (rows, cp, g * lp)
         if backend == "pallas":
-            assert tp % (128 if layout == "bitpacked" else 16) == 0
+            assert rows * g % (128 if layout == "bitpacked" else 16) == 0
+            assert rows * g >= n
         else:
-            assert tp == n
-        np.testing.assert_array_equal(table[:n, :n_outputs],
-                                      model.transpose(0, 2, 1))
-        assert not table[:, n_outputs:].any()
-        assert not table[n:].any()
-    assert sum(t.shape[0] for t, _ in tables) >= ens.n_trees
+            assert rows == -(-n // g)
+        unpacked = (table.reshape(rows, cp, g, lp).transpose(0, 2, 3, 1)
+                    .reshape(rows * g, lp, cp))
+        np.testing.assert_array_equal(unpacked[:n, :n_leaves, :n_outputs],
+                                      model)
+        assert not unpacked[:, :, n_outputs:].any()
+        assert not unpacked[:, n_leaves:].any()
+        assert not unpacked[n:].any()
+        back = ops.leaf_major(jnp.asarray(table), n_outputs, rows * g)
+        assert back.shape == (rows * g, lp, n_outputs)
+        np.testing.assert_array_equal(np.asarray(back),
+                                      unpacked[:, :, :n_outputs])
+        np.testing.assert_array_equal(
+            np.asarray(ops.class_major(back, rows * g)), table)
+        np.testing.assert_array_equal(
+            np.asarray(ops.class_major(jnp.asarray(model), rows * g)),
+            table)
+    assert sum(t.shape[0] * tuning.trees_per_step(m.shape[1])
+               for t, m in tables) >= ens.n_trees
     assert low.leaf_table_bytes() == sum(np.asarray(t).nbytes
                                          for t, _ in tables)
 
@@ -204,14 +254,34 @@ def test_depth_major_ref_is_bit_exact():
                                   np.asarray(dm.raw(x)))
 
 
-def test_depth_grouped_shrinks_leaf_tables():
-    ens = _mixed_depth(_rand_ensemble(n_trees=16, depth=5))
+@pytest.mark.parametrize("n_trees", [16, 256])
+def test_depth_grouped_shrinks_leaf_tables(n_trees):
+    # each depth group's packed table pads its trees to whole groups of
+    # up to 64: at 16 mixed-depth trees the groups are mostly padding,
+    # bigger than soa's one table, and best_layout keeps away from
+    # depth_grouped; at 256 the groups fill and the saving is real
+    ens = _mixed_depth(_rand_ensemble(n_trees=n_trees, depth=5))
     soa = lower(ens, "soa")
     grouped = lower(ens, "depth_grouped")
-    assert grouped.leaf_table_bytes() < soa.leaf_table_bytes() / 2
-    # group structure: one group per distinct clamped depth, all trees
+    costs = tuning.layout_costs(ens.true_depths, ens.n_outputs,
+                                ens.n_features)
+    assert costs["soa_leaf_bytes"] == soa.leaf_table_bytes()
+    assert costs["depth_grouped_leaf_bytes"] == grouped.leaf_table_bytes()
+    picked = tuning.best_layout(ens.true_depths, ens.n_outputs,
+                                ens.n_features)
+    if n_trees == 16:
+        assert grouped.leaf_table_bytes() > soa.leaf_table_bytes()
+        assert picked != "depth_grouped"
+    else:
+        assert grouped.leaf_table_bytes() < soa.leaf_table_bytes() / 2
+        assert picked == "depth_grouped"
+    # group structure: one group per distinct clamped depth, all trees,
+    # each padded to whole groups of its packed table
+    depths = np.maximum(np.asarray(ens.true_depths), 1)
     assert [g.depth for g in grouped.groups] == [1, 2, 3, 5]
-    assert sum(g.n_trees for g in grouped.groups) == ens.n_trees
+    for g in grouped.groups:
+        step = tuning.trees_per_step(1 << g.depth)
+        assert g.n_trees == -(-(depths == g.depth).sum() // step) * step
 
 
 def test_lower_pallas_pads_model_once():
@@ -222,8 +292,12 @@ def test_lower_pallas_pads_model_once():
     assert low.borders.shape[1] % ops.FEATURE_ALIGN == 0
     assert low.onehot.shape[0] % 16 == 0
     assert low.split_bins_dm.shape == (ens.depth, low.onehot.shape[0])
-    # ref lowering keeps exact shapes (padding would be wasted math)
-    assert lower(ens, "depth_major").onehot.shape[0] == ens.n_trees
+    # ref lowering pads only the tree axis, to whole groups of the
+    # packed leaf table (any more would be wasted math)
+    ref_low = lower(ens, "depth_major")
+    step = tuning.trees_per_step(1 << ens.depth)
+    assert ref_low.onehot.shape[0] == -(-ens.n_trees // step) * step
+    assert ref_low.borders.shape == ens.borders.shape
 
 
 def test_lower_rejects_unknown_layout_and_tracers():
@@ -317,9 +391,13 @@ def test_config_layout_validation_and_resolution():
         PredictConfig(layout="columnar")
     with pytest.raises(ValueError, match="soa-layout"):
         PredictConfig(tree_block=4, layout="depth_grouped")
-    ens = _mixed_depth(_rand_ensemble())
+    ens = _mixed_depth(_rand_ensemble(n_trees=256))
     r = PredictConfig().resolve(ens)
     assert r.layout == "depth_grouped"      # mixed depths -> grouped
+    # a few mixed-depth trees: each depth group's packed leaf table
+    # would be mostly padding, so auto stays on soa
+    assert PredictConfig().resolve(
+        _mixed_depth(_rand_ensemble())).layout == "soa"
     assert r.is_resolved
     # tree blocking pins auto to soa (blocked loop is an soa feature)
     rb = PredictConfig(tree_block=4).resolve(ens)
@@ -330,7 +408,7 @@ def test_config_layout_validation_and_resolution():
 
 
 def test_plan_stats_expose_layout_and_lowering():
-    ens = _mixed_depth(_rand_ensemble())
+    ens = _mixed_depth(_rand_ensemble(n_trees=256))
     plan = Predictor.build(ens, PredictConfig(strategy="staged",
                                               backend="ref"))
     s = plan.stats
@@ -348,9 +426,37 @@ def test_plan_stats_expose_layout_and_lowering():
     assert lazy._lowered is not None
 
 
+@pytest.mark.parametrize("layout", layout_mod.LAYOUT_NAMES)
+@pytest.mark.parametrize("depth", [1, 5, 8])
+def test_describe_reports_trees_per_step(layout, depth):
+    # G comes from each leaf table's leaf count alone (L = 2^d, W for
+    # node tables), and the leaf sum runs Tp/G steps a row block
+    ens = _rand_ensemble(n_trees=70, depth=depth)
+    if layout in ("depth_grouped", "bitpacked"):
+        ens = _mixed_depth(ens)
+    plan = Predictor.build(ens, PredictConfig(
+        strategy="staged", backend="ref", layout=layout))
+    low = plan.lowered
+    d = plan.describe()["lowered"]
+    if layout in ("depth_grouped", "bitpacked"):
+        tables = [(g.leaf_values, 1 << g.depth) for g in low.groups]
+    elif layout == "nodes":
+        tables = [(low.leaf_values, low.width)]
+    else:
+        tables = [(low.leaf_values, 1 << depth)]
+    gs = [tuning.trees_per_step(n) for _, n in tables]
+    want = gs[0] if len(set(gs)) == 1 else gs
+    assert d["trees_per_step"] == want
+    assert d["leaf_steps_per_block"] == sum(lv.shape[0] for lv, _ in tables)
+    for (lv, n), g in zip(tables, gs):
+        lp = -(-n // 8) * 8
+        assert g == min(max(tuning.LEAF_STEP_LANES // lp, 1), 64)
+        assert lv.shape[2] == g * lp
+
+
 def test_server_metrics_report_layout():
     from repro.serving.engine import GBDTServer
-    ens = _mixed_depth(_rand_ensemble(n_outputs=1))
+    ens = _mixed_depth(_rand_ensemble(n_trees=256, n_outputs=1))
     server = GBDTServer(ens, config=PredictConfig(strategy="staged",
                                                   backend="ref"),
                         max_batch=16)

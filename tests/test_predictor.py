@@ -346,13 +346,18 @@ def test_catboost_json_nonuniform_depths_lower_grouped(tmp_path):
     assert sb[1, 1] == PAD_SPLIT_BIN and sb[1, 2] == PAD_SPLIT_BIN
     assert sb[2, 2] == PAD_SPLIT_BIN
     np.testing.assert_array_equal(np.asarray(ens.leaf_values)[1, 2:, 0], 0.0)
-    # an auto plan on this model picks depth_grouped and matches ref
+    # one tree a depth: each depth group's packed leaf table would be
+    # one row of 64 padded trees, so an auto plan stays on soa; a
+    # depth_grouped plan matches ref all the same
     from repro.kernels import ref
+    assert PredictConfig().resolve(ens).layout == "soa"
     plan = Predictor.build(ens, PredictConfig(strategy="staged",
-                                              backend="ref"))
+                                              backend="ref",
+                                              layout="depth_grouped"))
     assert plan.config.layout == "depth_grouped"
     lowered = plan.describe()["lowered"]
-    assert lowered["groups"] == {1: 1, 2: 1, 3: 1}
+    # each group padded to whole groups of its packed leaf table
+    assert lowered["groups"] == {1: 64, 2: 64, 3: 64}
     x = jnp.asarray([[-0.5], [0.5], [1.5], [2.5]], jnp.float32)
     want = np.asarray(ref.fused_predict(
         x, ens.borders, ens.split_features, ens.split_bins,

@@ -66,6 +66,12 @@ def _pad(n, m):
     return -(-n // m) * m
 
 
+def _packed(n_trees, n_leaves=L):
+    """The lowered leaf table's shape: G trees of Lp lanes a row."""
+    g = tuning.trees_per_step(n_leaves)
+    return (n_trees // g, CP, g * tuning.leaf_lanes(n_leaves))
+
+
 @pytest.mark.parametrize("n_rows", [256, 4096])
 def test_fused_predict_u8_10k_trees(one_chip, n_rows):
     """The serving kernel `auto` resolves to on TPU (soa layout: the
@@ -80,7 +86,7 @@ def test_fused_predict_u8_10k_trees(one_chip, n_rows):
         bins_scratch_dtype=jnp.uint8),
         s((_pad(n_rows, bn), FP), jnp.float32), s((B, FP), jnp.float32),
         s((tp, D), jnp.int32), s((tp, D), jnp.int32),
-        s((tp, CP, L), jnp.float32))
+        s(_packed(tp), jnp.float32))
 
 
 def test_staged_chain_u8(one_chip):
@@ -99,7 +105,7 @@ def test_staged_chain_u8(one_chip):
 
     text = _compile(chain, s((n, FP), jnp.float32), s((B, FP), jnp.float32),
                     s((tp, D), jnp.int32), s((tp, D), jnp.int32),
-                    s((tp, CP, L), jnp.float32))
+                    s(_packed(tp), jnp.float32))
     assert text.count("tpu_custom_call") >= 3
 
 
@@ -132,7 +138,7 @@ def test_fused_layout_variants(one_chip, layout):
             bins_scratch_dtype=jnp.uint8),
             s((n, FP), jnp.float32), s((B, FP), jnp.float32),
             s((1024, D, FP), jnp.float32), s((D, 1024), jnp.int32),
-            s((D, 1), jnp.float32), s((1024, CP, L), jnp.float32))
+            s((D, 1), jnp.float32), s(_packed(1024), jnp.float32))
         return
     bn, _ = tuning.best_fused_blocks(F, D, L, C, B, n_rows=n, n_trees=1024,
                                      gather="bitplane")
@@ -140,7 +146,7 @@ def test_fused_layout_variants(one_chip, layout):
         x, b, sf, sb, lv, block_n=bn, bins_scratch_dtype=jnp.uint8),
         s((n, FP), jnp.float32), s((B, FP), jnp.float32),
         s((D, 1024), jnp.int32), s((D, 1024), jnp.int32),
-        s((1024, CP, L), jnp.float32))
+        s(_packed(1024), jnp.float32))
 
 
 def _covertype_plan(n_trees, **config):
@@ -198,14 +204,14 @@ def test_plan_entries_read_the_leaf_table_in_place(one_chip, monkeypatch,
                                                    entry, dtype):
     """The bulk (`proba_pool`) and online (fused `proba`) entries of the
     covertype plan (fused, pallas, soa, blocks 1024 x 64) at 640 trees:
-    the class-major (Tp, 8, L) table is read in the layout the device
-    stores it in.  A leaf-major (Tp, L, 7) table was relaid out by a
-    `copy` of the parameter before every kernel call, into a temp 16x
-    the table (83,886,080 B at 640 trees)."""
+    the packed class-major (Tp/G, 8, G*L) table is read in the layout
+    the device stores it in.  A leaf-major (Tp, L, 7) table was relaid
+    out by a `copy` of the parameter before every kernel call, into a
+    temp 16x the table (83,886,080 B at 640 trees)."""
     monkeypatch.setattr(ops, "_interpret", lambda: False)
     plan = _covertype_plan(640, strategy="fused", block_n=1024,
                            block_t=64)
-    assert plan.lowered.leaf_values.shape == (640, CP, L)
+    assert plan.lowered.leaf_values.shape == _packed(640)
     model = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
                          plan.lowered)
     compiled = plan._entries[entry].lower(
@@ -275,5 +281,46 @@ def test_lossguide_bulk_entry_names_node_index(one_chip, monkeypatch):
         assert f"/gbdt/{name}/" in kernels[name], kernels[name]
     copied = [line for line in text.splitlines()
               if re.search(r"= \S+ copy\(", line)
-              and re.search(r'op_name="p\.(node|path)', line)]
+              and re.search(r'op_name="p\.(node|path|leaf_values)', line)]
+    assert not copied, copied
+
+
+def test_stump_bulk_entry_reads_the_leaf_table_in_place(one_chip,
+                                                        monkeypatch):
+    """santander's shape: depth-1 trees, one output, 200 features,
+    10,000 trees, the depth_major plan `best_layout` picks.  Its 2-leaf
+    tables pack 64 trees to a 512-lane row, which the device stores as
+    it is: the bulk entry runs `leaf_index_dm` and `leaf_gather` with
+    no `copy` of the leaf table (a (T, 8, 2) table was relaid out
+    before every call)."""
+    import numpy as np
+
+    from repro.core.predictor import PredictConfig, Predictor
+    from repro.core.trees import ObliviousEnsemble
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    f, n_trees = 200, 10_000
+    rng = np.random.default_rng(0)
+    ens = ObliviousEnsemble(
+        jnp.asarray(rng.integers(0, f, (n_trees, 1)).astype(np.int32)),
+        jnp.asarray(rng.integers(1, B, (n_trees, 1)).astype(np.int32)),
+        jnp.asarray(rng.normal(size=(n_trees, 2, 1)).astype(np.float32)),
+        jnp.asarray(np.sort(rng.normal(size=(B, f)), 0).astype(np.float32)),
+        jnp.full((f,), B, jnp.int32))
+    plan = Predictor.build(ens, PredictConfig(strategy="staged",
+                                              backend="pallas"))
+    assert plan.config.layout == "depth_major"
+    g = tuning.trees_per_step(2)
+    assert plan.lowered.leaf_values.shape == (_pad(n_trees, g) // g, 8,
+                                              g * 8)
+    model = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
+                         plan.lowered)
+    text = plan._entries["proba_pool"].lower(
+        model, _sds(one_chip, (256, f), jnp.uint8)).compile().as_text()
+    kernels = {m.group(1).split(".")[0] for m in re.finditer(
+        r'%(\S+) = \S+ custom-call\(', text)}
+    assert kernels == {"leaf_index_dm", "leaf_gather"}, kernels
+    copied = [line for line in text.splitlines()
+              if re.search(r"= \S+ copy\(", line)
+              and 'op_name="p.leaf_values"' in line]
     assert not copied, copied
